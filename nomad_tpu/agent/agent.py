@@ -148,6 +148,12 @@ class Agent:
 
     # -- setup -------------------------------------------------------------
     def _setup_server(self) -> None:
+        from nomad_tpu.parallel.devices import configure_compile_cache
+
+        # Before the server's first jit: every kernel it compiles goes
+        # to $JAX_COMPILATION_CACHE_DIR or <checkout>/.jax_cache — never
+        # under data_dir, which dev mode makes fresh per boot.
+        configure_compile_cache()
         cfg = ServerConfig(
             num_schedulers=self.config.num_schedulers,
             use_device_scheduler=self.config.use_device_scheduler,
@@ -235,9 +241,18 @@ class Agent:
         )
         if self.server is not None:
             cfg.rpc_handler = self._inproc_rpc
-        elif not cfg.servers:
-            raise ValueError("client mode requires servers or a "
-                             "colocated server")
+        else:
+            if not cfg.servers:
+                raise ValueError("client mode requires servers or a "
+                                 "colocated server")
+            # One process per chip: a client-only agent must not
+            # initialize JAX — on the server's host it would fail, hang
+            # or take the chip from the server agent, and on a worker
+            # host it would hold the chip its own tasks need.  Only an
+            # agent that also runs the server (which owns the chip in
+            # this very process) fingerprints accel.* through jax; an
+            # operator can still opt a client-only node in explicitly.
+            cfg.options.setdefault("fingerprint.skip_accel", "1")
         self.client = Client(cfg)
         self.client.start()
 

@@ -133,10 +133,9 @@ def _place_rounds(capacity, reserved, usage0, jc0, feasible, asks, distinct,
     then the greedy never stacks a second copy on a node before using every
     other feasible node, i.e. it spreads exactly like top-k.
 
-    Motivation: sequential scans pay a fixed per-iteration cost (severe on
-    remote-attached TPUs); this path needs S x rounds steps instead of one
-    step per placement — a 10k-placement eval with one deduped group runs
-    in ~1 device step.
+    Motivation: sequential scans pay a fixed per-iteration cost; this
+    path needs S x rounds steps instead of one step per placement — a
+    10k-placement eval with one deduped group runs in ~1 device step.
 
     Args mirror place_sequence except:
       counts: i32[G] — copies to place per slot.

@@ -818,7 +818,10 @@ def _evaluate_window_vec(overlay, plans: list, executor,
                 _dispatch_window_fit(dev_mesh, dev_capres, dev_lease,
                                      dev_args, vec_pair, vec_rows,
                                      len(pairs))
-        except Exception:
+        except Exception as e:
+            from nomad_tpu.parallel.devices import transient_device_fault
+            if not transient_device_fault(e):
+                raise  # e.g. a kernel the chip's compiler refuses
             # Rare (runtime teardown, device OOM): the window still
             # verifies exactly — the caller's per-plan scalar path.
             return None

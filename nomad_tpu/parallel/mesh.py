@@ -374,10 +374,13 @@ def _window_verify_jit(capacity, reserved, usage, pair_ni, row_pair,
       pair_comp    i32[B]    pair's claim-graph component
       pair_removed f32[B,4]  pair's own removed-row sums (frame rows)
 
-    All resource values are small integers in float32, so every sum
-    here is exact and order-independent — the device numbers (and the
-    verdicts compared from them) are byte-identical to the host dense
-    pass (the same argument _evaluate_window_vec already relies on).
+    All resource values are small integers in float32 and every sum
+    here is a plain float32 add (scatter-add, masked reduce — never a
+    matmul, which a TPU runs as a bf16 pass at default precision and
+    would round an ask of 517 MHz to 516), so the sums are exact and
+    order-independent: the device numbers (and the verdicts compared
+    from them) are byte-identical to the host dense pass (the same
+    argument _evaluate_window_vec already relies on).
     """
     npair = pair_ni.shape[0]
     # Claim-scatter: each pair's placement rows sum into its delta row.
@@ -395,12 +398,15 @@ def _window_verify_jit(capacity, reserved, usage, pair_ni, row_pair,
     # node outside the claim graph, so node equality alone is not
     # enough), under the optimistic all-accepted assumption the host
     # walk validates (plan_conflict._walk_component's ``clean`` guard).
-    fold = jnp.where(
-        (seq_ni[None, :] == pair_ni[:, None])
-        & (seq_order[None, :] < pair_order[:, None])
-        & (seq_comp[None, :] == pair_comp[:, None]),
-        jnp.float32(1.0), jnp.float32(0.0))
-    used_seq = used + fold @ seq_vec - pair_removed
+    fold = (seq_ni[None, :] == pair_ni[:, None]) \
+        & (seq_order[None, :] < pair_order[:, None]) \
+        & (seq_comp[None, :] == pair_comp[:, None])
+    # One masked float32 reduce per resource column (fold entries along
+    # the minor axis) — see the exactness note in the docstring.
+    overlay = jnp.stack(
+        [jnp.sum(jnp.where(fold, seq_vec[None, :, d], jnp.float32(0.0)),
+                 axis=1) for d in range(4)], axis=1)
+    used_seq = used + overlay - pair_removed
     fits_seq = jnp.all(used_seq <= caps, axis=1)
     return used, caps, fits_seq
 

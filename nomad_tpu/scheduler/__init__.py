@@ -1,8 +1,9 @@
 """Scheduler layer: pure placement logic behind the State/Planner seams.
 
 Registry carries service/batch/system (sequential, parity-faithful) plus the
-TPU-native jax-binpack backend (registered lazily to keep JAX import optional
-for host-only use).
+TPU-native jax-binpack backend.  JAX is a hard dependency: a failure to
+import or register the device schedulers fails the import of this package
+instead of leaving a server that silently schedules on the CPU.
 """
 from .interfaces import (  # noqa: F401
     BUILTIN_SCHEDULERS,
@@ -23,58 +24,18 @@ from .generic import (  # noqa: F401
 from .system import SystemScheduler, new_system_scheduler  # noqa: F401
 from .harness import Harness, RejectPlan  # noqa: F401
 from .stack import GenericStack, SystemStack  # noqa: F401
+from .batch import BatchEvalRunner  # noqa: F401
+from .jax_binpack import (
+    new_jax_binpack_batch_scheduler,
+    new_jax_binpack_scheduler,
+)
+from .system_vec import new_vector_system_scheduler
 
 register_scheduler("service", new_service_scheduler)
 register_scheduler("batch", new_batch_scheduler)
-register_scheduler("system", new_system_scheduler)
 # The sequential iterator-chain system scheduler stays addressable for
-# golden-parity tests; "system" is rebound to the vectorized one below
-# when the array stack imports.
+# golden-parity tests; "system" itself is the vectorized one.
 register_scheduler("system-seq", new_system_scheduler)
-
-
-def _register_jax() -> None:
-    try:
-        from .jax_binpack import (
-            new_jax_binpack_batch_scheduler,
-            new_jax_binpack_scheduler,
-        )
-        from .system_vec import new_vector_system_scheduler
-    except ImportError:  # pragma: no cover - jax always present in CI
-        return
-    register_scheduler("jax-binpack", new_jax_binpack_scheduler)
-    register_scheduler("jax-binpack-batch", new_jax_binpack_batch_scheduler)
-    register_scheduler("system", new_vector_system_scheduler)
-    global BatchEvalRunner
-    from .batch import BatchEvalRunner  # noqa: F401
-
-
-try:
-    import jax  # noqa: F401
-    _HAS_JAX = True
-except Exception:  # pragma: no cover
-    _HAS_JAX = False
-
-if _HAS_JAX:
-    try:
-        _register_jax()
-    except Exception:  # pragma: no cover - keep host plane importable
-        pass
-
-
-def device_available() -> bool:
-    """One-time probe: can the JAX backend actually hand out devices?
-
-    Importing jax succeeding does not mean the backend initialises (e.g. a
-    plugin platform selected via JAX_PLATFORMS whose plugin isn't on the
-    path).  Without this probe a broken device plane would fail every
-    device-scheduled eval into the delivery-limit reaper; with it the
-    server degrades to the sequential schedulers at startup.
-    """
-    if not _HAS_JAX:
-        return False
-    try:
-        from nomad_tpu.parallel.devices import default_platform_devices
-        return bool(default_platform_devices())
-    except Exception:
-        return False
+register_scheduler("jax-binpack", new_jax_binpack_scheduler)
+register_scheduler("jax-binpack-batch", new_jax_binpack_batch_scheduler)
+register_scheduler("system", new_vector_system_scheduler)

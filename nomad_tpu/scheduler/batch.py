@@ -86,6 +86,33 @@ class BatchEvalRunner:
         self.state = state
         self.planner = planner
         self.state_refresh = state_refresh
+        # Dispatch mix: kernel calls by the executor that actually ran
+        # them (a fused device dispatch is ONE call for all its lanes;
+        # the host twin runs one call per lane).  ``sharded`` counts
+        # the device calls that rode a mesh.  Written by the thread
+        # that drives ``process``; the registry reads plain ints.
+        self.host_dispatches = 0
+        self.device_dispatches = 0
+        self.sharded_dispatches = 0
+        self.fused_batches = 0   # fused windows planned, either executor
+
+    def _note_dispatch(self, sched) -> None:
+        """Fold one scheduler's own kernel-call counts (its single-eval
+        dispatches and finish-loop host re-plans) into the mix."""
+        calls = sched.kernel_calls
+        self.host_dispatches += calls["host"]
+        self.device_dispatches += calls["device"]
+        self.sharded_dispatches += calls["sharded"]
+        sched.kernel_calls = dict.fromkeys(calls, 0)
+
+    def stats(self) -> dict:
+        """Registry provider (obs/registry.py): the dispatch mix."""
+        return {
+            "host_dispatches": self.host_dispatches,
+            "device_dispatches": self.device_dispatches,
+            "sharded_dispatches": self.sharded_dispatches,
+            "fused_batches": self.fused_batches,
+        }
 
     def _split_rounds(self, evals: list[Evaluation]
                       ) -> tuple[list, list]:
@@ -175,9 +202,14 @@ class BatchEvalRunner:
                 pending = retries
                 self.state = self.state_refresh()
             for ev in pending:
-                retry = JaxBinPackScheduler(self.state, self.planner,
-                                            batch=(ev.type == "batch"))
-                retry.process(ev)
+                self._retry_sequential(self.state, ev)
+
+    def _retry_sequential(self, state, ev: Evaluation) -> None:
+        """Exact per-eval retry (fresh scheduler, full process)."""
+        retry = JaxBinPackScheduler(state, self.planner,
+                                    batch=(ev.type == "batch"))
+        retry.process(ev)
+        self._note_dispatch(retry)
 
     def _process(self, evals: list[Evaluation],
                  retries: Optional[list] = None) -> None:
@@ -231,6 +263,7 @@ class BatchEvalRunner:
         policy = executor_policy()
         steps = rounds * g_max if rounds_ok else p_max
         fused_cost = B * steps * statics.n_real
+        self.fused_batches += 1
         if policy == EXECUTOR_HOST or (
                 policy != EXECUTOR_DEVICE and
                 fused_cost <= JaxBinPackScheduler.HOST_SINGLE_SHOT_COST):
@@ -268,6 +301,9 @@ class BatchEvalRunner:
         from nomad_tpu.parallel.mesh import dispatch_mesh
 
         mesh = dispatch_mesh(B_pad, statics.n_pad)
+        self.device_dispatches += 1
+        if mesh is not None:
+            self.sharded_dispatches += 1
         # All fused lanes share the same snapshot base usage (fast-path
         # contract above); use the resident device copies when available
         # (single-device mirror copy, or on a mesh the sharded statics +
@@ -387,6 +423,7 @@ class BatchEvalRunner:
                     float(args.penalty), n_real=n_real)
             _lane_spans("sched.dispatch", [sched], t_disp, _tnow(),
                         host=True)
+            self.host_dispatches += 1
             done.append((sched, place, args, chosen, scores))
         self._finish_window(done, retries)
 
@@ -411,6 +448,7 @@ class BatchEvalRunner:
         t1 = _tnow()
         _lane_spans("sched.dispatch", [sched], t0, t1)
         sched.finish_deferred(place, args, chosen, scores)
+        self._note_dispatch(sched)
         _lane_spans("sched.finish", [sched], t1, _tnow())
         self._finish(sched, retries)
 
@@ -473,6 +511,8 @@ class BatchEvalRunner:
         if not done:
             return
         self._finish_lanes(done)
+        for sched, *_rest in done:
+            self._note_dispatch(sched)  # finish-loop host re-plans
         self._submit_window([sched for sched, *_rest in done], retries)
 
     def _submit_window(self, scheds: list, retries=None) -> None:
@@ -519,9 +559,7 @@ class BatchEvalRunner:
             elif retries is not None:
                 retries.append(ev)  # no status yet: a later round owns it
             else:
-                retry = JaxBinPackScheduler(
-                    sched.state, self.planner, batch=(ev.type == "batch"))
-                retry.process(ev)
+                self._retry_sequential(sched.state, ev)
         _lane_spans("sched.submit", scheds, t_sub, _tnow(),
                     window=len(scheds))
 
@@ -543,6 +581,4 @@ class BatchEvalRunner:
         elif retries is not None:
             retries.append(ev)  # no status yet: a later round owns it
         else:
-            retry = JaxBinPackScheduler(
-                sched.state, self.planner, batch=(ev.type == "batch"))
-            retry.process(ev)
+            self._retry_sequential(sched.state, ev)

@@ -5,11 +5,11 @@ The jax-binpack scheduler picks between two executors per dispatch
 
   host    numpy twin kernels (ops/binpack_host.py) — zero dispatch
           latency, wins whenever the workload is smaller than a device
-          round trip (on remote-attached TPUs one dispatch costs a full
-          network RTT, ~100 ms, regardless of compute size);
+          round trip (enqueue + run + device->host copy; PERF.md
+          records the fenced round trip measured on the chip);
   device  jit kernels (ops/binpack.py) — wins for fused eval storms,
           multi-chip fleets, and pipelined streams deep enough to hide
-          the RTT behind host work.
+          the round trip behind host work.
 
 ``auto`` (the default) applies the cost model.  ``host`` / ``device``
 force one side — the bench's `4_device_pipelined` row, the multi-chip

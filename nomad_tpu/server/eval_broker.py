@@ -141,6 +141,8 @@ class EvalBroker:
         self._acks = 0               # deliveries acked (the control
         #   plane's throughput gauge: depth / ack rate estimates queue
         #   residence, the portable congestion signal)
+        self._nacks = 0              # deliveries nacked or timed out:
+        #   every one is a redelivery (or a trip to the failed queue)
         self._trace_enq: dict = {}   # eval id -> tracer-epoch ready time
         #   (obs/trace.py: the broker.wait span's t0; stamped per
         #    _enqueue_locked so nack redeliveries re-time their wait)
@@ -473,6 +475,7 @@ class EvalBroker:
             with self._token_lock:
                 self._tokens.pop(eval_id, None)
             del self._unack[eval_id]
+            self._nacks += 1
 
             if self._evals.get(eval_id, 0) >= self.delivery_limit:
                 self._enqueue_locked(unack.eval, FAILED_QUEUE)
@@ -492,6 +495,7 @@ class EvalBroker:
                 "expired_drops": self._expired_drops,
                 "depth_sheds": self._depth_sheds,
                 "acks": self._acks,
+                "nacks": self._nacks,
                 # The admission pressure source's inputs, exported so
                 # the control plane reads them as gauges.
                 "depth": len(self._evals),

@@ -128,14 +128,25 @@ class Server:
         self.config = config or ServerConfig()
         from nomad_tpu.scheduler.executor import (executor_policy,
                                                   set_executor_policy)
-        if self.config.executor != "auto":
-            # Process-wide: the executor choice is a property of the
-            # machine (chip attach latency), not of one worker.  A bad
-            # value fails the boot here, not the first dispatch.
-            set_executor_policy(self.config.executor)
+        # Process-wide: the executor choice is a property of the
+        # machine (dispatch round trip), not of one worker.  Always
+        # installed — "auto" included — so a second server in one
+        # process runs ITS configured policy, not the first one's.  A
+        # bad value fails the boot here, not the first dispatch.
+        set_executor_policy(self.config.executor)
         # Resolve once now so a typo'd $NOMAD_TPU_EXECUTOR also fails
         # the boot, not the first dispatch (the README's guarantee).
         executor_policy()
+        if self.config.use_device_scheduler and \
+                self.config.num_schedulers > 0:
+            # No usable backend is a boot error too (jax raises
+            # RuntimeError, before any thread or socket exists here):
+            # degrading to the sequential schedulers would hide a
+            # missing chip behind a working, slow cluster.  An operator
+            # who wants them sets use_device_scheduler=False.
+            from nomad_tpu.parallel.devices import \
+                default_platform_devices
+            default_platform_devices()
         if self.config.tune_gc:
             # Scheduler churn + a large live store make default GC
             # thresholds cost 100-200ms pauses (utils/gctune.py).
@@ -338,6 +349,11 @@ class Server:
         # fsm.state is REPLACED on snapshot restore: resolve per read.
         reg.register("store", lambda: self.fsm.state.stats())
         reg.register("workers", self._worker_stats)
+        for w in self.workers:
+            if isinstance(w, BatchWorker):
+                # The fused runner's dispatch mix: which engine (host
+                # twin / device / sharded) actually ran the kernels.
+                reg.register("batch_runner", w.runner.stats)
         if self.rpc_server is not None:
             reg.register("rpc", self.rpc_server.stats)
         self.obs_registry = reg
@@ -348,6 +364,8 @@ class Server:
         return {
             "count": len(self.workers),
             "expired_drops": sum(w.expired_drops for w in self.workers),
+            "dispatch_failures": sum(w.dispatch_failures
+                                     for w in self.workers),
         }
 
     def _gossip_join(self, member) -> None:
@@ -455,14 +473,6 @@ class Server:
             # Leader-only server (and test rigs that drive the broker /
             # plan queue by hand): no scheduling workers at all.
             return
-        if self.config.use_device_scheduler:
-            import nomad_tpu.scheduler as sched_registry
-
-            if not sched_registry.device_available():
-                logger.warning(
-                    "device backend unavailable; falling back to "
-                    "sequential schedulers for this server")
-                self.config.use_device_scheduler = False
         if self.config.use_device_scheduler:
             # One device batch worker replaces the goroutine fleet for
             # service/batch evals; plain workers cover system/_core so the

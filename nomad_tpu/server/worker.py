@@ -53,6 +53,10 @@ class Worker:
         # and checked after potentially-long waits.
         self._delivery_deadline: float = 0.0
         self.expired_drops = 0  # deliveries abandoned past deadline
+        # Deliveries (for the batch worker: whole batches) whose
+        # scheduling raised and were nacked for redelivery —
+        # nomad.workers.dispatch_failures.
+        self.dispatch_failures = 0
 
     # -- lifecycle --------------------------------------------------------
     def start(self) -> None:
@@ -121,6 +125,7 @@ class Worker:
                 else:
                     logger.exception("worker: failed to process eval %s",
                                      ev.id)
+                    self.dispatch_failures += 1
                 try:
                     self.server.eval_broker.nack(ev.id, token)
                 except ValueError:
@@ -236,19 +241,27 @@ class BatchWorker(Worker):
     """Drains ready evals in batches and fuses them on device."""
 
     def __init__(self, server, max_batch: int = 64) -> None:
+        from nomad_tpu.scheduler.batch import BatchEvalRunner
+
         super().__init__(server, scheduler_override=None)
         self.max_batch = max_batch
         self._tokens: dict = {}
+        # One runner for the worker's life (re-pointed at a fresh
+        # snapshot per batch), so its dispatch-mix counters accumulate
+        # and the server's registry can export them
+        # (nomad.batch_runner.*).
+        self.runner = BatchEvalRunner(
+            None, _BatchPlanner(self),
+            state_refresh=lambda: self.server.fsm.state.snapshot())
 
     # The fused device runner implements generic (service/batch) semantics;
     # system and _core evals go to the plain workers.
     DEVICE_QUEUES = ("service", "batch")
 
     def run(self) -> None:
-        from nomad_tpu.scheduler.batch import BatchEvalRunner
-
         backoff = Backoff(base=BACKOFF_BASE, max_delay=BACKOFF_LIMIT,
                           jitter=0.5)
+        runner = self.runner
         while not self._stop.is_set():
             self._check_paused()
             queues = [q for q in self.server.enabled_schedulers()
@@ -282,20 +295,20 @@ class BatchWorker(Worker):
                 continue
 
             self._tokens = {ev.id: token for ev, token in batch}
-            state = self.server.fsm.state.snapshot()
-            runner = BatchEvalRunner(
-                state, _BatchPlanner(self),
-                state_refresh=lambda: self.server.fsm.state.snapshot())
+            runner.state = self.server.fsm.state.snapshot()
             try:
                 runner.process([ev for ev, _ in batch])
             except Exception:
                 logger.exception("batch worker: dispatch failed")
+                self.dispatch_failures += 1
                 for ev, token in batch:
                     try:
                         self.server.eval_broker.nack(ev.id, token)
                     except ValueError:
                         pass
                 continue
+            finally:
+                runner.state = None  # don't pin a store generation idle
             for ev, token in batch:
                 try:
                     self.server.eval_broker.ack(ev.id, token)

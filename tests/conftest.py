@@ -24,6 +24,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 
 jax.config.update("jax_default_device", jax.devices("cpu")[0])
+# Agents place the persistent compile cache at boot
+# (parallel/devices.configure_compile_cache); the suite's thousands of
+# sub-second CPU compiles gain nothing from hashing and writing them.
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 def pytest_configure(config):

@@ -10,12 +10,14 @@ import numpy as np
 import pytest
 
 from nomad_tpu.parallel.devices import (
+    configure_compile_cache,
     current_platform,
     default_device,
     default_platform,
     default_platform_devices,
     ensure_on_default,
     on_default_platform,
+    transient_device_fault,
 )
 
 
@@ -76,3 +78,62 @@ def test_usage_mirror_survives_repin(restore_pin):
     jax.config.update("jax_default_device", cpus[-1])
     cap2, res2 = fleet.device_capacity_reserved()
     assert cap2 is cap_d and res2 is res_d
+
+
+def test_compile_cache_env_dir_is_left_to_jax(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself: the
+    function names that directory and sets nothing in code."""
+    prior = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert configure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == prior
+
+
+def test_compile_cache_default_is_fixed_in_checkout(monkeypatch):
+    """Unset, the cache goes to <checkout>/.jax_cache — a fixed path
+    (it is part of the cache key), git-ignored, never a temp name."""
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    prior = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        assert configure_compile_cache() == configure_compile_cache() \
+            == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prior)
+    with open(os.path.join(repo, ".gitignore")) as fh:
+        assert ".jax_cache/" in fh.read().split()
+
+
+def test_compiler_refusal_is_not_a_transient_fault():
+    """The breaker and the window verify absorb RUNTIME device faults
+    only; a compiler refusal (and any non-runtime exception type) must
+    propagate instead of parking the work on the host twin."""
+    from nomad_tpu.faultinject import FaultDropped, FaultInjected
+
+    # Verbatim from a TPU v5e (PERF.md, bring-up): what its compiler
+    # says to a program that cannot fit.
+    refusal = jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Allocation (size=360038400000) would "
+        "exceed memory (size=17179869184) :: #allocation5 [shape = "
+        "'f32[300000,300000]{1,0:T(8,128)}', space=hbm, size = "
+        "0xffffffffffffffff, tag = 'output of "
+        "broadcast_multiply_fusion@{}'] :: <no-hlo-instruction>")
+    assert not transient_device_fault(refusal)
+    assert not transient_device_fault(jax.errors.JaxRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel"))
+    # ... and what the runtime says to a buffer that does not fit.
+    assert not transient_device_fault(ValueError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting "
+        "to allocate 4.00G. That was not possible. There are 3.75G "
+        "free.; (0x0x0_HBM0)"))
+    assert not transient_device_fault(TypeError("bad operand"))
+    assert not transient_device_fault(NotImplementedError("lowering"))
+    assert transient_device_fault(jax.errors.JaxRuntimeError(
+        "INTERNAL: device halted"))
+    assert transient_device_fault(FaultInjected("device.dispatch"))
+    assert transient_device_fault(FaultDropped("lost frame"))
+    assert transient_device_fault(TimeoutError("collect deadline"))

@@ -141,19 +141,44 @@ class TestPolicyResolution:
         with pytest.raises(ExecutorPolicyError):
             Server(ServerConfig(num_schedulers=0))
 
+    def test_second_server_installs_its_own_policy(self):
+        """The policy is process-wide, so every Server installs its
+        configured value — a default-config server booted after an
+        executor="device" one must run ``auto``, not inherit."""
+        from nomad_tpu.server import Server, ServerConfig
+
+        try:
+            Server(ServerConfig(num_schedulers=0,
+                                executor="device")).shutdown()
+            assert executor_policy() == EXECUTOR_DEVICE
+            Server(ServerConfig(num_schedulers=0)).shutdown()
+            assert executor_policy() == EXECUTOR_AUTO
+        finally:
+            set_executor_policy(EXECUTOR_AUTO)
+
     def test_batch_runner_honors_force(self):
         """The fused batch path (BatchEvalRunner) obeys the same
         override: forced device must produce the same committed allocs
-        as forced host."""
+        as forced host — and its dispatch mix says which engine ran."""
         from nomad_tpu.scheduler.batch import BatchEvalRunner
 
         placed = {}
+        mix = {}
         for executor in (EXECUTOR_HOST, EXECUTOR_DEVICE):
             h, jobs = _cluster(10, 4)
+            runner = BatchEvalRunner(h.state.snapshot(), h,
+                                     state_refresh=h.snapshot)
             with executor_override(executor):
-                BatchEvalRunner(
-                    h.state.snapshot(), h,
-                    state_refresh=h.snapshot).process(
-                    [make_eval(j) for j in jobs])
+                runner.process([make_eval(j) for j in jobs])
             placed[executor] = _plan_shape(h)
+            mix[executor] = runner.stats()
         assert placed[EXECUTOR_HOST] == placed[EXECUTOR_DEVICE]
+        # Host: one twin call per lane, nothing on the device.  Device:
+        # one fused call for all lanes, nothing on the twin; on the
+        # suite's 8-device platform it rides the mesh.
+        assert mix[EXECUTOR_HOST]["host_dispatches"] == len(jobs)
+        assert mix[EXECUTOR_HOST]["device_dispatches"] == 0
+        assert mix[EXECUTOR_DEVICE]["host_dispatches"] == 0
+        assert mix[EXECUTOR_DEVICE]["device_dispatches"] == \
+            mix[EXECUTOR_DEVICE]["fused_batches"] == 1
+        assert mix[EXECUTOR_DEVICE]["sharded_dispatches"] == 1

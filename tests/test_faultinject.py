@@ -434,6 +434,37 @@ class TestDeviceBreaker:
         assert all(e.status == "complete" for e in h.evals)
         assert len(h.plans) == 3
 
+    def test_compile_refusal_propagates_past_the_breaker(self,
+                                                         monkeypatch):
+        """A dispatch the chip's compiler refuses is deterministic, not
+        a transient fault: it must surface to the caller instead of
+        re-running on the host twin and tripping the breaker."""
+        import jax
+
+        from nomad_tpu.scheduler.breaker import (CLOSED,
+                                                 DeviceCircuitBreaker)
+        from nomad_tpu.scheduler.executor import executor_override
+        from nomad_tpu.scheduler.jax_binpack import JaxBinPackScheduler
+        from nomad_tpu.scheduler.pipeline import PipelinedEvalRunner
+
+        def refuse(self, args, pipelined=False, force=False):
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: Allocation (size=360038400000) "
+                "would exceed memory (size=17179869184)")
+
+        monkeypatch.setattr(JaxBinPackScheduler, "dispatch_device", refuse)
+        h, jobs = _pipeline_cluster(8, 2)
+        breaker = DeviceCircuitBreaker(failure_threshold=1, cooldown=30.0)
+        runner = PipelinedEvalRunner(h.state.snapshot(), h, depth=2,
+                                     breaker=breaker)
+        with executor_override("device"), \
+                pytest.raises(jax.errors.JaxRuntimeError,
+                              match="would exceed memory"):
+            runner.process([_make_eval(j) for j in jobs])
+        assert breaker.state == CLOSED
+        assert breaker.stats()["failures"] == 0
+        assert runner.breaker_reruns == 0 and not h.plans
+
     def test_collect_fault_reruns_on_host(self):
         """device.collect fault mid-window: drain re-runs that eval on
         the host twin; plans still land, breaker records the failure."""
@@ -552,11 +583,9 @@ class TestDeviceBreaker:
         assert b.state == CLOSED
 
     def test_probe_parity_mismatch_fails_loudly_and_reopens(self):
-        """Review regression: a probe whose device result disagrees
-        with the host twin must raise (not silently close the breaker)
-        and re-open it."""
-        import numpy as np
-
+        """Review regression: a probe whose device result the host
+        scorer rejects must raise (not silently close the breaker) and
+        re-open it."""
         from nomad_tpu.scheduler.breaker import OPEN, DeviceCircuitBreaker
         from nomad_tpu.scheduler.executor import executor_override
         from nomad_tpu.scheduler.pipeline import PipelinedEvalRunner
@@ -565,18 +594,63 @@ class TestDeviceBreaker:
         breaker = DeviceCircuitBreaker(failure_threshold=1, cooldown=0.0)
         breaker.record_failure()  # open; next admission is a probe
 
-        class _CorruptHostTwin(PipelinedEvalRunner):
-            def _host_rerun(self, it):
-                chosen, scores = super()._host_rerun(it)
-                return np.asarray(chosen) + 1, scores  # disagree
+        class _CorruptDevice(PipelinedEvalRunner):
+            def _collect_device_bounded(self, it):
+                chosen, scores = super()._collect_device_bounded(it)
+                chosen = chosen.copy()
+                chosen[1] = chosen[0]  # two copies stacked on one node
+                return chosen, scores
 
-        runner = _CorruptHostTwin(h.state.snapshot(), h, depth=2,
-                                  breaker=breaker)
+        runner = _CorruptDevice(h.state.snapshot(), h, depth=2,
+                                breaker=breaker)
         with executor_override("device"):
             with pytest.raises(RuntimeError, match="parity violation"):
                 runner.process([_make_eval(jobs[0])])
         assert breaker.state == OPEN  # probe failure re-opened it
         assert runner.parity_checks == 0
+
+    def test_probe_contract_follows_the_device_trajectory(self):
+        """On a TPU 10^x rounds differently from numpy, so near-tied
+        nodes may swap between the engines and their usage trajectories
+        part ways (measured on a v5e: PERF.md, bring-up).  The probe
+        therefore asks the host scorer to rank each device pick at the
+        step the device made it: a tied node in place of the twin's is
+        fine; a clearly worse node, a node outside the fleet, or a copy
+        left unplaced is not — in both kernel modes."""
+        import numpy as np
+
+        from nomad_tpu.scheduler.jax_binpack import JaxBinPackScheduler
+        from nomad_tpu.scheduler.pipeline import probe_agrees
+
+        h, jobs = _pipeline_cluster(8, 1)
+        sched = JaxBinPackScheduler(h.state.snapshot(), h, batch=False)
+        sched.eval = _make_eval(jobs[0])
+        sched.defer_device = True
+        sched._begin()
+        _place, args = sched.deferred
+        assert args.rounds_eligible
+        twin, _scores = sched.collect_device(args, sched.dispatch_host(args))
+        n_place = args.n_place
+        assert (twin[:n_place] >= 0).all()
+        for rounds_mode in (True, False):
+            args.rounds_eligible = rounds_mode
+            assert probe_agrees(args, twin)
+            # The empty homogeneous fleet ties exactly: the two copies
+            # trading nodes is the swap a TPU's rounding produces.
+            swapped = twin.copy()
+            swapped[[0, 1]] = twin[[1, 0]]
+            assert probe_agrees(args, swapped)
+            # Stacking two copies on one node forfeits the 10-point
+            # anti-affinity term: far outside any rounding.
+            stacked = twin.copy()
+            stacked[1] = twin[0]
+            assert not probe_agrees(args, stacked)
+            outside = twin.copy()
+            outside[0] = args.statics.n_real  # a padding row
+            assert not probe_agrees(args, outside)
+            dropped = twin.copy()
+            dropped[n_place - 1] = -1
+            assert not probe_agrees(args, dropped)
 
     def test_pipeline_unaffected_without_faults(self):
         """No plan, forced device: the breaker stays closed and counts

@@ -559,6 +559,46 @@ class TestDeviceVerifyParity:
         assert store_image(s_seq) == store_image(s_host) \
             == store_image(s_dev)
 
+    def test_overlay_fold_is_plain_float32_adds(self):
+        """The window kernel's verdicts are byte-identical to the host
+        walk only while every sum is a plain float32 add.  A matmul
+        runs as a bf16 pass on a TPU at default precision — it would
+        read an ask of 1299 MHz as 1296 — and the CPU cannot show that
+        rounding, so the structure is pinned: no dot in the traced
+        kernel, and asks no bf16 pass could carry fold exactly."""
+        import jax
+        import numpy as np
+
+        from nomad_tpu.parallel.mesh import _window_verify_jit
+
+        n, bucket = 8, 8
+        capacity = np.zeros((n, 6), dtype=np.float32)
+        capacity[:, :4] = 3900
+        zeros = np.zeros((n, 6), dtype=np.float32)
+        # Node 0: 1299 + 1299 + 1303 = 3901 (the third must not fit);
+        # node 1: 1301 + 1301 + 1298 = 3900 (the third fits exactly).
+        asks = [(0, 1299), (1, 1301), (0, 1299), (1, 1301),
+                (0, 1303), (1, 1298)]
+        pair_ni = np.zeros(bucket, dtype=np.int32)
+        row_vec = np.zeros((bucket, 4), dtype=np.float32)
+        for k, (ni, cpu) in enumerate(asks):
+            pair_ni[k] = ni
+            row_vec[k, 0] = cpu
+        order = np.arange(bucket, dtype=np.int32)
+        comp = np.zeros(bucket, dtype=np.int32)
+        seq_ni = pair_ni.copy()
+        seq_ni[len(asks):] = -1
+        args = (capacity, zeros, zeros, pair_ni, order, row_vec, seq_ni,
+                row_vec, order, comp, order, comp,
+                np.zeros((bucket, 4), dtype=np.float32))
+        assert "dot_general" not in str(
+            jax.make_jaxpr(_window_verify_jit)(*args))
+        used, _caps, fits = _window_verify_jit(*args)
+        assert np.asarray(used)[:6, 0].tolist() == \
+            [float(cpu) for _ni, cpu in asks]
+        assert np.asarray(fits)[:6].tolist() == \
+            [True, True, True, True, False, True]
+
     def test_device_info_and_fallback_taxonomy(self):
         """The window info record: host policy reports no device entry,
         a cold device window reports the lease-miss fallback, a warmed

@@ -597,26 +597,29 @@ class TestServerEndToEnd:
         finally:
             srv.shutdown()
 
-    def test_device_unavailable_falls_back_to_sequential(self,
-                                                         monkeypatch):
-        """A broken device backend degrades to the sequential schedulers
-        instead of failing every eval into the delivery-limit reaper."""
-        import nomad_tpu.scheduler as sched_registry
+    def test_device_unavailable_fails_the_boot(self, monkeypatch):
+        """A server asked for the device scheduler on a host whose JAX
+        backend cannot hand out devices does not boot — degrading to
+        the sequential schedulers would hide the missing chip behind a
+        working, slow cluster.  use_device_scheduler=False is how an
+        operator asks for them."""
+        from nomad_tpu.parallel import devices
         from nomad_tpu.server.worker import BatchWorker
 
-        monkeypatch.setattr(sched_registry, "device_available",
-                            lambda: False)
-        srv = make_server(use_device_scheduler=True)
+        def no_backend():
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        monkeypatch.setattr(devices, "default_platform_devices",
+                            no_backend)
+        before = threading.active_count()
+        with pytest.raises(RuntimeError, match="initialize backend"):
+            Server(ServerConfig(num_schedulers=2))
+        # Refused before any thread or socket of the server existed.
+        assert threading.active_count() == before
+        srv = make_server(use_device_scheduler=False)
         try:
-            assert not srv.config.use_device_scheduler
             assert not any(isinstance(w, BatchWorker)
                            for w in srv.workers)
-            srv.node_register(mock.node(0))
-            job = mock.job()
-            _, eval_id = srv.job_register(job)
-            statuses = srv.wait_for_evals([eval_id], timeout=15)
-            assert statuses[eval_id] == "complete"
-            assert srv.fsm.state.allocs_by_job(job.id)
         finally:
             srv.shutdown()
 

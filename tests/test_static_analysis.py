@@ -38,6 +38,36 @@ from nomad_tpu.analysis.sanitizers import (
     RecompileSentinel,
 )
 
+_PACKAGE_LINT: list = []
+
+
+def package_lint() -> list:
+    """``run_lint(strict=True)`` over the real package, computed once
+    per session: the tree does not change while the suite runs, and the
+    dozen "rides the gates" tests below each paid the whole ~5 s pass
+    for the same findings — a minute of a tier-1 gate that is already
+    kill-bound (ROADMAP D5).  ``test_package_is_clean`` and the timing
+    budget test still run the real pass themselves."""
+    if not _PACKAGE_LINT:
+        _PACKAGE_LINT.append(run_lint(strict=True))
+    return list(_PACKAGE_LINT[0])
+
+
+_PACKAGE_GRAPH: list = []
+
+
+def package_graph():
+    """The real package's interprocedural call graph, built once per
+    session for the same reason.  The passes share one graph inside
+    ``run_lint`` too, so handing the gate tests a shared instance asks
+    nothing new of them."""
+    from nomad_tpu.analysis import default_package_root
+    from nomad_tpu.analysis.callgraph import CallGraph
+
+    if not _PACKAGE_GRAPH:
+        _PACKAGE_GRAPH.append(CallGraph.build(default_package_root()))
+    return _PACKAGE_GRAPH[0]
+
 
 def write_pkg(tmp_path, name, source) -> str:
     d = tmp_path / name
@@ -179,7 +209,7 @@ class TestLintGate:
         from nomad_tpu.analysis.callgraph import CallGraph
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         assert any(q.startswith("nomad_tpu.ops.plan_conflict:")
                    for q in graph.functions), \
             "plan_conflict.py missing from the interprocedural graph"
@@ -188,7 +218,7 @@ class TestLintGate:
         assert "nomad_tpu.state.store:StateStore.upsert_allocs_batched" \
             in graph.functions
 
-        findings = run_lint(strict=True)
+        findings = package_lint()
         touching = [f for f in findings
                     if "plan_conflict" in f.path
                     or "_apply_plan_batch" in f.render()
@@ -213,7 +243,7 @@ class TestLintGate:
         from nomad_tpu.analysis.callgraph import CallGraph
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.server.overload:OverloadController.admit",
             "nomad_tpu.server.overload:TokenBucket.try_take",
@@ -226,7 +256,7 @@ class TestLintGate:
             assert qual in graph.functions, \
                 f"{qual} missing from the interprocedural graph"
 
-        findings = run_lint(strict=True)
+        findings = package_lint()
         touching = [f for f in findings
                     if "overload" in f.path or "ttlwheel" in f.path
                     or "heartbeat" in f.path]
@@ -251,7 +281,7 @@ class TestLintGate:
         from nomad_tpu.analysis import default_package_root
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.server.mux:EdgeLoop._run",
             "nomad_tpu.server.mux:EdgeLoop._close",
@@ -271,7 +301,7 @@ class TestLintGate:
 
         allowlist = load_allowlist(default_allowlist_path())
         gating, _allowed, _stale = partition_findings(
-            run_lint(strict=True), allowlist)
+            package_lint(), allowlist)
         touching = [f for f in gating
                     if "server/mux" in f.path or "agent/swarm" in f.path
                     or "server/rpc" in f.path
@@ -299,7 +329,7 @@ class TestLintGate:
         from nomad_tpu.analysis.callgraph import CallGraph
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.server.raft:FileLogStore.append",
             "nomad_tpu.server.raft:FileLogStore._scan_and_recover",
@@ -319,7 +349,7 @@ class TestLintGate:
 
         allowlist = load_allowlist(default_allowlist_path())
         gating, _allowed, _stale = partition_findings(
-            run_lint(strict=True), allowlist)
+            package_lint(), allowlist)
         touching = [f for f in gating
                     if "server/raft" in f.path
                     or "faultinject/crash" in f.path]
@@ -347,7 +377,7 @@ class TestLintGate:
         from nomad_tpu.analysis.callgraph import CallGraph
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.parallel.mesh:dispatch_mesh",
             "nomad_tpu.models.fleet:ShardedResidency.install",
@@ -365,7 +395,7 @@ class TestLintGate:
 
         allowlist = load_allowlist(default_allowlist_path())
         gating, _allowed, _stale = partition_findings(
-            run_lint(strict=True), allowlist)
+            package_lint(), allowlist)
         touching = [f for f in gating
                     if "parallel/" in f.path or "models/" in f.path
                     or "node_slab" in f.path]
@@ -388,7 +418,7 @@ class TestLintGate:
         from nomad_tpu.analysis.callgraph import CallGraph
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.structs.alloc_slab:AllocSlab.wire",
             "nomad_tpu.structs.alloc_slab:AllocSlab.from_wire",
@@ -410,7 +440,7 @@ class TestLintGate:
 
         allowlist = load_allowlist(default_allowlist_path())
         gating, _allowed, _stale = partition_findings(
-            run_lint(strict=True), allowlist)
+            package_lint(), allowlist)
         touching = [f for f in gating if "alloc_slab" in f.path]
         assert touching == [], \
             "columnar contract must lint clean:\n" + \
@@ -431,7 +461,7 @@ class TestLintGate:
         from nomad_tpu.analysis.callgraph import CallGraph
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.obs.trace:Tracer.record",
             "nomad_tpu.obs.trace:Tracer.snapshot",
@@ -451,7 +481,7 @@ class TestLintGate:
 
         allowlist = load_allowlist(default_allowlist_path())
         gating, _allowed, _stale = partition_findings(
-            run_lint(strict=True), allowlist)
+            package_lint(), allowlist)
         touching = [f for f in gating if "nomad_tpu/obs" in f.path
                     or f.path.startswith("obs/") or "/obs/" in f.path]
         assert touching == [], \
@@ -477,7 +507,7 @@ class TestLintGate:
         from nomad_tpu.analysis.callgraph import CallGraph
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.ops.plan_conflict:partition_window",
             "nomad_tpu.ops.plan_conflict:_walk_component",
@@ -501,7 +531,7 @@ class TestLintGate:
 
         allowlist = load_allowlist(default_allowlist_path())
         gating, _allowed, _stale = partition_findings(
-            run_lint(strict=True), allowlist)
+            package_lint(), allowlist)
         touching = [f for f in gating
                     if "plan_conflict" in f.path
                     or "plan_apply" in f.path
@@ -533,7 +563,7 @@ class TestLintGate:
         from nomad_tpu.analysis.callgraph import CallGraph
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.control.controller:Actuator.apply",
             "nomad_tpu.control.controller:Actuator.pin",
@@ -556,7 +586,7 @@ class TestLintGate:
 
         allowlist = load_allowlist(default_allowlist_path())
         gating, _allowed, _stale = partition_findings(
-            run_lint(strict=True), allowlist)
+            package_lint(), allowlist)
         touching = [f for f in gating if "control/" in f.path
                     or "nomad_tpu/control" in f.path]
         assert touching == [], \
@@ -643,7 +673,7 @@ class TestLintGate:
         from nomad_tpu.analysis.callgraph import CallGraph
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.scheduler.jax_binpack:"
             "JaxBinPackScheduler.dispatch_device",
@@ -697,7 +727,7 @@ class TestLintGate:
                                                    TRANSFER_SEAMS)
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.parallel.mesh:window_verify_sharded",
             "nomad_tpu.ops.plan_conflict:_dispatch_window_fit",
@@ -734,7 +764,7 @@ class TestLintGate:
 
         allowlist = load_allowlist(default_allowlist_path())
         gating, _allowed, _stale = partition_findings(
-            run_lint(strict=True), allowlist)
+            package_lint(), allowlist)
         touching = [f for f in gating
                     if "plan_conflict" in f.path
                     or "verify_policy" in f.path
@@ -779,7 +809,7 @@ class TestLintGate:
         from nomad_tpu.server.endpoints import CONSISTENT_READS
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         for qual in (
             "nomad_tpu.server.fsm:NomadFSM.apply",
             "nomad_tpu.server.fsm:NomadFSM.restore",
@@ -903,7 +933,7 @@ class TestLintGate:
         from nomad_tpu.faultinject.plan import SITES
 
         pkg = default_package_root()
-        graph = CallGraph.build(pkg)
+        graph = package_graph()
         # The failure-plane roots the passes hinge on must exist in the
         # interprocedural graph (a rename would silently hollow the
         # gate out).
