@@ -21,9 +21,12 @@ kernel once at the smoke's shapes and compares it with its numpy twin;
 and, when it sees more than one chip, checks the sharded family, the
 mesh-resident twins and the device window verify.
 
-Any failed check raises; nothing records an error and carries on.  The
-last line of stdout is one JSON object, printed only when every check
-passed (progress goes to stderr).  ``--seed`` makes all data.
+Any failed check raises; nothing records an error and carries on.
+Stdout is two lines, printed only when every check passed (progress
+goes to stderr): the report — one JSON object with every fact above —
+and, last, the verdict ``{"ok": ..., "device": {"platform", "kind",
+"count"}}`` with the device as JAX reports it.  ``--seed`` makes all
+data.
 """
 from __future__ import annotations
 
@@ -1038,7 +1041,9 @@ def main() -> int:
         str(d): (d.memory_stats() or {}).get("peak_bytes_in_use")
         for d in devices}
     result["wall_s"] = round(time.perf_counter() - wall0, 1)
-    print(json.dumps(result), flush=True)
+    print(json.dumps(result))
+    print(json.dumps({"ok": result["ok"], "device": result["device"]}),
+          flush=True)
     return 0
 
 

@@ -62,9 +62,15 @@ def runs(tmp_path_factory):
 def test_rehearsal_runs_every_phase_and_never_reads_as_a_pass(runs):
     rc, stdout, stderr = runs["rehearsal"]
     assert rc == 0, (stdout[-2000:], stderr[-4000:])
-    out = json.loads(stdout.strip().splitlines()[-1])
+    # Stdout is the report, then the verdict in exactly the shape the
+    # chip check reads.
+    report_line, verdict_line = stdout.strip().splitlines()
+    assert json.loads(verdict_line) == {
+        "ok": False,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 4}}
+    out = json.loads(report_line)
     assert out["ok"] is False and out["rehearsal"] is True
-    assert out["device"] == {"platform": "cpu", "kind": "cpu", "count": 4}
+    assert out["device"] == json.loads(verdict_line)["device"]
     assert out["native"]["built_from_source"] is True
     assert out["compile_cache"]["dir"] == os.path.join(REPO, ".jax_cache")
     for phase in ("phase_a", "phase_b"):
