@@ -28,6 +28,8 @@ from http.server import BaseHTTPRequestHandler
 from typing import Optional
 from urllib.parse import parse_qs, urlparse
 
+from nomad_tpu.obs import trace as trace_mod
+from nomad_tpu.server.endpoints import take_fired
 from nomad_tpu.server.mux import DispatchPool
 from nomad_tpu.utils.duration import parse_duration
 
@@ -42,6 +44,44 @@ _SHED_503 = (b"HTTP/1.1 503 Service Unavailable\r\n"
              b"Content-Length: 22\r\nConnection: close\r\n"
              b"Content-Type: application/json\r\n\r\n"
              b'{"error":"overloaded"}')
+
+
+# Span names of the HTTP edge: ``http.serve.<route key>``.  The key is
+# the resource and the verb, never the raw path (ids would make the
+# name's cardinality unbounded).
+_RESOURCES = {"jobs": "job", "job": "job", "nodes": "node", "node": "node",
+              "allocations": "alloc", "allocation": "alloc",
+              "evaluations": "eval", "evaluation": "eval"}
+_SUBRESOURCES = frozenset({"allocations", "evaluations", "evaluate",
+                           "drain"})
+_AGENT_ROUTES = frozenset({"self", "metrics", "monitor", "members",
+                           "servers", "join", "force-leave", "pprof",
+                           "profile", "trace", "leader", "peers"})
+
+
+def route_key(method: str, path: str) -> str:
+    """Low-cardinality name of the route ``path`` resolves to:
+    ``job_register``, ``eval_get``, ``node_allocations``,
+    ``agent_metrics``, ... and ``other`` for what the table lacks."""
+    parts = [p for p in path.split("?", 1)[0].split("/") if p][1:]
+    if not parts:
+        return "other"
+    resource = _RESOURCES.get(parts[0])
+    if resource is None:
+        if parts[0] in ("agent", "status") and len(parts) == 2 \
+                and parts[1] in _AGENT_ROUTES:
+            return f"{parts[0]}_{parts[1].replace('-', '_')}"
+        return "other"
+    if len(parts) == 3:
+        return f"{resource}_{parts[2]}" if parts[2] in _SUBRESOURCES \
+            else "other"
+    if len(parts) > 3:
+        return "other"
+    if method in ("PUT", "POST"):
+        return resource + "_register"
+    if method == "DELETE":
+        return resource + "_deregister"
+    return resource + ("_list" if len(parts) == 1 else "_get")
 
 
 class BadRequest(Exception):
@@ -111,6 +151,7 @@ class HTTPServer:
                 body = json.dumps(payload,
                                   indent=4 if pretty else None
                                   ).encode() + b"\n"
+                self._code = code
                 self.send_response(code)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(body)))
@@ -120,6 +161,17 @@ class HTTPServer:
                 self.wfile.write(body)
 
             def _handle(self) -> None:
+                # tracer() re-checked for None behind the gate: a
+                # concurrent disable() degrades the request to untraced.
+                tracer = trace_mod.tracer() if trace_mod.ENABLED else None
+                if tracer is None:
+                    self._serve()
+                else:
+                    outer._serve_traced(self, tracer)
+
+            def _serve(self):
+                """Parse, route, answer; the payload of a 2xx answer
+                is handed back for the traced wrapper's tags."""
                 url = urlparse(self.path)
                 query = {k: v[0] for k, v in
                          parse_qs(url.query, keep_blank_values=True
@@ -143,6 +195,7 @@ class HTTPServer:
                         with outer._pool.blocking():
                             code, payload, index = outer.route(
                                 self.command, url.path, query, body)
+                        self._wake = outer._note_wake(query, payload)
                     else:
                         code, payload, index = outer.route(
                             self.command, url.path, query, body)
@@ -161,8 +214,11 @@ class HTTPServer:
                     return
                 self._respond(code, payload, pretty="pretty" in query,
                               index=index)
+                return payload
 
             do_GET = do_PUT = do_POST = do_DELETE = _handle
+            _code = 0       # status of the last response written
+            _wake = None    # (fired, changed) of an answered blocking read
 
         self._handler_cls = _Handler
         listener = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
@@ -193,6 +249,16 @@ class HTTPServer:
         self.conn_sheds = 0
         self.closed_idle = 0
         self.closed_deadline = 0
+        # Blocking reads of ONE object (?index=N on a job, node, alloc
+        # or eval) that an index change woke, and those of them whose
+        # object had itself changed: the rest were woken by a write to
+        # some other row of the watched table.  Worker threads.
+        self._wake_lock = threading.Lock()
+        self.blocking_wakes = 0
+        self.blocking_wakes_changed = 0
+        # Tracing only: (tracer, when the selector saw the socket
+        # readable) for the request a worker is about to parse.
+        self._local = threading.local()
 
     def start(self) -> None:
         self._pool.start()
@@ -215,6 +281,8 @@ class HTTPServer:
                 "conn_sheds": self.conn_sheds,
                 "closed_idle": self.closed_idle,
                 "closed_deadline": self.closed_deadline,
+                "blocking_wakes": self.blocking_wakes,
+                "blocking_wakes_changed": self.blocking_wakes_changed,
                 "pool": self._pool.stats()}
 
     # -- the edge loop ------------------------------------------------------
@@ -335,17 +403,22 @@ class HTTPServer:
         except (KeyError, ValueError):
             pass
         self._conns.pop(sock.fileno(), None)
-        if not self._pool.submit(lambda: self._serve_one(sock, addr)):
+        tracer = trace_mod.tracer() if trace_mod.ENABLED else None
+        ready = (tracer, tracer.now()) if tracer is not None else None
+        if not self._pool.submit(
+                lambda: self._serve_one(sock, addr, ready)):
             try:
                 sock.send(_SHED_503)
             except OSError:
                 pass
             self._drop(sock)
 
-    def _serve_one(self, sock: socket.socket, addr) -> None:
+    def _serve_one(self, sock: socket.socket, addr, ready=None) -> None:
         """Worker: parse and answer ONE request, then re-park or close.
         The handler's socket timeout bounds a stalled mid-request
         client, so a slowloris costs a worker at most read_deadline."""
+        if ready is not None:
+            self._local.ready = ready
         try:
             sock.setblocking(True)
             handler = self._handler_cls(sock, addr, self)
@@ -381,6 +454,60 @@ class HTTPServer:
             sock.close()
         except OSError:
             pass
+
+    # -- counters, spans ---------------------------------------------------
+    def _note_wake(self, query: dict, payload) -> tuple:
+        """(fired, changed) of one answered blocking query: ``fired`` is
+        why the server's blocking wrapper answered (``take_fired``; None
+        for a read another server answered), ``changed`` whether the
+        ``modify_index`` of the ONE object read passed the query's
+        index (None for a list).  Counts the single-object reads an
+        index change woke."""
+        fired = take_fired()
+        if not isinstance(payload, dict) or "modify_index" not in payload:
+            return fired, None
+        changed = int(payload["modify_index"] > int(query["index"]))
+        if fired == "index":
+            with self._wake_lock:
+                self.blocking_wakes += 1
+                self.blocking_wakes_changed += changed
+        return fired, changed
+
+    def _serve_traced(self, handler, tracer) -> None:
+        """One request under its ``http.serve.<route key>`` span: from
+        the moment the selector saw the socket readable (the first
+        request of a dispatch; a pipelined one starts at its parse) to
+        the response written.  The span roots the request's trace and
+        is ambient for ``route``, so the RPC spans and any eval created
+        hang under it.  Tags: ``code``, ``blocking``, ``pool_wait_ms``
+        (readable -> a worker picked it up); for a blocking read of one
+        object ``fired`` and ``changed``; for ``eval_get`` also
+        ``eval_id`` and ``eval_status``."""
+        t_in = tracer.now()
+        ready = self._local.__dict__.pop("ready", None)
+        t0 = ready[1] if ready is not None and ready[0] is tracer else t_in
+        mine = {"trace_id": tracer.new_id(), "span_id": tracer.new_id()}
+        handler._wake = payload = None
+        try:
+            with tracer.attach(mine):
+                payload = handler._serve()
+        finally:
+            key = route_key(handler.command, handler.path)
+            wake = handler._wake
+            tags = {"code": handler._code, "blocking": int(wake is not None),
+                    "pool_wait_ms": 1e3 * (t_in - t0)}
+            if isinstance(payload, dict) and "modify_index" in payload:
+                # One object read: a plain read answers at once with
+                # whatever is there, which "changed" since index 0.
+                fired, changed = wake or (take_fired(), 1)
+                tags.update(fired=fired or "immediate", changed=changed)
+                if key == "eval_get":
+                    tags.update(eval_id=payload.get("id"),
+                                eval_status=payload.get("status"))
+            tracer.record("http.serve." + key, t0, tracer.now() - t0,
+                          ctx={"trace_id": mine["trace_id"],
+                               "parent_id": None},
+                          span_id=mine["span_id"], **tags)
 
     # -- routing -----------------------------------------------------------
     def route(self, method: str, path: str, query: dict, body):
@@ -619,7 +746,7 @@ class HTTPServer:
             return 200, {}, None
 
         if parts and parts[0] == "agent" and \
-                parts[1:2] in (["pprof"], ["profile"]):
+                parts[1:2] in (["pprof"], ["profile"], ["trace"]):
             # Debug introspection, mounted only when enable_debug is set
             # (reference http.go:115-120 pprof under enableDebug).
             if not agent.config.enable_debug:
@@ -630,6 +757,32 @@ class HTTPServer:
             if parts[1] == "pprof":
                 return 200, {"stacks": profiling.thread_stacks()}, None
             action = query.get("action", "")
+            if parts[1] == "trace":
+                # The span recorder (obs/trace.py): start with a ring
+                # size and an optional id seed, dump the Chrome-trace /
+                # Perfetto document, stop.
+                tracer = trace_mod.tracer()
+                if action == "start":
+                    if tracer is not None:
+                        raise BadRequest("span recorder already on")
+                    try:
+                        ring = int(query.get("ring",
+                                             trace_mod.DEFAULT_RING))
+                        seed = int(query["seed"]) if "seed" in query \
+                            else None
+                        trace_mod.enable(seed=seed, ring=ring)
+                    except ValueError as e:
+                        raise BadRequest(str(e)) from e
+                    return 200, {"tracing": True, "ring": ring}, None
+                if tracer is None:
+                    raise BadRequest("span recorder is off")
+                if action == "dump":
+                    return 200, tracer.chrome_trace(), None
+                if action == "stop":
+                    trace_mod.disable()
+                    return 200, {"tracing": False,
+                                 "spans": tracer.stats()}, None
+                raise BadRequest("trace wants ?action=start|stop|dump")
             if action == "start":
                 log_dir = query.get("dir", "")
                 if not log_dir:
@@ -638,7 +791,16 @@ class HTTPServer:
                     profiling.start_device_trace(log_dir)
                 except RuntimeError as e:
                     raise BadRequest(str(e)) from e
-                return 200, {"tracing": log_dir}, None
+                # One capture, one clock: the span recorder runs beside
+                # the device trace (its device.dispatch spans open a
+                # TraceAnnotation of the same name inside the profile).
+                # It stays on after ?action=stop, for /v1/agent/trace
+                # to dump and stop.
+                spans = "already on"
+                if not trace_mod.ENABLED:
+                    trace_mod.enable()
+                    spans = "started"
+                return 200, {"tracing": log_dir, "spans": spans}, None
             if action == "stop":
                 try:
                     done = profiling.stop_device_trace()

@@ -1089,10 +1089,12 @@ def _scatter_rows(usage_d, idx: np.ndarray, rows: np.ndarray):
         rows = np.concatenate([rows, np.repeat(rows[:1], pad, axis=0)])
     import jax
 
-    from nomad_tpu.parallel.devices import note_transfer
+    from nomad_tpu.obs import trace as trace_mod
+    from nomad_tpu.parallel.devices import (NO_DISPATCH, device_dispatch,
+                                            note_transfer)
     sharding = getattr(usage_d, "sharding", None)
     mesh = getattr(sharding, "mesh", None)
-    note_transfer("h2d", 2)
+    note_transfer("h2d", 2, idx, rows)
     if mesh is not None and getattr(mesh, "axis_names", None):
         from jax.sharding import NamedSharding, PartitionSpec as P
         target = NamedSharding(mesh, P())  # replicated update batch
@@ -1102,7 +1104,11 @@ def _scatter_rows(usage_d, idx: np.ndarray, rows: np.ndarray):
     # devlint-ok(transfer-under-lock): bounded async update batch; must
     # stay atomic with the host swap (see docstring).
     idx_d, rows_d = jax.device_put(idx, target), jax.device_put(rows, target)
-    return _ensure_scatter_jit()(usage_d, idx_d, rows_d)
+    scatter = _ensure_scatter_jit()
+    with (device_dispatch(scatter, async_=True, rows=padded,
+                          n_pad=usage_d.shape[0])
+          if trace_mod.ENABLED else NO_DISPATCH):
+        return scatter(usage_d, idx_d, rows_d)
 
 
 def _scatter_jit_impl(usage, idx, rows):

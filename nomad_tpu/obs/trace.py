@@ -16,8 +16,10 @@ Design constraints, in order:
 - **Disabled = one module-bool check.**  Every instrumentation site in
   the runtime guards on ``trace.ENABLED`` (the same pattern as
   ``faultinject.ACTIVE``); with tracing off the hot path pays a single
-  global read.  bench.py asserts the tracing-ON config-4 stream stays
-  within 5% of off.
+  global read: no clock, no ``thread_time``, no tag dict and no
+  ``TraceAnnotation`` is built behind a false gate.  Tracing ON was
+  measured on the chip in both benchmark cells (PERF.md §6, PR 26):
+  jobs completed per window traced vs. untraced, span count, drops.
 - **Lock-cheap recording.**  Finished spans append to a per-thread
   buffer (plain ``list.append`` — owner-thread only, no lock) and drain
   into one bounded global ring under a single leaf lock every
@@ -55,6 +57,16 @@ Control-plane taxonomy (ISSUE 14): the feedback controller records one
 with a ``control.adjust`` child per moved knob (``knob``, ``old``,
 ``new``, ``gauge``, ``direction``, ``reversal``, ``rail``) — the
 decision trail that makes a tuning loop auditable after the fact.
+
+Whole-path taxonomy (ISSUE 26): ``http.serve.<route>`` roots every
+request served over HTTP (socket readable -> response written),
+``query.blocked`` times a blocking query's park,
+``server.apply.<msg>`` times every non-plan raft apply, ``sched.status`` the eval-status applies inside a lane's
+``sched.submit``, ``worker.batch`` (+ ``worker.dequeue/sync/snapshot/
+ack``) the fused runner's whole cycle, and ``device.dispatch``
+(parallel/devices.py) brackets every jitted program so the device
+trace's module events can be laid on this clock.  README
+"Observability" has the table; PERF.md §3 names each span's reader.
 
 Export is Chrome-trace JSON (``chrome://tracing`` / Perfetto "X"
 complete events), span tags riding in ``args``.

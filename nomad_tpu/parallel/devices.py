@@ -16,6 +16,7 @@ between the host data plane and the XLA device plane.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 
@@ -24,6 +25,7 @@ from typing import Optional
 import jax
 
 from nomad_tpu.faultinject import FaultInjected
+from nomad_tpu.obs import trace as trace_mod
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -87,12 +89,65 @@ def transient_device_fault(e: Exception) -> bool:
 
 _TRANSFER_LOCK = threading.Lock()
 _TRANSFERS = {"h2d": 0, "d2h": 0, "d2d": 0}
+# Bytes this thread moved through the counted seams since its last
+# ``device.dispatch`` span closed (tracing only): a dispatch's uploads
+# happen before the program is enqueued, its fetch after.
+_moved = threading.local()
 
 
-def note_transfer(kind: str, n: int = 1) -> None:
-    """Count ``n`` explicit transfers of ``kind`` ("h2d"/"d2h"/"d2d")."""
+def note_transfer(kind: str, n: int = 1, *moved) -> None:
+    """Count ``n`` explicit transfers of ``kind`` ("h2d"/"d2h"/"d2d").
+    ``moved`` are the arrays themselves: while tracing is on their bytes
+    accrue to the calling thread's next ``device.dispatch`` span."""
     with _TRANSFER_LOCK:
         _TRANSFERS[kind] += n
+    if trace_mod.ENABLED and moved:
+        held = _moved.__dict__
+        held[kind] = held.get(kind, 0) + sum(
+            int(getattr(x, "nbytes", 0)) for x in moved)
+
+
+# -- device.dispatch spans (obs/trace.py) ----------------------------------
+# What a dispatch site enters with tracing off:
+#   with (device_dispatch(kernel, lanes=B, ...) if trace_mod.ENABLED
+#         else NO_DISPATCH):
+# so the tag dict is only ever built behind the gate.
+NO_DISPATCH = contextlib.nullcontext()
+
+
+@contextlib.contextmanager
+def device_dispatch(program, async_: bool = False, **tags):
+    """Bracket one call of a jitted program with a ``device.dispatch``
+    span: enqueue -> results on the host where the caller fetches inside
+    the block, enqueue -> return (tagged ``async=1``) where the caller
+    collects later — the block adds no sync of its own.  ``program`` is
+    the jitted callable; the tag is its ``__name__``, which is what the
+    device plane's ``XLA Modules`` line shows after ``jit_``, so a
+    reader can lay the k-th span of a program on its k-th module event
+    and put both clocks on one axis (benchmarks/reducers/
+    idle_attribution.py).  Shape tags (``lanes``, ``b_pad``, ``g_pad``,
+    ``k_cap``, ``rounds``, ``n_pad``, ``rows``) are the site's;
+    ``h2d_bytes``/``d2h_bytes`` are what this thread moved through the
+    counted seams since its previous span.  A
+    ``jax.profiler.TraceAnnotation`` of the same name is open for the
+    block, so a profiler capture with the host tracer on shows the
+    dispatch beside the device ops.  Not to be confused with the
+    fault-injection site of the same name (scheduler/pipeline.py)."""
+    tracer = trace_mod.tracer()
+    if tracer is None:   # a disable() raced the site's gate
+        yield
+        return
+    t0 = tracer.now()
+    with jax.profiler.TraceAnnotation("device.dispatch"):
+        yield
+    held = _moved.__dict__
+    if async_:
+        tags["async"] = 1
+    tracer.record("device.dispatch", t0, tracer.now() - t0,
+                  parent_ctx=tracer.ctx(),
+                  program=getattr(program, "__name__", str(program)),
+                  h2d_bytes=held.pop("h2d", 0),
+                  d2h_bytes=held.pop("d2h", 0), **tags)
 
 
 def transfer_counts() -> dict:
@@ -181,7 +236,7 @@ def ensure_on_default(cached, host):
     """
     if cached is not None and on_default_platform(cached):
         return cached
-    note_transfer("h2d")
+    note_transfer("h2d", 1, host)
     return jax.device_put(host, default_device())
 
 
@@ -212,9 +267,9 @@ def put_counted(x, device=None):
         if on_default_platform(x):
             return x
         src = next(iter(x.devices())).platform
-        note_transfer(classify_move(src, current_platform()))
+        note_transfer(classify_move(src, current_platform()), 1, x)
         return jax.device_put(x, device or default_device())
-    note_transfer("h2d")
+    note_transfer("h2d", 1, x)
     return jax.device_put(x, device or default_device())
 
 
@@ -224,6 +279,6 @@ def fetch_host(x):
     ``np.asarray``) so the transfer survives a d2h transfer guard; host
     values pass through untouched."""
     if isinstance(x, jax.Array):
-        note_transfer("d2h")
+        note_transfer("d2h", 1, x)
         return jax.device_get(x)
     return x

@@ -26,7 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from nomad_tpu.obs import trace as trace_mod
 from nomad_tpu.ops.binpack import _place_rounds, _place_sequence
+from nomad_tpu.parallel.devices import NO_DISPATCH, device_dispatch
 
 FLEET_AXIS = "fleet"
 LANE_AXIS = "lanes"
@@ -167,7 +169,7 @@ def _put(x, sharding):
         kind = classify_move(src, dst)
     else:
         kind = "h2d"
-    note_transfer(kind)
+    note_transfer(kind, 1, x)
     return jax.device_put(x, sharding)
 
 
@@ -437,7 +439,11 @@ def window_verify_sharded(mesh: Mesh, capacity, reserved, usage, pair_ni,
     pair_order = _put(pair_order, repl)
     pair_comp = _put(pair_comp, repl)
     pair_removed = _put(pair_removed, repl)
-    return _window_verify_jit(capacity, reserved, usage, pair_ni,
-                              row_pair, row_vec, seq_ni, seq_vec,
-                              seq_order, seq_comp, pair_order,
-                              pair_comp, pair_removed)
+    with (device_dispatch(_window_verify_jit, async_=True,
+                          n_pad=capacity.shape[0],
+                          rows=pair_ni.shape[0])
+          if trace_mod.ENABLED else NO_DISPATCH):
+        return _window_verify_jit(capacity, reserved, usage, pair_ni,
+                                  row_pair, row_vec, seq_ni, seq_vec,
+                                  seq_order, seq_comp, pair_order,
+                                  pair_comp, pair_removed)

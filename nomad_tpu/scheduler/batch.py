@@ -47,11 +47,14 @@ def pad_lanes(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
 
-def _lane_spans(name: str, scheds, t0: float, t1: float, **tags) -> None:
+def _lane_spans(name: str, scheds, t0: float, t1: float,
+                span_ids: Optional[dict] = None, **tags) -> None:
     """One span per lane sharing the window's [t0, t1] — fused stages
     (dispatch, finish, submit) run once for the whole window, and every
     member eval's tree records the window it rode (the shared
-    timestamps make the fusion visible in the exported trace)."""
+    timestamps make the fusion visible in the exported trace).
+    ``span_ids`` (eval id -> span id) pins the ids of lanes whose stage
+    already has children (``sched.status`` under ``sched.submit``)."""
     tracer = trace_mod.tracer() if trace_mod.ENABLED else None
     if tracer is None:
         # Includes a concurrent disable() racing the ENABLED check:
@@ -61,7 +64,8 @@ def _lane_spans(name: str, scheds, t0: float, t1: float, **tags) -> None:
         ev = sched.eval
         if ev is not None and ev.trace:
             tracer.record(name, t0, t1 - t0, parent_ctx=ev.trace,
-                          eval_id=ev.id, **tags)
+                          span_id=span_ids.get(ev.id) if span_ids
+                          else None, eval_id=ev.id, **tags)
 
 
 class BatchEvalRunner:
@@ -95,6 +99,10 @@ class BatchEvalRunner:
         self.device_dispatches = 0
         self.sharded_dispatches = 0
         self.fused_batches = 0   # fused windows planned, either executor
+        # Tracing only: eval id -> span id of the stage span (sched.begin
+        # / sched.submit) that eval is under right now; the planner's
+        # ``sched.status`` span (server/worker.py) takes it as parent.
+        self.stage_span: dict = {}
 
     def _note_dispatch(self, sched) -> None:
         """Fold one scheduler's own kernel-call counts (its single-eval
@@ -148,11 +156,14 @@ class BatchEvalRunner:
                                          eval_id=ev.id,
                                          eval_type=ev.type)
             t0 = tracer.now()
+            sid = self.stage_span[ev.id] = tracer.new_id()
             try:
                 return self._begin_eval_inner(ev, finish_noop)
             finally:
+                del self.stage_span[ev.id]
                 tracer.record("sched.begin", t0, tracer.now() - t0,
-                              parent_ctx=ev.trace, eval_id=ev.id)
+                              parent_ctx=ev.trace, span_id=sid,
+                              eval_id=ev.id)
         return self._begin_eval_inner(ev, finish_noop)
 
     def _begin_eval_inner(self, ev: Evaluation, finish_noop: bool = True):
@@ -208,8 +219,13 @@ class BatchEvalRunner:
         """Exact per-eval retry (fresh scheduler, full process)."""
         retry = JaxBinPackScheduler(state, self.planner,
                                     batch=(ev.type == "batch"))
+        t0 = _tnow()
         retry.process(ev)
         self._note_dispatch(retry)
+        # One span over the whole re-plan.  Its status write is a
+        # sibling ``sched.status`` under the eval's anchor, not a child:
+        # the re-plan's own time stays a leaf of the eval's tree.
+        _lane_spans("sched.retry", [retry], t0, _tnow())
 
     def _process(self, evals: list[Evaluation],
                  retries: Optional[list] = None) -> None:
@@ -339,27 +355,35 @@ class BatchEvalRunner:
             job_counts = put_counted(job_counts)
             counts = put_counted(counts)
             penalty = put_counted(penalty)
+        from nomad_tpu.parallel.devices import NO_DISPATCH, device_dispatch
+
         if rounds_ok:
             # Fast path: top-k rounds — device steps scale with unique
             # groups x rounds, not with placements.
             from .jax_binpack import rounds_to_placements
 
             if mesh is not None:
-                from nomad_tpu.parallel.mesh import \
-                    place_rounds_batch_sharded
-
-                chosen_s, score_s, _u = place_rounds_batch_sharded(
-                    mesh, capacity_d, reserved_d, base_usage, job_counts,
-                    feasible, asks, distinct, counts, penalty,
-                    k_cap=k_cap, rounds=rounds)
+                from nomad_tpu.parallel.mesh import (
+                    _place_rounds_batch_sharded_jit as program,
+                    place_rounds_batch_sharded)
             else:
-                from nomad_tpu.ops.binpack import place_rounds_batch
-
-                chosen_s, score_s, _u = place_rounds_batch(
-                    capacity_d, reserved_d, base_usage, job_counts,
-                    feasible, asks, distinct, counts, penalty,
-                    k_cap=k_cap, rounds=rounds)
-            chosen_s, score_s = fetch_results(chosen_s, score_s)
+                from nomad_tpu.ops.binpack import \
+                    place_rounds_batch as program
+            with (device_dispatch(program, lanes=B, b_pad=B_pad,
+                                  g_pad=g_max, k_cap=k_cap, rounds=rounds,
+                                  n_pad=statics.n_pad)
+                  if trace_mod.ENABLED else NO_DISPATCH):
+                if mesh is not None:
+                    chosen_s, score_s, _u = place_rounds_batch_sharded(
+                        mesh, capacity_d, reserved_d, base_usage,
+                        job_counts, feasible, asks, distinct, counts,
+                        penalty, k_cap=k_cap, rounds=rounds)
+                else:
+                    chosen_s, score_s, _u = program(
+                        capacity_d, reserved_d, base_usage, job_counts,
+                        feasible, asks, distinct, counts, penalty,
+                        k_cap=k_cap, rounds=rounds)
+                chosen_s, score_s = fetch_results(chosen_s, score_s)
             _lane_spans("sched.dispatch", [s for s, _p, _a in pending],
                         t_disp, _tnow(), fused=B)
             done = []
@@ -370,17 +394,26 @@ class BatchEvalRunner:
             self._finish_window(done, retries)
         else:
             if mesh is not None:
-                from nomad_tpu.parallel.mesh import \
-                    place_sequence_batch_sharded
-
-                chosen, scores, _usage = place_sequence_batch_sharded(
-                    mesh, capacity_d, reserved_d, base_usage, job_counts,
-                    feasible, asks, distinct, group_idx, valid, penalty)
+                from nomad_tpu.parallel.mesh import (
+                    _place_sequence_batch_sharded_jit as program,
+                    place_sequence_batch_sharded)
             else:
-                chosen, scores, _usage = place_sequence_batch(
-                    capacity_d, reserved_d, base_usage, job_counts,
-                    feasible, asks, distinct, group_idx, valid, penalty)
-            chosen, scores = fetch_results(chosen, scores)
+                program = place_sequence_batch
+            with (device_dispatch(program, lanes=B, b_pad=B_pad,
+                                  g_pad=g_max, p_pad=p_max,
+                                  n_pad=statics.n_pad)
+                  if trace_mod.ENABLED else NO_DISPATCH):
+                if mesh is not None:
+                    chosen, scores, _usage = place_sequence_batch_sharded(
+                        mesh, capacity_d, reserved_d, base_usage,
+                        job_counts, feasible, asks, distinct, group_idx,
+                        valid, penalty)
+                else:
+                    chosen, scores, _usage = program(
+                        capacity_d, reserved_d, base_usage, job_counts,
+                        feasible, asks, distinct, group_idx, valid,
+                        penalty)
+                chosen, scores = fetch_results(chosen, scores)
             _lane_spans("sched.dispatch", [s for s, _p, _a in pending],
                         t_disp, _tnow(), fused=B)
             self._finish_window(
@@ -521,6 +554,24 @@ class BatchEvalRunner:
         planner's group path when it has one; per-plan submits
         otherwise."""
         t_sub = _tnow()
+        tracer = trace_mod.tracer() if trace_mod.ENABLED else None
+        sub_ids = None
+        if tracer is not None:
+            # Pin each lane's sched.submit span id up front: the status
+            # writes inside the window are its children.
+            sub_ids = {s.eval.id: tracer.new_id() for s in scheds
+                       if s.eval is not None}
+            self.stage_span.update(sub_ids)
+        try:
+            self._submit_window_inner(scheds, retries)
+        finally:
+            if sub_ids is not None:
+                for eval_id in sub_ids:
+                    self.stage_span.pop(eval_id, None)
+        _lane_spans("sched.submit", scheds, t_sub, _tnow(),
+                    span_ids=sub_ids, window=len(scheds))
+
+    def _submit_window_inner(self, scheds: list, retries=None) -> None:
         submitters = []
         for sched in scheds:
             ev = sched.eval
@@ -536,8 +587,6 @@ class BatchEvalRunner:
                 continue
             submitters.append(sched)
         if not submitters:
-            _lane_spans("sched.submit", scheds, t_sub, _tnow(),
-                        window=len(scheds))
             return
         group = getattr(self.planner, "submit_plans", None)
         if group is not None and len(submitters) > 1:
@@ -560,8 +609,6 @@ class BatchEvalRunner:
                 retries.append(ev)  # no status yet: a later round owns it
             else:
                 self._retry_sequential(sched.state, ev)
-        _lane_spans("sched.submit", scheds, t_sub, _tnow(),
-                    window=len(scheds))
 
     def _finish(self, sched, retries=None) -> None:
         """Submit the plan; on rejection/partial commit either queue the

@@ -38,6 +38,7 @@ from nomad_tpu.models.fleet import (
     mirror_for,
     net_base_for,
 )
+from nomad_tpu.obs import trace as trace_mod
 from nomad_tpu.ops.binpack import place_sequence
 from nomad_tpu.structs import (
     ALLOC_CLIENT_STATUS_FAILED,
@@ -652,8 +653,10 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         self.dispatched_sharded = False
         capacity_d, reserved_d = args.statics.device_capacity_reserved()
         feas_cached = args.feasible_d  # [host, device-or-None], lazy
-        from nomad_tpu.parallel.devices import ensure_on_default, \
-            put_counted
+        from nomad_tpu.parallel.devices import (NO_DISPATCH,
+                                                device_dispatch,
+                                                ensure_on_default,
+                                                put_counted)
         feas_cached[1] = ensure_on_default(feas_cached[1], feas_cached[0])
         feasible_d = feas_cached[1]
         # Per-eval varying operands are placed EXPLICITLY (counted by
@@ -672,17 +675,26 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
 
             asks_d, distinct_d, counts_d = self._dev_const(
                 args, "rounds", (args.asks, args.distinct, args.counts))
-            chosen_s, scores_s, _ = place_rounds(
-                capacity_d, reserved_d, usage_d, jc_d, feasible_d,
-                asks_d, distinct_d, counts_d, pen_d,
-                k_cap=args.k_cap, rounds=args.rounds)
+            with (device_dispatch(place_rounds, async_=True, lanes=1,
+                                  g_pad=args.g_pad, k_cap=args.k_cap,
+                                  rounds=args.rounds,
+                                  n_pad=args.statics.n_pad)
+                  if trace_mod.ENABLED else NO_DISPATCH):
+                chosen_s, scores_s, _ = place_rounds(
+                    capacity_d, reserved_d, usage_d, jc_d, feasible_d,
+                    asks_d, distinct_d, counts_d, pen_d,
+                    k_cap=args.k_cap, rounds=args.rounds)
         else:
             asks_d, distinct_d, group_idx_d, valid_d = self._dev_const(
                 args, "seq", (args.asks, args.distinct, args.group_idx,
                               args.valid))
-            chosen_s, scores_s, _ = place_sequence(
-                capacity_d, reserved_d, usage_d, jc_d, feasible_d,
-                asks_d, distinct_d, group_idx_d, valid_d, pen_d)
+            with (device_dispatch(place_sequence, async_=True, lanes=1,
+                                  g_pad=args.g_pad, p_pad=args.p_pad,
+                                  n_pad=args.statics.n_pad)
+                  if trace_mod.ENABLED else NO_DISPATCH):
+                chosen_s, scores_s, _ = place_sequence(
+                    capacity_d, reserved_d, usage_d, jc_d, feasible_d,
+                    asks_d, distinct_d, group_idx_d, valid_d, pen_d)
         chosen_s.copy_to_host_async()
         scores_s.copy_to_host_async()
         return chosen_s, scores_s

@@ -37,6 +37,20 @@ CONSISTENT_READS = frozenset({
 })
 
 
+# Why the last blocking-query wrapper on this thread answered:
+# "immediate" (no wait), "index" (the watched table moved) or "timeout".
+# The in-proc HTTP edge serves on the handler's own thread and reads it
+# back to count wake-ups (nomad.http.blocking_wakes*) and to tag its
+# ``http.serve.*`` span; the response itself carries no such field.
+_fired = threading.local()
+
+
+def take_fired() -> Optional[str]:
+    """The calling thread's last blocking-query outcome, cleared by the
+    read (None when no wrapper ran here since, e.g. a forwarded read)."""
+    return _fired.__dict__.pop("why", None)
+
+
 def _jittered(wait: float) -> float:
     wait = min(wait, MAX_BLOCKING_WAIT)
     return wait + wait * random.random() / 16
@@ -220,27 +234,46 @@ class Endpoints:
         down).  Synchronous callers (in-proc agent RPC) park ONE shared
         fan-out waiter and wait on a local event — registered once,
         deregistered in ``finally``, so an abandoned wait can never
-        leak a registry entry."""
+        leak a registry entry.
+
+        Either wait records one ``query.blocked`` span (subscribe ->
+        wake, tagged ``table`` and ``fired``) when tracing is on."""
         min_index = int(args.get("min_query_index") or 0)
         state = self._state()
         fired = args.pop("_watch_fired", None)
+        tracer = trace_mod.tracer() if trace_mod.ENABLED else None
 
-        def respond() -> dict:
+        def respond(why: str) -> dict:
+            _fired.why = why
             out = run()
             out["index"] = self._state().get_index(table)
             out["known_leader"] = self.server.has_leader()
             return out
 
-        if min_index <= 0 or fired is not None or \
-                state.get_index(table) > min_index:
-            return respond()
+        if fired is not None:
+            # Resumed from a park: the span runs park -> resume (t0
+            # rode in the args; dropped when the tracer changed since).
+            why = "timeout" if fired == "timeout" else "index"
+            parked = args.pop("_blocked_at", None)
+            if tracer is not None and parked and parked[0] == id(tracer):
+                tracer.record("query.blocked", parked[1],
+                              tracer.now() - parked[1],
+                              parent_ctx=tracer.ctx(), table=table,
+                              fired=why)
+            return respond(why)
+        if min_index <= 0 or state.get_index(table) > min_index:
+            return respond("immediate")
         wait = _jittered(float(args.get("max_query_time") or
                                MAX_BLOCKING_WAIT))
         # Deadline envelope (server/overload.py): never wait past the
         # caller's remaining budget — a reply past it talks to nobody.
         wait = overload_mod.remaining(
             overload_mod.absolute_deadline(args), wait)
+        t0 = tracer.now() if tracer is not None else 0.0
         if mux.parking_enabled():
+            if tracer is not None:
+                args["_blocked_at"] = [id(tracer), t0]
+
             def _subscribe(resume):
                 token = state.watch.subscribe(
                     (table,), resume, min_index=min_index, ttl=wait)
@@ -251,10 +284,13 @@ class Endpoints:
                                       lambda timed_out: woke.set(),
                                       min_index=min_index)
         try:
-            woke.wait(wait)
+            why = "index" if woke.wait(wait) else "timeout"
         finally:
             state.watch.unsubscribe(token)
-        return respond()
+        if tracer is not None:
+            tracer.record("query.blocked", t0, tracer.now() - t0,
+                          parent_ctx=tracer.ctx(), table=table, fired=why)
+        return respond(why)
 
     # -- Status -----------------------------------------------------------
     def status_ping(self, args: dict) -> dict:

@@ -366,6 +366,10 @@ class Server:
             "expired_drops": sum(w.expired_drops for w in self.workers),
             "dispatch_failures": sum(w.dispatch_failures
                                      for w in self.workers),
+            # The fused runner's cycle (server/worker.py BatchWorker):
+            # batches run, seconds spent inside them.
+            "batches": sum(w.batches for w in self.workers),
+            "batch_busy_s": sum(w.batch_busy_s for w in self.workers),
         }
 
     def _gossip_join(self, member) -> None:
@@ -689,8 +693,19 @@ class Server:
 
     # -- raft-backed mutations (the endpoint layer calls these) -----------
     def raft_apply(self, msg_type: int, payload: dict) -> int:
+        tracer = obs_trace.tracer() if obs_trace.ENABLED else None
+        t0 = tracer.now() if tracer is not None else 0.0
         entry = codec.encode(msg_type, payload)
         index, _ = self.raft.apply(entry).wait(30.0)
+        if tracer is not None:
+            # Encode -> committed index back, under whatever is ambient
+            # (the serving RPC's span, or a lane's sched.status).  The
+            # plan applier dispatches its own entries and records them
+            # as ``raft.apply``; every other apply of a job is here.
+            tracer.record(
+                "server.apply." + codec.MESSAGE_NAMES.get(msg_type, "other"),
+                t0, tracer.now() - t0, parent_ctx=tracer.ctx(),
+                index=index)
         return index
 
     def apply_eval_update(self, evals: list, token: str = "") -> int:

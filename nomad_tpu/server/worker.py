@@ -57,6 +57,12 @@ class Worker:
         # scheduling raised and were nacked for redelivery —
         # nomad.workers.dispatch_failures.
         self.dispatch_failures = 0
+        # The fused runner's cycle (BatchWorker; a plain worker keeps
+        # noughts): batches run, and the seconds from a batch's dequeue
+        # returning to its last ack — against wall time, how saturated
+        # the one runner is (nomad.workers.batches, .batch_busy_s).
+        self.batches = 0
+        self.batch_busy_s = 0.0
 
     # -- lifecycle --------------------------------------------------------
     def start(self) -> None:
@@ -261,11 +267,14 @@ class BatchWorker(Worker):
     def run(self) -> None:
         backoff = Backoff(base=BACKOFF_BASE, max_delay=BACKOFF_LIMIT,
                           jitter=0.5)
-        runner = self.runner
         while not self._stop.is_set():
             self._check_paused()
             queues = [q for q in self.server.enabled_schedulers()
                       if q in self.DEVICE_QUEUES]
+            # The dequeue's t0 is taken per call; only the call that
+            # returned a batch is recorded.
+            tracer = trace_mod.tracer() if trace_mod.ENABLED else None
+            t_deq = tracer.now() if tracer is not None else 0.0
             try:
                 batch = self.server.eval_broker.dequeue_batch(
                     queues, self.max_batch,
@@ -277,43 +286,96 @@ class BatchWorker(Worker):
             backoff.reset()
             if not batch:
                 continue
-            self._delivery_deadline = time.monotonic() + \
-                self.server.eval_broker.nack_timeout
-            max_index = max(ev.modify_index for ev, _ in batch)
-            try:
-                self._wait_for_index(max_index, RAFT_SYNC_LIMIT)
-                # ErrDeadlineExceeded is a TimeoutError: an expired
-                # delivery nacks the batch below instead of burning a
-                # whole fused device dispatch on redelivered work.
-                self._check_delivery_live(batch[0][0])
-            except TimeoutError:
-                for ev, token in batch:
-                    try:
-                        self.server.eval_broker.nack(ev.id, token)
-                    except ValueError:
-                        pass
-                continue
+            t_busy = time.perf_counter()
+            if trace_mod.ENABLED and tracer is not trace_mod.tracer():
+                # Tracing came on (or changed hands) while the dequeue
+                # waited: the batch is traced, its dequeue reads zero.
+                tracer = trace_mod.tracer()
+                t_deq = tracer.now() if tracer is not None else 0.0
+            if tracer is None:
+                self._run_batch(batch)
+            else:
+                self._run_batch_traced(batch, tracer, t_deq)
+            self.batches += 1
+            self.batch_busy_s += time.perf_counter() - t_busy
 
-            self._tokens = {ev.id: token for ev, token in batch}
-            runner.state = self.server.fsm.state.snapshot()
-            try:
-                runner.process([ev for ev, _ in batch])
-            except Exception:
-                logger.exception("batch worker: dispatch failed")
-                self.dispatch_failures += 1
-                for ev, token in batch:
-                    try:
-                        self.server.eval_broker.nack(ev.id, token)
-                    except ValueError:
-                        pass
-                continue
-            finally:
-                runner.state = None  # don't pin a store generation idle
+    def _run_batch_traced(self, batch: list, tracer, t_deq: float) -> None:
+        """One batch under a ``worker.batch`` span — the runner's whole
+        cycle, dequeue to last ack, a trace of its own (a batch belongs
+        to no one eval) — with ``worker.dequeue``, ``worker.sync``,
+        ``worker.snapshot`` and ``worker.ack`` as children.  ``cpu_s``
+        is this thread's CPU time over the batch: the rest of the
+        span's duration after ``worker.dequeue`` is time the runner
+        waited (plan results, the GIL)."""
+        ctx = {"trace_id": tracer.new_id(), "span_id": tracer.new_id()}
+        t0 = tracer.now()
+        tracer.record("worker.dequeue", t_deq, t0 - t_deq, parent_ctx=ctx,
+                      lanes=len(batch))
+        cpu0 = time.thread_time()
+        try:
+            self._run_batch(batch, tracer, ctx)
+        finally:
+            tracer.record(
+                "worker.batch", t_deq, tracer.now() - t_deq,
+                ctx={"trace_id": ctx["trace_id"], "parent_id": None},
+                span_id=ctx["span_id"], lanes=len(batch),
+                cpu_s=time.thread_time() - cpu0)
+
+    def _run_batch(self, batch: list, tracer=None, ctx=None) -> None:
+        """Sync to the batch's raft index, snapshot, run the fused
+        runner, ack (or nack everything on a failed sync/dispatch).
+        With a ``tracer``, each step is a span under ``ctx``."""
+        broker = self.server.eval_broker
+
+        def now() -> float:
+            return tracer.now() if tracer is not None else 0.0
+
+        def step(name: str, t0: float) -> None:
+            if tracer is not None:
+                tracer.record(name, t0, tracer.now() - t0, parent_ctx=ctx)
+
+        def nack_all() -> None:
             for ev, token in batch:
                 try:
-                    self.server.eval_broker.ack(ev.id, token)
+                    broker.nack(ev.id, token)
                 except ValueError:
                     pass
+
+        self._delivery_deadline = time.monotonic() + broker.nack_timeout
+        max_index = max(ev.modify_index for ev, _ in batch)
+        t0 = now()
+        try:
+            self._wait_for_index(max_index, RAFT_SYNC_LIMIT)
+            # ErrDeadlineExceeded is a TimeoutError: an expired
+            # delivery nacks the batch below instead of burning a
+            # whole fused device dispatch on redelivered work.
+            self._check_delivery_live(batch[0][0])
+        except TimeoutError:
+            nack_all()
+            return
+        finally:
+            step("worker.sync", t0)
+
+        self._tokens = {ev.id: token for ev, token in batch}
+        t0 = now()
+        self.runner.state = self.server.fsm.state.snapshot()
+        step("worker.snapshot", t0)
+        try:
+            self.runner.process([ev for ev, _ in batch])
+        except Exception:
+            logger.exception("batch worker: dispatch failed")
+            self.dispatch_failures += 1
+            nack_all()
+            return
+        finally:
+            self.runner.state = None  # don't pin a store generation idle
+        t0 = now()
+        for ev, token in batch:
+            try:
+                broker.ack(ev.id, token)
+            except ValueError:
+                pass
+        step("worker.ack", t0)
 
 
 class _BatchPlanner:
@@ -379,8 +441,23 @@ class _BatchPlanner:
         return result, state
 
     def update_eval(self, ev: Evaluation) -> None:
-        self.worker.server.apply_eval_update(
-            [ev], self.worker._tokens.get(ev.id, ""))
+        tracer = trace_mod.tracer() if trace_mod.ENABLED else None
+        if tracer is None or not ev.trace:
+            self.worker.server.apply_eval_update(
+                [ev], self.worker._tokens.get(ev.id, ""))
+            return
+        # Every status write of the fused runner, whichever path took
+        # it (a lane's submit window, a begin-time failure, a sequential
+        # re-plan), under one ``sched.status`` span: child of the stage
+        # span the runner says the eval is under, else of its anchor;
+        # ambient, so ``server.apply.eval_update`` hangs below it.
+        parent = {"trace_id": ev.trace.get("trace_id"),
+                  "span_id": self.worker.runner.stage_span.get(ev.id)
+                  or ev.trace.get("span_id")}
+        with tracer.span("sched.status", ctx=parent, eval_id=ev.id,
+                         status=ev.status):
+            self.worker.server.apply_eval_update(
+                [ev], self.worker._tokens.get(ev.id, ""))
 
     def create_eval(self, ev: Evaluation) -> None:
         self.worker.server.apply_eval_update(

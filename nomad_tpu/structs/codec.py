@@ -24,6 +24,22 @@ ALLOC_CLIENT_UPDATE_REQUEST = 9
 # by one batched FSM pass (server/plan_apply.py group commit).
 PLAN_BATCH_APPLY_REQUEST = 10
 
+# Low-cardinality names of the message types, for span names
+# (``server.apply.<name>``, obs/trace.py).
+MESSAGE_NAMES = {
+    NODE_REGISTER_REQUEST: "node_register",
+    NODE_DEREGISTER_REQUEST: "node_deregister",
+    NODE_UPDATE_STATUS_REQUEST: "node_update_status",
+    NODE_UPDATE_DRAIN_REQUEST: "node_update_drain",
+    JOB_REGISTER_REQUEST: "job_register",
+    JOB_DEREGISTER_REQUEST: "job_deregister",
+    EVAL_UPDATE_REQUEST: "eval_update",
+    EVAL_DELETE_REQUEST: "eval_delete",
+    ALLOC_UPDATE_REQUEST: "alloc_update",
+    ALLOC_CLIENT_UPDATE_REQUEST: "alloc_client_update",
+    PLAN_BATCH_APPLY_REQUEST: "plan_batch_apply",
+}
+
 # Upper bit: apply must not error on unknown type (structs.go:40-43)
 IGNORE_UNKNOWN_TYPE_FLAG = 128
 

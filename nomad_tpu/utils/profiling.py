@@ -65,20 +65,3 @@ def active_trace_dir() -> Optional[str]:
     # lockcheck gate rightly flags guarded attrs read unlocked).
     with _lock:
         return _trace_dir
-
-
-class device_trace:
-    """Context manager for one-shot traces (bench.py --xla-trace)."""
-
-    def __init__(self, log_dir: Optional[str]) -> None:
-        self.log_dir = log_dir
-
-    def __enter__(self):
-        if self.log_dir:
-            start_device_trace(self.log_dir)
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        if self.log_dir:
-            stop_device_trace()
-        return False

@@ -907,3 +907,418 @@ class TestTracingOverhead:
         self._stream(h, [job])
         with trace.tracing(seed=4) as t:
             assert t.snapshot() == []
+
+
+# ---------------------------------------------------------------------------
+# 6. the whole path (ISSUE 26): HTTP socket -> blocking-query answer, the
+#    fused runner's cycle, device dispatches
+# ---------------------------------------------------------------------------
+
+def _reducer(name: str):
+    """A reader of the benchmark (benchmarks/reducers/<name>.py): the
+    coverage it reports on the chip is the coverage asserted here."""
+    import importlib.util
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:    # the readers import their xplane.py
+        sys.path.insert(0, bench)
+    spec = importlib.util.spec_from_file_location(
+        f"obs_reducer_{name}", os.path.join(bench, "reducers",
+                                            f"{name}.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _tags(span: dict) -> dict:
+    return span.get("tags") or {}
+
+
+def _http_agent():
+    from nomad_tpu.agent import Agent, AgentConfig
+    from nomad_tpu.api import APIClient
+
+    agent = Agent(AgentConfig(server_enabled=True, http_port=0,
+                              rpc_port=0, enable_debug=True))
+    for i in range(8):
+        agent.server.node_register(mock.node(i))
+    host, port = agent.http.address
+    return agent, APIClient(f"http://{host}:{port}")
+
+
+def _http_get(api, path: str) -> tuple:
+    """(status, body) of a GET that may be refused."""
+    from nomad_tpu.api.client import APIError
+
+    try:
+        return 200, api.raw("GET", path)[0]
+    except APIError as e:
+        return e.status, None
+
+
+def _await_eval(api, eval_id: str, timeout: float = 20.0):
+    """The benchmark client's wait: a blocking query, never a poll."""
+    from nomad_tpu.api.client import QueryOptions
+
+    opts, deadline = None, time.monotonic() + timeout
+    while True:
+        ev, meta = api.eval_info(eval_id, opts)
+        if ev.terminal_status() or time.monotonic() > deadline:
+            return ev
+        opts = QueryOptions(wait_index=meta.last_index, wait_time=5.0)
+
+
+class TestWholePathSpans:
+    def _one_job(self):
+        """One job registered over HTTP and awaited by a blocking
+        query, every raft apply slowed so the path's steps (not the
+        HTTP codec) are what the interval is made of."""
+        plan = FaultPlan.parse("seed=26;raft.apply=delay(secs=0.02)")
+        agent, api = _http_agent()
+        try:
+            api.job_register(_job(1))      # warm: compile, caches
+            time.sleep(0.5)  # sleep-ok: let the warm job's batch finish
+            with trace.tracing(seed=26) as tracer:
+                with faultinject.injected(plan):
+                    eval_id = api.job_register(_job(2))["eval_id"]
+                    ev = _await_eval(api, eval_id)
+                assert ev.status == "complete"
+                # The runner records worker.batch after its last ack,
+                # the HTTP worker its span after the answer is written.
+                wait_until(lambda: {"worker.batch", "complete"} <= {
+                    _tags(s).get("eval_status", s["name"])
+                    for s in tracer.snapshot()}, timeout=5.0)
+                return eval_id, tracer.snapshot()
+        finally:
+            agent.shutdown()
+
+    def test_one_job_over_http_is_one_closed_chain(self):
+        eval_id, spans = self._one_job()
+        by_id = {s["span_id"]: s for s in spans}
+        anchor = next(s for s in spans if s["name"] == "eval.created"
+                      and _tags(s).get("eval_id") == eval_id)
+        tree = [s for s in spans if s["trace_id"] == anchor["trace_id"]]
+        roots = [s for s in tree if s["parent_id"] not in by_id]
+        assert [s["name"] for s in roots] == ["http.serve.job_register"]
+        assert roots[0]["parent_id"] is None
+        assert all(s["dur"] >= 0.0 for s in tree)
+        assert _tags(roots[0])["code"] == 200
+        assert _tags(roots[0])["blocking"] == 0
+        names = {s["name"] for s in tree}
+        assert {"rpc.client.Job.Register", "rpc.serve.Job.Register",
+                "server.apply.job_register", "server.apply.eval_update",
+                "broker.wait", "sched.begin", "sched.dispatch",
+                "sched.finish", "sched.submit", "sched.status",
+                "plan.queued", "applier.window", "raft.apply",
+                "store.upsert"} <= names
+        # The status write hangs under the lane's sched.submit, and its
+        # raft apply under it.
+        status = [s for s in tree if s["name"] == "sched.status"]
+        assert [_tags(s)["status"] for s in status] == ["complete"]
+        assert by_id[status[0]["parent_id"]]["name"] == "sched.submit"
+        applies = [s for s in tree
+                   if s["name"] == "server.apply.eval_update"]
+        assert {by_id[s["parent_id"]]["name"] for s in applies} == \
+            {"rpc.serve.Job.Register", "sched.status"}
+        # The old invariant, with the reads of the eval set aside (each
+        # request roots a trace of its own, tied to the eval by tag).
+        _assert_single_rooted_closed(
+            [s for s in spans if _tags(s).get("eval_id") == eval_id
+             and not s["name"].startswith("http.serve.")], eval_id)
+
+        # The runner's cycle: one batch of one lane around the stages.
+        begin = next(s for s in tree if s["name"] == "sched.begin")
+        batch = next(s for s in spans if s["name"] == "worker.batch"
+                     and s["t0"] <= begin["t0"] <= s["t0"] + s["dur"])
+        assert _tags(batch)["lanes"] == 1
+        assert 0.0 <= _tags(batch)["cpu_s"] <= batch["dur"]
+        assert {s["name"] for s in spans
+                if s["parent_id"] == batch["span_id"]} == {
+            "worker.dequeue", "worker.sync", "worker.snapshot",
+            "worker.ack"}
+        submit = next(s for s in tree if s["name"] == "sched.submit")
+        assert submit["t0"] + submit["dur"] <= \
+            batch["t0"] + batch["dur"] + 1e-6
+
+        # The answer: a read of this eval that found it changed.
+        reads = [s for s in spans if s["name"] == "http.serve.eval_get"
+                 and _tags(s).get("eval_id") == eval_id]
+        answer = [s for s in reads
+                  if _tags(s)["eval_status"] == "complete"]
+        assert answer and _tags(answer[0])["changed"] == 1
+        assert _tags(answer[0])["code"] == 200
+        blocked = [s for s in spans if s["name"] == "query.blocked"]
+        assert blocked and all(
+            _tags(s)["table"] == "evals" and
+            _tags(s)["fired"] in ("index", "timeout") for s in blocked)
+        assert {by_id[s["parent_id"]]["name"] for s in blocked} == \
+            {"rpc.serve.Eval.GetEval"}
+
+    def test_leaf_spans_cover_the_interval(self):
+        """The chain is contiguous: at most a tenth of socket-readable
+        -> answer-written lies under no leaf span (the benchmark's
+        commit_unattributed_share, by the benchmark's own reader)."""
+        _eval_id, spans = self._one_job()
+        ctx = {"spans": spans, "notes": []}
+        chain = _reducer("job_chain")
+        share = chain.reduce({"what": "unattributed_share"}, ctx)
+        assert share is not None and share <= 10.0, (share, ctx["notes"])
+        lag = chain.reduce({"what": "wake_lag_ms"}, ctx)
+        assert lag is not None and lag < 1000.0
+
+    def test_unrelated_eval_write_wakes_the_query_unchanged(self):
+        """The table-level watch: a write to ANOTHER eval wakes a
+        client blocked on its own eval; the span says fired=index,
+        changed=0, and the registry counts the wake-up."""
+        from nomad_tpu.api.client import QueryOptions
+        from nomad_tpu.structs import Evaluation, generate_uuid
+
+        agent, api = _http_agent()
+        srv = agent.server
+        try:
+            for w in srv.workers:
+                w.set_pause(True)   # evals stay pending
+            time.sleep(0.6)  # sleep-ok: workers leave their dequeue
+
+            def pending(job_id):
+                return Evaluation(
+                    id=generate_uuid(), priority=50, type="service",
+                    triggered_by="job-register", job_id=job_id,
+                    status="pending")
+            mine, other = pending("job-a"), pending("job-b")
+            srv.apply_eval_update([mine])
+            index = srv.fsm.state.get_index("evals")
+            got = {}
+            with trace.tracing(seed=27) as tracer:
+                reader = threading.Thread(target=lambda: got.update(
+                    ev=api.eval_info(mine.id, QueryOptions(
+                        wait_index=index, wait_time=10.0))[0]))
+                reader.start()
+                wait_until(lambda: len(srv.fsm.state.watch._waiters)
+                           >= 1, timeout=5.0)
+                srv.apply_eval_update([other])
+                reader.join(10.0)
+                assert not reader.is_alive()
+                wait_until(lambda: any(
+                    s["name"] == "http.serve.eval_get"
+                    for s in tracer.snapshot()), timeout=5.0)
+                spans = tracer.snapshot()
+            assert got["ev"].status == "pending"
+            read = next(s for s in spans
+                        if s["name"] == "http.serve.eval_get")
+            assert _tags(read)["fired"] == "index"
+            assert _tags(read)["changed"] == 0
+            assert _tags(read)["blocking"] == 1
+            assert _tags(read)["eval_id"] == mine.id
+            assert _tags(read)["eval_status"] == "pending"
+            blocked = next(s for s in spans
+                           if s["name"] == "query.blocked")
+            assert _tags(blocked)["fired"] == "index"
+            assert read["t0"] <= blocked["t0"] and \
+                blocked["t0"] + blocked["dur"] <= read["t0"] + read["dur"]
+            stats = agent.http.stats()
+            assert stats["blocking_wakes"] == 1
+            assert stats["blocking_wakes_changed"] == 0
+            metrics = agent.metrics_payload()["providers"]
+            assert metrics["nomad.http.blocking_wakes"] == 1
+            assert metrics["nomad.http.blocking_wakes_changed"] == 0
+            assert metrics["nomad.workers.batches"] >= 0
+            assert metrics["nomad.workers.batch_busy_s"] >= 0.0
+        finally:
+            for w in srv.workers:
+                w.set_pause(False)
+            agent.shutdown()
+
+    @pytest.mark.parametrize("wait, fired", [(10.0, "index"),
+                                             (0.2, "timeout")])
+    def test_parked_query_records_its_wait(self, wait, fired):
+        """The RPC plane parks a blocking query instead of holding a
+        thread: the span still runs subscribe -> wake."""
+        from nomad_tpu.structs import Evaluation, generate_uuid
+
+        srv = Server(ServerConfig(num_schedulers=0, enable_rpc=True))
+        srv.establish_leadership()
+        pool = ConnPool()
+        try:
+            def pending(job_id):
+                return Evaluation(
+                    id=generate_uuid(), priority=50, type="service",
+                    triggered_by="job-register", job_id=job_id,
+                    status="pending")
+            mine = pending("job-a")
+            srv.apply_eval_update([mine])
+            index = srv.fsm.state.get_index("evals")
+            with trace.tracing(seed=28) as tracer:
+                reader = threading.Thread(target=lambda: pool.call(
+                    srv.rpc_address(), "Eval.GetEval",
+                    {"eval_id": mine.id, "min_query_index": index,
+                     "max_query_time": wait}, timeout=15.0))
+                reader.start()
+                if fired == "index":
+                    wait_until(lambda: len(
+                        srv.fsm.state.watch._waiters) >= 1, timeout=5.0)
+                    srv.apply_eval_update([pending("job-b")])
+                reader.join(15.0)
+                assert not reader.is_alive()
+                blocked = [s for s in tracer.snapshot()
+                           if s["name"] == "query.blocked"]
+            assert [_tags(s)["fired"] for s in blocked] == [fired]
+            assert _tags(blocked[0])["table"] == "evals"
+            if fired == "timeout":
+                assert blocked[0]["dur"] >= 0.15
+        finally:
+            pool.shutdown()
+            srv.shutdown()
+
+    def test_device_dispatch_names_program_and_shapes(self):
+        """device.dispatch carries the jitted function's name as the
+        device plane shows it (``jit_<name>`` minus ``jit_``) and the
+        shapes of the call; bytes come from the counted seams."""
+        import numpy as np
+
+        from nomad_tpu.models import fleet
+        from nomad_tpu.ops import binpack
+        from nomad_tpu.parallel.devices import put_counted
+        from nomad_tpu.parallel.mesh import mesh_override
+        from nomad_tpu.scheduler.batch import BatchEvalRunner
+        from nomad_tpu.scheduler.executor import executor_override
+        from nomad_tpu.scheduler.harness import Harness
+        from nomad_tpu.structs import Evaluation, generate_uuid
+
+        h = Harness()
+        for i in range(16):
+            h.state.upsert_node(h.next_index(), mock.node(i))
+        jobs = [_job(2, count=2) for _ in range(3)]
+        for j in jobs:
+            h.state.upsert_job(h.next_index(), j)
+        evals = [Evaluation(id=generate_uuid(), priority=j.priority,
+                            type="service", triggered_by="job-register",
+                            job_id=j.id, status="pending") for j in jobs]
+        with trace.tracing(seed=29) as tracer:
+            usage = put_counted(np.zeros((16, 6), np.float32))
+            tracer.snapshot()
+            fleet._scatter_rows(usage, np.array([1, 2, 3], np.int32),
+                                np.ones((3, 6), np.float32))
+            # The single-device twin: the suite's 8 virtual devices
+            # would otherwise shard the lanes (another program's name).
+            with executor_override("device"), mesh_override("off"):
+                BatchEvalRunner(h.state.snapshot(), h).process(evals)
+            spans = [s for s in tracer.snapshot()
+                     if s["name"] == "device.dispatch"]
+        scatter = next(s for s in spans if _tags(s)["program"] ==
+                       fleet._scatter_jit_impl.__name__)
+        assert _tags(scatter)["async"] == 1
+        assert _tags(scatter)["rows"] == 4 and _tags(scatter)["n_pad"] == 16
+        assert _tags(scatter)["h2d_bytes"] >= 4 * 4 + 4 * 6 * 4
+        fused = next(s for s in spans if _tags(s)["program"] ==
+                     binpack.place_rounds_batch.__name__)
+        assert _tags(fused)["program"] == "_place_rounds_batched"
+        assert "async" not in _tags(fused)
+        assert _tags(fused)["lanes"] == 3 and _tags(fused)["b_pad"] == 4
+        for key in ("g_pad", "k_cap", "rounds", "n_pad"):
+            assert _tags(fused)[key] >= 1, key
+        assert _tags(fused)["h2d_bytes"] > 0
+        assert _tags(fused)["d2h_bytes"] > 0
+        assert {e.status for e in h.evals} == {"complete"}
+
+    def test_disabled_sites_read_one_bool(self, monkeypatch):
+        """Tracing off: no new site takes the thread's CPU clock, opens
+        a dispatch bracket or a profiler annotation, or touches a
+        tracer — a job over HTTP and a device dispatch run with all of
+        them booby-trapped."""
+        import jax
+        import numpy as np
+
+        from nomad_tpu.models import fleet
+        from nomad_tpu.parallel import devices
+        from nomad_tpu.parallel.devices import put_counted
+
+        def boom(*_a, **_kw):
+            raise AssertionError("a tracing-only call ran with "
+                                 "tracing off")
+        assert trace.ENABLED is False
+        monkeypatch.setattr(time, "thread_time", boom)
+        monkeypatch.setattr(devices, "device_dispatch", boom)
+        monkeypatch.setattr(fleet, "device_dispatch", boom, raising=False)
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+        monkeypatch.setattr(Tracer, "record", boom)
+        monkeypatch.setattr(Tracer, "span", boom)
+        monkeypatch.setattr(Tracer, "new_id", boom)
+        agent, api = _http_agent()
+        try:
+            eval_id = api.job_register(_job(2))["eval_id"]
+            assert _await_eval(api, eval_id).status == "complete"
+            assert agent.server.workers[0].dispatch_failures == 0
+            assert agent.http.stats()["blocking_wakes"] >= 0
+        finally:
+            agent.shutdown()
+        usage = put_counted(np.zeros((16, 6), np.float32))
+        out = fleet._scatter_rows(usage, np.array([1], np.int32),
+                                  np.ones((1, 6), np.float32))
+        assert float(np.asarray(out)[1, 0]) == 1.0
+        assert devices._moved.__dict__ == {}
+
+    @pytest.mark.parametrize("method, path, key", [
+        ("PUT", "/v1/jobs", "job_register"),
+        ("GET", "/v1/jobs", "job_list"),
+        ("GET", "/v1/job/abc", "job_get"),
+        ("DELETE", "/v1/job/abc", "job_deregister"),
+        ("GET", "/v1/job/abc/allocations", "job_allocations"),
+        ("GET", "/v1/evaluation/9f3c?index=7&wait=5s", "eval_get"),
+        ("GET", "/v1/evaluation/9f3c/allocations", "eval_allocations"),
+        ("GET", "/v1/allocation/9f3c", "alloc_get"),
+        ("GET", "/v1/nodes", "node_list"),
+        ("PUT", "/v1/node/n1/drain", "node_drain"),
+        ("GET", "/v1/agent/metrics", "agent_metrics"),
+        ("GET", "/v1/agent/force-leave", "agent_force_leave"),
+        ("GET", "/v1/status/leader", "status_leader"),
+        ("GET", "/v1/job/abc/9f3c-looks-like-an-id", "other"),
+        ("GET", "/v1/agent/9f3c", "other"),
+        ("GET", "/nothing", "other"),
+    ])
+    def test_route_key_is_low_cardinality(self, method, path, key):
+        from nomad_tpu.agent.http_server import route_key
+
+        assert route_key(method, path) == key
+
+    def test_operator_starts_dumps_and_stops_the_recorder(self, tmp_path):
+        """/v1/agent/trace beside /v1/agent/profile: start with a ring,
+        dump a Chrome-trace document that holds the request chain, stop;
+        a profile start brings the span recorder up with it."""
+        agent, api = _http_agent()
+        try:
+            assert _http_get(api, "/v1/agent/trace?action=dump")[0] == 400
+            code, out = _http_get(
+                api, "/v1/agent/trace?action=start&ring=4096&seed=7")
+            assert code == 200 and out == {"tracing": True, "ring": 4096}
+            assert trace.ENABLED and trace.tracer().stats()[
+                "ring_max"] == 4096
+            assert _http_get(api, "/v1/agent/trace?action=start")[0] == 400
+            eval_id = api.job_register(_job(1))["eval_id"]
+            assert _await_eval(api, eval_id).status == "complete"
+            code, doc = _http_get(api, "/v1/agent/trace?action=dump")
+            assert code == 200
+            events = doc["traceEvents"]
+            assert all(e["ph"] == "X" and e["dur"] >= 0 for e in events)
+            assert {"http.serve.job_register", "broker.wait",
+                    "sched.submit"} <= {e["name"] for e in events}
+            assert any(e["args"].get("eval_id") == eval_id
+                       for e in events)
+            code, out = _http_get(api, "/v1/agent/trace?action=stop")
+            assert code == 200 and out["tracing"] is False
+            assert out["spans"]["dropped"] == 0
+            assert trace.ENABLED is False and trace.tracer() is None
+            assert _http_get(api, "/v1/agent/trace?action=stop")[0] == 400
+
+            log_dir = str(tmp_path / "profile")
+            code, out = _http_get(
+                api, f"/v1/agent/profile?action=start&dir={log_dir}")
+            assert code == 200 and out["spans"] == "started"
+            assert trace.ENABLED
+            assert _http_get(api, "/v1/agent/profile?action=stop")[0] == 200
+            assert trace.ENABLED   # the recorder is dumped on its own
+            assert _http_get(api, "/v1/agent/trace?action=stop")[0] == 200
+        finally:
+            trace.disable()
+            agent.shutdown()
