@@ -313,15 +313,12 @@ PyObject* make_instance(PyObject* cls, PyObject* d) {
   return inst;
 }
 
-// Accumulate one node's proposed-alloc network usage into (used, bw).
-int walk_proposed(PyObject* ctx, PyObject* node_id, PyObject* used,
-                  long* bw) {
+// Add every offer of every alloc in `allocs` (any iterable) to
+// (used, bw): the proposed-alloc walk's accounting.  Python twin:
+// scheduler/jax_binpack._add_offers.
+int add_alloc_offers(PyObject* allocs, PyObject* used, long* bw) {
   Interned& I = interned();
-  PyObject* allocs =
-      PyObject_CallMethodObjArgs(ctx, I.proposed_allocs, node_id, nullptr);
-  if (!allocs) return -1;
   PyObject* it = PyObject_GetIter(allocs);
-  Py_DECREF(allocs);
   if (!it) return -1;
   PyObject* alloc;
   while ((alloc = PyIter_Next(it))) {
@@ -424,12 +421,159 @@ int node_base(PyObject* net_base, PyObject* base_fn, PyObject* ch_key,
   return 1;
 }
 
+// The next dynamic port of the LCG stream that is neither in `used` (the
+// lane's own: node-static reserved ports, its plan's picks, walked
+// allocs) nor in `held` (the mirror's occupancy, shared and read-only):
+// linear probe from the draw, the pick added to `used`.  Returns the
+// port as a new PyLong, or nullptr with an error set.  Python twin:
+// FastPlacementMixin._assign_networks_fast.
+PyObject* draw_port(PyObject* used, PyObject* held, long long* lcg,
+                    long min_port, long span) {
+  *lcg = (*lcg * 1103515245LL + 12345LL) & 0x3FFFFFFFLL;
+  long port = min_port + (long)(*lcg % span);
+  for (long tries = 0; tries <= span; tries++) {
+    PyObject* po = PyLong_FromLong(port);
+    if (!po) return nullptr;
+    int hit = PySet_Contains(used, po);
+    if (hit == 0) hit = PySet_Contains(held, po);
+    if (hit == 0 && PySet_Add(used, po) == 0) return po;
+    Py_DECREF(po);
+    if (hit <= 0) return nullptr;
+    port = min_port + (port - min_port + 1) % span;
+  }
+  // Whole dynamic range exhausted on this node: a genuine error (the
+  // Python twin would spin); raise, don't bail.
+  PyErr_SetString(PyExc_RuntimeError, "dynamic port range exhausted");
+  return nullptr;
+}
+
+// What a finish call hands back beside its progress: per-node network
+// states built, and how many of those walked the node's proposed allocs.
+struct NetCounts {
+  long inits = 0;
+  long walks = 0;
+};
+
+// The per-eval inputs of a node's first touch (all borrowed).
+struct NetSources {
+  PyObject* node_net;    // node index -> [used, bw_used, bw_avail, ip, dev]
+  PyObject* net_base;    // node index -> node-static base tuple | None
+  PyObject* base_fn;     // miss callback: _net_base_for(index, node)
+  PyObject* net_seed;    // node index -> (frozenset of live ports, live
+                         // mbits): the usage mirror's occupancy at the
+                         // eval's snapshot
+  PyObject* allocs_idx;  // node id -> store alloc ids
+  PyObject* ctx;         // EvalContext (proposed_allocs for the walk)
+  PyObject* plan_nu;     // plan.node_update
+  PyObject* plan_na;     // plan.node_allocation
+};
+
+// The port and bandwidth occupancy of `node_id` under the in-flight
+// plan, on top of its node-static `base`: *used_out (new reference, the
+// lane's own set), *held_out (new reference, a frozenset the lane only
+// reads) and *bw.  Served from the mirror's occupancy — held by
+// reference, never copied — plus the plan's own placements where the
+// seed holds the node and the plan evicts nothing there; otherwise by the
+// exact walk of ctx.proposed_allocs into `used` (skipped for nodes with
+// no store allocs and no plan deltas).  Python twin:
+// FastPlacementMixin._node_net_init.  Returns 0 ok, -1 error.
+int node_occupancy(const NetSources& src, PyObject* ch_key,
+                   PyObject* node_id, PyObject* base, PyObject** used_out,
+                   PyObject** held_out, long* bw, NetCounts* counts) {
+  *bw = PyLong_AsLong(PyTuple_GET_ITEM(base, 1));
+  int evicts = PyDict_Contains(src.plan_nu, node_id);
+  if (evicts < 0 || PyErr_Occurred()) return -1;
+  PyObject* seed = nullptr;
+  if (!evicts) {
+    seed = PyDict_GetItemWithError(src.net_seed, ch_key);
+    if (!seed && PyErr_Occurred()) return -1;
+  }
+  PyObject* used = *used_out = PySet_New(PyTuple_GET_ITEM(base, 0));
+  if (!used) return -1;
+  if (seed) {
+    *held_out = PyTuple_GET_ITEM(seed, 0);
+    Py_INCREF(*held_out);
+    *bw += PyLong_AsLong(PyTuple_GET_ITEM(seed, 1));
+    if (PyErr_Occurred()) return -1;
+    PyObject* own = PyDict_GetItemWithError(src.plan_na, node_id);
+    if (!own) return PyErr_Occurred() ? -1 : 0;
+    return add_alloc_offers(own, used, bw);
+  }
+  if (!(*held_out = PyFrozenSet_New(nullptr))) return -1;  // the empty one
+  // Probe for proposed allocs needing the exact walk: direct lookup in
+  // the store's allocs-by-node index (snapshots copy-on-write, so the
+  // borrowed dict is stable for the eval), then the plan's deltas.
+  int busy = evicts;
+  if (!busy) {
+    PyObject* entry = PyDict_GetItemWithError(src.allocs_idx, node_id);
+    if (!entry && PyErr_Occurred()) return -1;
+    busy = entry ? PyObject_IsTrue(entry) : 0;
+  }
+  if (busy == 0) busy = PyDict_Contains(src.plan_na, node_id);
+  if (busy <= 0) return busy;
+  counts->walks++;
+  PyObject* allocs = PyObject_CallMethodObjArgs(
+      src.ctx, interned().proposed_allocs, node_id, nullptr);
+  if (!allocs) return -1;
+  int rc = add_alloc_offers(allocs, used, bw);
+  Py_DECREF(allocs);
+  return rc;
+}
+
+// First touch of node `ch` in a finish pass: build its fast network
+// state [used, bw_used, bw_avail, ip, device, held] and park it in
+// node_net.  Returns 1 ok (*out = the state, borrowed from node_net),
+// 0 bail (complex topology: the Python tail owns the placement), -1 error.
+int node_net_init(const NetSources& src, long ch, PyObject* node,
+                  PyObject* node_id, NetCounts* counts, PyObject** out) {
+  PyObject* ch_key = PyLong_FromLong(ch);
+  if (!ch_key) return -1;
+  PyObject* base = nullptr;
+  int rc = node_base(src.net_base, src.base_fn, ch_key, node, &base);
+  if (rc <= 0) {
+    Py_DECREF(ch_key);
+    return rc;
+  }
+  rc = -1;
+  counts->inits++;
+  PyObject* used = nullptr;
+  PyObject* held = nullptr;
+  PyObject* bw_obj = nullptr;
+  PyObject* st = nullptr;
+  long bw = 0;
+  if (node_occupancy(src, ch_key, node_id, base, &used, &held, &bw,
+                     counts) == 0 &&
+      (bw_obj = PyLong_FromLong(bw)) && (st = PyList_New(6))) {
+    gc_untrack(used);                // port ints only
+    PyList_SET_ITEM(st, 0, used);    // steals
+    PyList_SET_ITEM(st, 1, bw_obj);  // steals
+    PyList_SET_ITEM(st, 5, held);    // steals
+    used = held = bw_obj = nullptr;
+    for (int k = 2; k < 5; k++) {  // bw_avail, ip, device
+      PyObject* o = PyTuple_GET_ITEM(base, k);
+      Py_INCREF(o);
+      PyList_SET_ITEM(st, k, o);
+    }
+    gc_untrack(st);  // [set, int, int, str, str, frozenset]
+    if (PyDict_SetItem(src.node_net, ch_key, st) == 0) {
+      *out = st;  // node_net holds it now
+      rc = 1;
+    }
+  }
+  Py_XDECREF(st);
+  Py_XDECREF(bw_obj);
+  Py_XDECREF(held);
+  Py_XDECREF(used);
+  Py_DECREF(ch_key);
+  return rc;
+}
+
 // bulk_finish(place, group_idx, chosen, scores, uuids, slots, nodes,
-//             node_net, net_base, base_fn, allocs_idx, ctx, plan_nu, plan_na,
-//             failed_list, alloc_proto, metric_proto,
+//             node_net, net_base, base_fn, net_seed, allocs_idx, ctx,
+//             plan_nu, plan_na, failed_list, alloc_proto, metric_proto,
 //             alloc_cls, metric_cls, res_cls, net_cls,
 //             statuses, coalesce_all, port_lcg, min_port, max_port)
-//   -> (n_done, port_lcg, failed_map)
+//   -> (n_done, port_lcg, failed_map, node_inits, node_walks)
 //
 // slots[g] = (size_obj, tasks) with tasks = list of
 //   (task_name, res_proto_dict, None | (mbits, net_proto, dyn_labels)).
@@ -442,22 +586,25 @@ int node_base(PyObject* net_base, PyObject* base_fn, PyObject* ch_key,
 // others).
 PyObject* bulk_finish(PyObject*, PyObject* args) {
   PyObject *place, *group_idx, *chosen, *scores, *uuids, *slots, *nodes;
-  PyObject *node_net, *net_base, *base_fn, *allocs_idx, *ctx, *plan_nu,
-      *plan_na;
+  NetSources src;
+  NetCounts counts;
   PyObject *failed_list, *alloc_proto, *metric_proto;
   PyObject *alloc_cls, *metric_cls, *res_cls, *net_cls, *statuses;
   int coalesce_all;
   long long lcg;  // 64-bit: lcg*1103515245 overflows a 32-bit long
   long min_port, max_port;
   if (!PyArg_ParseTuple(
-          args, "OOOOOOOOOOOOOOOOOOOOOOiLll", &place, &group_idx, &chosen,
-          &scores, &uuids, &slots, &nodes, &node_net, &net_base, &base_fn,
-          &allocs_idx, &ctx, &plan_nu, &plan_na, &failed_list, &alloc_proto,
+          args, "OOOOOOOOOOOOOOOOOOOOOOOiLll", &place, &group_idx, &chosen,
+          &scores, &uuids, &slots, &nodes, &src.node_net, &src.net_base,
+          &src.base_fn, &src.net_seed, &src.allocs_idx, &src.ctx,
+          &src.plan_nu, &src.plan_na, &failed_list, &alloc_proto,
           &metric_proto, &alloc_cls, &metric_cls,
           &res_cls, &net_cls, &statuses, &coalesce_all, &lcg, &min_port,
           &max_port)) {
     return nullptr;
   }
+  PyObject* node_net = src.node_net;
+  PyObject* plan_na = src.plan_na;
   Interned& I = interned();
   const long span = max_port - min_port;
   PyObject* st_run = PyTuple_GET_ITEM(statuses, 0);
@@ -563,105 +710,15 @@ PyObject* bulk_finish(PyObject*, PyObject* args) {
         goto fail;
       }
       if (!st) {
-        PyObject* base = nullptr;
-        int rc = node_base(net_base, base_fn, ch_key, node, &base);
-        if (rc < 0) {
+        int rc = node_net_init(src, ch, node, node_id, &counts, &st);
+        if (rc <= 0) {  // 0 = bail: Python path owns this placement
           Py_DECREF(ch_key);
           Py_DECREF(node_id);
           Py_DECREF(tg_key);
           Py_DECREF(tg);
-          goto fail;
-        }
-        if (rc == 0) {  // bail: Python path owns this placement
-          Py_DECREF(ch_key);
-          Py_DECREF(node_id);
-          Py_DECREF(tg_key);
-          Py_DECREF(tg);
+          if (rc < 0) goto fail;
           goto done;
         }
-        PyObject* used = PySet_New(PyTuple_GET_ITEM(base, 0));
-        if (!used) {
-          Py_DECREF(ch_key);
-          Py_DECREF(node_id);
-          Py_DECREF(tg_key);
-          Py_DECREF(tg);
-          goto fail;
-        }
-        long bw = PyLong_AsLong(PyTuple_GET_ITEM(base, 1));
-        // Probe for proposed allocs needing the exact walk: direct
-        // lookup in the store's allocs-by-node index (node_id ->
-        // alloc-id collection; snapshots copy-on-write so the borrowed
-        // dict is stable for the eval).
-        int busy;
-        {
-          PyObject* entry = PyDict_GetItemWithError(allocs_idx, node_id);
-          if (!entry && PyErr_Occurred()) {
-            Py_DECREF(used);
-            Py_DECREF(ch_key);
-            Py_DECREF(node_id);
-            Py_DECREF(tg_key);
-            Py_DECREF(tg);
-            goto fail;
-          }
-          busy = entry ? PyObject_IsTrue(entry) : 0;
-        }
-        if (busy == 0) {
-          int c1 = PyDict_Contains(plan_nu, node_id);
-          int c2 = c1 == 0 ? PyDict_Contains(plan_na, node_id) : c1;
-          if (c1 < 0 || c2 < 0) busy = -1;
-          else busy = (c1 > 0 || c2 > 0) ? 1 : 0;
-        }
-        if (busy < 0) {
-          Py_DECREF(used);
-          Py_DECREF(ch_key);
-          Py_DECREF(node_id);
-          Py_DECREF(tg_key);
-          Py_DECREF(tg);
-          goto fail;
-        }
-        if (busy &&
-            walk_proposed(ctx, node_id, used, &bw) < 0) {
-          Py_DECREF(used);
-          Py_DECREF(ch_key);
-          Py_DECREF(node_id);
-          Py_DECREF(tg_key);
-          Py_DECREF(tg);
-          goto fail;
-        }
-        PyObject* bw_obj = PyLong_FromLong(bw);
-        st = bw_obj ? PyList_New(5) : nullptr;
-        if (!st) {
-          Py_XDECREF(bw_obj);
-          Py_DECREF(used);
-          Py_DECREF(ch_key);
-          Py_DECREF(node_id);
-          Py_DECREF(tg_key);
-          Py_DECREF(tg);
-          goto fail;
-        }
-        PyList_SET_ITEM(st, 0, used);  // steals
-        PyList_SET_ITEM(st, 1, bw_obj);
-        PyObject* avail = PyTuple_GET_ITEM(base, 2);
-        Py_INCREF(avail);
-        PyList_SET_ITEM(st, 2, avail);
-        PyObject* ipo = PyTuple_GET_ITEM(base, 3);
-        Py_INCREF(ipo);
-        PyList_SET_ITEM(st, 3, ipo);
-        PyObject* devo = PyTuple_GET_ITEM(base, 4);
-        Py_INCREF(devo);
-        PyList_SET_ITEM(st, 4, devo);
-        gc_untrack(used);  // port ints only
-        gc_untrack(st);    // [set, int, int, str, str]
-        int rc2 = PyDict_SetItem(node_net, ch_key, st);
-        Py_DECREF(st);  // dict holds it now
-        if (rc2 < 0) {
-          Py_DECREF(ch_key);
-          Py_DECREF(node_id);
-          Py_DECREF(tg_key);
-          Py_DECREF(tg);
-          goto fail;
-        }
-        st = PyDict_GetItem(node_net, ch_key);  // borrowed
       }
       Py_DECREF(ch_key);
 
@@ -670,6 +727,7 @@ PyObject* bulk_finish(PyObject*, PyObject* args) {
       long bw_avail = PyLong_AsLong(PyList_GET_ITEM(st, 2));
       PyObject* node_ip = PyList_GET_ITEM(st, 3);
       PyObject* node_dev = PyList_GET_ITEM(st, 4);
+      PyObject* held = PyList_GET_ITEM(st, 5);
 
       // Total bandwidth ask up-front: no mid-slot rollback needed.
       long total_mbits = 0;
@@ -728,42 +786,9 @@ PyObject* bulk_finish(PyObject*, PyObject* args) {
           }
           bool port_fail = false;
           for (Py_ssize_t dp = 0; dp < n_dyn && !port_fail; dp++) {
-            lcg = (lcg * 1103515245LL + 12345LL) & 0x3FFFFFFFLL;
-            long port = min_port + (long)(lcg % span);
-            long tries = 0;
-            while (true) {
-              PyObject* po = PyLong_FromLong(port);
-              if (!po) {
-                port_fail = true;
-                break;
-              }
-              int hit = PySet_Contains(used, po);
-              if (hit < 0) {
-                Py_DECREF(po);
-                port_fail = true;
-                break;
-              }
-              if (!hit) {
-                if (PySet_Add(used, po) < 0 ||
-                    PyList_Append(ports, po) < 0) {
-                  Py_DECREF(po);
-                  port_fail = true;
-                  break;
-                }
-                Py_DECREF(po);
-                break;
-              }
-              Py_DECREF(po);
-              port = min_port + (port - min_port + 1) % span;
-              if (++tries > span) {
-                // Whole dynamic range exhausted on this node: a genuine
-                // error (the Python twin would spin); raise, don't bail.
-                PyErr_SetString(PyExc_RuntimeError,
-                                "dynamic port range exhausted");
-                port_fail = true;
-                break;
-              }
-            }
+            PyObject* po = draw_port(used, held, &lcg, min_port, span);
+            port_fail = !po || PyList_Append(ports, po) < 0;
+            Py_XDECREF(po);
           }
           if (port_fail) {
             Py_DECREF(ports);
@@ -990,7 +1015,8 @@ PyObject* bulk_finish(PyObject*, PyObject* args) {
   }
 
 done:
-  return Py_BuildValue("(nLN)", p, lcg, failed_map);
+  return Py_BuildValue("(nLNll)", p, lcg, failed_map, counts.inits,
+                       counts.walks);
 
 fail:
   Py_DECREF(failed_map);
@@ -1015,27 +1041,31 @@ fail:
 // bulk_finish_cols(chosen, group_l, uuids, names, tg_names,
 //                  slot_mbits, slot_ndyn, ports_buf,
 //                  nids_out, ips_out, devs_out, lazy_proto, alloc_cls,
-//                  nodes, node_net, net_base, base_fn, allocs_idx, ctx,
-//                  plan_nu, plan_na, port_lcg, min_port, max_port)
-//   -> (n_done, port_lcg)
+//                  nodes, node_net, net_base, base_fn, net_seed,
+//                  allocs_idx, ctx, plan_nu, plan_na, port_lcg, min_port,
+//                  max_port)
+//   -> (n_done, port_lcg, node_inits, node_walks)
 // ---------------------------------------------------------------------------
 PyObject* bulk_finish_cols(PyObject*, PyObject* args) {
   PyObject *chosen, *group_l, *uuids, *names, *tg_names;
   PyObject *slot_mbits, *slot_ndyn;
   Py_buffer ports_buf;
   PyObject *nids_out, *ips_out, *devs_out, *lazy_proto, *alloc_cls;
-  PyObject *nodes, *node_net, *net_base, *base_fn, *allocs_idx, *ctx,
-      *plan_nu, *plan_na;
+  PyObject* nodes;
+  NetSources src;
+  NetCounts counts;
   long long lcg;
   long min_port, max_port;
   if (!PyArg_ParseTuple(
-          args, "OOOOOOOw*OOOOOOOOOOOOOLll", &chosen, &group_l, &uuids,
+          args, "OOOOOOOw*OOOOOOOOOOOOOOLll", &chosen, &group_l, &uuids,
           &names, &tg_names, &slot_mbits, &slot_ndyn, &ports_buf,
           &nids_out, &ips_out, &devs_out, &lazy_proto, &alloc_cls,
-          &nodes, &node_net, &net_base, &base_fn, &allocs_idx, &ctx,
-          &plan_nu, &plan_na, &lcg, &min_port, &max_port)) {
+          &nodes, &src.node_net, &src.net_base, &src.base_fn,
+          &src.net_seed, &src.allocs_idx, &src.ctx, &src.plan_nu,
+          &src.plan_na, &lcg, &min_port, &max_port)) {
     return nullptr;
   }
+  PyObject* plan_na = src.plan_na;
   Interned& I = interned();
   const long span = max_port - min_port;
   Py_ssize_t P = PyList_GET_SIZE(chosen);
@@ -1077,82 +1107,12 @@ PyObject* bulk_finish_cols(PyObject*, PyObject* args) {
         break;
       }
       nid_of[ch] = node_id;  // owned for the rest of the call
-      PyObject* ch_key = PyLong_FromLong(ch);
-      if (!ch_key) {
-        failed = true;
-        break;
-      }
-      PyObject* base = nullptr;
-      int rc = node_base(net_base, base_fn, ch_key, node, &base);
+      int rc = node_net_init(src, ch, node, node_id, &counts, &st);
       if (rc < 0) {
-        Py_DECREF(ch_key);
         failed = true;
         break;
       }
-      if (rc == 0) {  // complex topology: Python tail owns it
-        Py_DECREF(ch_key);
-        break;
-      }
-      PyObject* used = PySet_New(PyTuple_GET_ITEM(base, 0));
-      if (!used) {
-        Py_DECREF(ch_key);
-        failed = true;
-        break;
-      }
-      long bw = PyLong_AsLong(PyTuple_GET_ITEM(base, 1));
-      int busy;
-      {
-        PyObject* entry = PyDict_GetItemWithError(allocs_idx, node_id);
-        if (!entry && PyErr_Occurred()) {
-          Py_DECREF(used);
-          Py_DECREF(ch_key);
-          failed = true;
-          break;
-        }
-        busy = entry ? PyObject_IsTrue(entry) : 0;
-      }
-      if (busy == 0) {
-        int c1 = PyDict_Contains(plan_nu, node_id);
-        int c2 = c1 == 0 ? PyDict_Contains(plan_na, node_id) : c1;
-        if (c1 < 0 || c2 < 0) busy = -1;
-        else busy = (c1 > 0 || c2 > 0) ? 1 : 0;
-      }
-      if (busy < 0 ||
-          (busy && walk_proposed(ctx, node_id, used, &bw) < 0)) {
-        Py_DECREF(used);
-        Py_DECREF(ch_key);
-        failed = true;
-        break;
-      }
-      PyObject* bw_obj = PyLong_FromLong(bw);
-      st = bw_obj ? PyList_New(5) : nullptr;
-      if (!st) {
-        Py_XDECREF(bw_obj);
-        Py_DECREF(used);
-        Py_DECREF(ch_key);
-        failed = true;
-        break;
-      }
-      PyList_SET_ITEM(st, 0, used);    // steals
-      PyList_SET_ITEM(st, 1, bw_obj);  // steals
-      PyObject* avail = PyTuple_GET_ITEM(base, 2);
-      Py_INCREF(avail);
-      PyList_SET_ITEM(st, 2, avail);
-      PyObject* ipo = PyTuple_GET_ITEM(base, 3);
-      Py_INCREF(ipo);
-      PyList_SET_ITEM(st, 3, ipo);
-      PyObject* devo = PyTuple_GET_ITEM(base, 4);
-      Py_INCREF(devo);
-      PyList_SET_ITEM(st, 4, devo);
-      gc_untrack(used);
-      gc_untrack(st);
-      int rc2 = PyDict_SetItem(node_net, ch_key, st);
-      Py_DECREF(st);  // node_net holds it now
-      Py_DECREF(ch_key);
-      if (rc2 < 0) {
-        failed = true;
-        break;
-      }
+      if (rc == 0) break;  // complex topology: Python tail owns it
       st_of[ch] = st;  // borrowed from node_net for this call
     }
 
@@ -1165,41 +1125,14 @@ PyObject* bulk_finish_cols(PyObject*, PyObject* args) {
     if (bw_used + total_mbits > bw_avail) break;  // divergence: tail
 
     PyObject* used = PyList_GET_ITEM(st, 0);
+    PyObject* held = PyList_GET_ITEM(st, 5);
     bool port_fail = false;
     for (long d = 0; d < ndyn && !port_fail; d++) {
-      lcg = (lcg * 1103515245LL + 12345LL) & 0x3FFFFFFFLL;
-      long port = min_port + (long)(lcg % span);
-      long tries = 0;
-      while (true) {
-        PyObject* po = PyLong_FromLong(port);
-        if (!po) {
-          port_fail = true;
-          break;
-        }
-        int hit = PySet_Contains(used, po);
-        if (hit < 0) {
-          Py_DECREF(po);
-          port_fail = true;
-          break;
-        }
-        if (!hit) {
-          int rc3 = PySet_Add(used, po);
-          Py_DECREF(po);
-          if (rc3 < 0) {
-            port_fail = true;
-            break;
-          }
-          pbuf[poff + d] = (int32_t)port;
-          break;
-        }
+      PyObject* po = draw_port(used, held, &lcg, min_port, span);
+      port_fail = !po;
+      if (po) {
+        pbuf[poff + d] = (int32_t)PyLong_AsLong(po);
         Py_DECREF(po);
-        port = min_port + (port - min_port + 1) % span;
-        if (++tries > span) {
-          PyErr_SetString(PyExc_RuntimeError,
-                          "dynamic port range exhausted");
-          port_fail = true;
-          break;
-        }
       }
     }
     if (port_fail) {
@@ -1288,10 +1221,10 @@ PyObject* bulk_finish_cols(PyObject*, PyObject* args) {
     }
     return nullptr;
   }
-  return Py_BuildValue("(nL)", p, lcg);
+  return Py_BuildValue("(nLll)", p, lcg, counts.inits, counts.walks);
 }
 
-// bulk_finish_many(items) -> [(n_done, port_lcg), ...]
+// bulk_finish_many(items) -> [(n_done, port_lcg, inits, walks), ...]
 //
 // items: list of bulk_finish_cols argument TUPLES (built by
 // scheduler/jax_binpack._finish_native_args), one per evaluation of a
@@ -1354,7 +1287,7 @@ PyMODINIT_FUNC PyInit__nomad_native(void) {
   // Bumped on any signature/behavior change of an existing function so a
   // stale prebuilt .so (same names, old ABI) is detected by the loader
   // (nomad_tpu/utils/native.py) instead of crashing mid-eval.
-  if (PyModule_AddIntConstant(m, "ABI_VERSION", 6) < 0) {
+  if (PyModule_AddIntConstant(m, "ABI_VERSION", 7) < 0) {
     Py_DECREF(m);
     return nullptr;
   }

@@ -447,7 +447,12 @@ def _net_row_build(alloc: Allocation):
         ports.extend(n0.reserved_ports)
         mbits += n0.mbits
         k = (n0.ip, n0.device)
-        if key is None:
+        if len(nets) > 1:
+            # The scheduler's proposed-alloc walk counts every network
+            # of a task, NetworkIndex.add_allocs only the first: a node
+            # holding such an alloc keeps the exact walks on both sides.
+            key = NET_KEY_ODD
+        elif key is None:
             key = k
         elif k != key:
             key = NET_KEY_ODD
@@ -557,11 +562,12 @@ class UsageMirror:
         # maintained by the same scatters as the single-device copy.
         # Invariant: every resident value exactly equals self.usage.
         self._sharded = ShardedResidency()
-        # Per-node port/bandwidth tracking for the vectorized plan
-        # verifier (server/plan_apply).  Disabled until sync_net() is
-        # first called so scheduler-only users pay nothing; once
-        # enabled, maintained incrementally by the same delta walk as
-        # usage.  All keyed by node index, empties pruned:
+        # Per-node port/bandwidth tracking, read by the vectorized plan
+        # verifier (server/plan_apply) and by the scheduler's finish
+        # (net_occupancy).  Disabled until sync_net() is first called:
+        # the first verify or finish pays one O(allocs) _rebuild_net;
+        # from then on it is maintained incrementally by the same delta
+        # walk as usage.  All keyed by node index, empties pruned:
         #   net_rows:   alloc_id -> (ni, ports, mbits, (ip, device))
         #   node_ports: ni -> {port: live count}
         #   node_dup:   ni -> number of ports with count > 1
@@ -675,6 +681,43 @@ class UsageMirror:
                 self._publish_fence()
             return ok
 
+    def net_occupancy(self, state, node_indexes) -> dict:
+        """Port/bandwidth occupancy of the given nodes at exactly
+        ``state``: ``{node index: (frozenset of live ports, live
+        mbits)}``, copied
+        under one lock hold so the finish reads it unlocked.  What the
+        scheduler's finish seeds a node's network state from in place
+        of walking the node's allocations (FastPlacementMixin.
+        _node_net_init, native/port_alloc.cpp node_net_init).
+
+        A node is served only when the merged per-node counts equal
+        what the proposed-alloc walk would collect: every live offer on
+        the node's one (ip, device) and no port held twice.  Nodes left
+        out — and every node when ``state`` is older than the mirror —
+        take the exact walk."""
+        statics = self.statics
+        nodes = statics.nodes
+        out: dict = {}
+        with self._lock:
+            if not self.sync_net(state):
+                return out
+            keys_of = self.node_net_keys
+            ports_of = self.node_ports
+            bw_of = self.node_bw
+            dup_of = self.node_dup
+            for ni in node_indexes:
+                if ni < 0:
+                    continue
+                keys = keys_of.get(ni)
+                if keys:
+                    base = net_base_for(statics, ni, nodes[ni])
+                    if base is None or len(keys) > 1 or ni in dup_of \
+                            or (base[3], base[4]) not in keys:
+                        continue
+                out[ni] = (frozenset(ports_of.get(ni, ())),
+                           bw_of.get(ni, 0))
+        return out
+
     def _changed_ids(self, log: list, target: int) -> set:
         start = self._log_pos if log is self._log_ref else 0
         changed: set = set()
@@ -722,7 +765,7 @@ class UsageMirror:
         if self._net_ready:
             self._rebuild_net(table)
 
-    # -- net tracking (vectorized plan verifier) ---------------------------
+    # -- net tracking (plan verifier, scheduler finish) --------------------
     def _rebuild_net(self, table: dict) -> None:
         index_of = self.statics.index_of
         self.net_rows = {}

@@ -99,6 +99,11 @@ class BatchEvalRunner:
         self.device_dispatches = 0
         self.sharded_dispatches = 0
         self.fused_batches = 0   # fused windows planned, either executor
+        # Finish: per-node network states built, and how many of those
+        # walked the node's allocations because the usage mirror's
+        # occupancy could not serve them (nomad.finish.*).
+        self.finish_node_inits = 0
+        self.finish_node_walks = 0
         # Tracing only: eval id -> span id of the stage span (sched.begin
         # / sched.submit) that eval is under right now; the planner's
         # ``sched.status`` span (server/worker.py) takes it as parent.
@@ -113,6 +118,17 @@ class BatchEvalRunner:
         self.sharded_dispatches += calls["sharded"]
         sched.kernel_calls = dict.fromkeys(calls, 0)
 
+    def _note_finish(self, scheds: list) -> dict:
+        """Fold the schedulers' node-init counts into nomad.finish.*;
+        returns them as the tags of the ``sched.finish`` span."""
+        inits = sum(s.net_inits for s in scheds)
+        walks = sum(s.net_walks for s in scheds)
+        for s in scheds:
+            s.net_inits = s.net_walks = 0
+        self.finish_node_inits += inits
+        self.finish_node_walks += walks
+        return {"node_inits": inits, "walked": walks}
+
     def stats(self) -> dict:
         """Registry provider (obs/registry.py): the dispatch mix."""
         return {
@@ -120,6 +136,15 @@ class BatchEvalRunner:
             "device_dispatches": self.device_dispatches,
             "sharded_dispatches": self.sharded_dispatches,
             "fused_batches": self.fused_batches,
+        }
+
+    def finish_stats(self) -> dict:
+        """Registry provider: how often the finish seeds a node from
+        the usage mirror (``node_inits`` − ``node_walks``) against how
+        often it walks the node's allocations."""
+        return {
+            "node_inits": self.finish_node_inits,
+            "node_walks": self.finish_node_walks,
         }
 
     def _split_rounds(self, evals: list[Evaluation]
@@ -222,6 +247,7 @@ class BatchEvalRunner:
         t0 = _tnow()
         retry.process(ev)
         self._note_dispatch(retry)
+        self._note_finish([retry])
         # One span over the whole re-plan.  Its status write is a
         # sibling ``sched.status`` under the eval's anchor, not a child:
         # the re-plan's own time stays a leaf of the eval's tree.
@@ -482,13 +508,36 @@ class BatchEvalRunner:
         _lane_spans("sched.dispatch", [sched], t0, t1)
         sched.finish_deferred(place, args, chosen, scores)
         self._note_dispatch(sched)
-        _lane_spans("sched.finish", [sched], t1, _tnow())
+        _lane_spans("sched.finish", [sched], t1, _tnow(),
+                    **self._note_finish([sched]))
         self._finish(sched, retries)
 
     @staticmethod
-    def _finish_lanes(lanes: list) -> None:
+    def _window_net_seed(lanes: list) -> "dict | None":
+        """One copy of the usage mirror's port/bandwidth occupancy for
+        a whole window (UsageMirror.net_occupancy over every lane's
+        chosen nodes) when its lanes plan on one snapshot — a fused
+        window's do, and they mostly pick the same nodes; None
+        otherwise: each lane then copies its own."""
+        if len(lanes) < 2:
+            return None
+        first, _place, args0, *_ = lanes[0]
+        if any(s.state is not first.state or a.statics is not args0.statics
+               for s, _p, a, *_r in lanes):
+            return None
+        from nomad_tpu.models.fleet import mirror_for
+
+        touched: set = set()
+        for *_x, chosen, _scores in lanes:
+            touched.update(chosen if type(chosen) is list
+                           else chosen.tolist())
+        return mirror_for(args0.statics).net_occupancy(first.state,
+                                                       touched)
+
+    def _finish_lanes(self, lanes: list) -> None:
         """Windowed finish for a list of lanes in lane order — ONE
-        shared uuid slab (structs.generate_uuids) and ONE native call
+        shared uuid slab (structs.generate_uuids), ONE copy of the
+        mirror's occupancy (``_window_net_seed``) and ONE native call
         (native/port_alloc.cpp bulk_finish_many) cover every lane's
         happy-path prefix, then each lane's Python tail runs.  The one
         implementation of the windowed finish sequence, shared by the
@@ -503,12 +552,14 @@ class BatchEvalRunner:
 
         uuid_slab = generate_uuids(
             sum(len(place) for _, place, *_ in lanes))
+        net_seed = self._window_net_seed(lanes)
         states = []
         nargs = []
         off = 0
         for sched, place, args, chosen, scores in lanes:
             fs = sched._finish_prepare(place, args, chosen, scores,
-                                       uuid_slab[off:off + len(place)])
+                                       uuid_slab[off:off + len(place)],
+                                       net_seed)
             off += len(place)
             states.append(fs)
             nargs.append(sched._finish_native_args(fs))
@@ -533,8 +584,9 @@ class BatchEvalRunner:
                             fs, native.bulk_finish(*a))
         for (sched, *_rest), fs in zip(lanes, states):
             sched._finish_python_tail(fs)
-        _lane_spans("sched.finish", [s for s, *_r in lanes],
-                    t_fin, _tnow(), window=len(lanes))
+        scheds = [s for s, *_r in lanes]
+        _lane_spans("sched.finish", scheds, t_fin, _tnow(),
+                    window=len(lanes), **self._note_finish(scheds))
 
     def _finish_window(self, done: list, retries=None) -> None:
         """Windowed finish + group submit for fused lanes

@@ -108,7 +108,7 @@ def build_bulk_args(sched, place, group_l, chosen_l, scores_l,
         place if type(place) is list else list(place),
         group_l, chosen_l, scores_l, uuids, slots_c,
         statics.nodes, sched._node_net, statics.net_base,
-        sched._net_base_for,
+        sched._net_base_for, sched._net_seed,
         sched.state.allocs_node_index(), sched.ctx, plan.node_update,
         plan.node_allocation, plan.failed_allocs,
         alloc_proto, metric_proto,
@@ -127,11 +127,14 @@ def run_bulk_finish(native, sched, place, group_l, chosen_l, scores_l,
     happy path), shared by the generic and system schedulers.  ``sched``
     supplies the per-eval placement state (_node_net/_net_base_for/
     _port_lcg via FastPlacementMixin, plan, state, ctx).  Returns
-    (resume index, failed-TG map); updates sched._port_lcg."""
-    start_p, sched._port_lcg, fmap = native.bulk_finish(
+    (resume index, failed-TG map); updates sched._port_lcg and the
+    node-init counters."""
+    start_p, sched._port_lcg, fmap, inits, walks = native.bulk_finish(
         *build_bulk_args(sched, place, group_l, chosen_l, scores_l,
                          uuids, slots_c, alloc_proto, metric_proto,
                          coalesce_all, sched._port_lcg))
+    sched.net_inits += inits
+    sched.net_walks += walks
     return start_p, fmap
 
 
@@ -312,12 +315,52 @@ class _FinishState:
                  "slab")
 
 
+_NO_PORTS: frozenset = frozenset()
+
+
+def _add_offers(used: set, allocs) -> int:
+    """Add every offer's ports of ``allocs`` to ``used``; returns the
+    offers' summed mbits (the proposed-alloc walk's accounting; C twin:
+    native/port_alloc.cpp add_alloc_offers)."""
+    bw = 0
+    for alloc in allocs:
+        for tr in alloc.task_resources.values():
+            for offer in tr.networks:
+                used.update(offer.reserved_ports)
+                bw += offer.mbits
+    return bw
+
+
 class FastPlacementMixin:
     """Host-side placement machinery shared by the device-backed generic
     scheduler and the vectorized system scheduler: fleet-wide proposed
     allocs, exact + O(1) network assignment, and post-divergence fit
     re-checks.  Host classes provide self.state/self.plan/self.ctx and
-    per-eval `_statics`/`_net_cache`/`_node_net`/`_port_lcg`."""
+    call ``_finish_reset`` before each finish pass."""
+
+    # Per-node network states this scheduler built (first touches of a
+    # node in a finish pass), and how many of those had to walk the
+    # node's proposed allocations because the usage mirror's occupancy
+    # could not serve them.  The batch runner folds both into
+    # nomad.finish.* and the sched.finish span's tags.
+    net_inits = 0
+    net_walks = 0
+
+    def _finish_reset(self, statics, chosen_l: list,
+                      net_seed: "dict | None" = None) -> None:
+        """Per-finish placement state: the exact path's NetworkIndex
+        cache, the fast per-node network states (_node_net_init), the
+        port LCG, and the mirror's port/bandwidth occupancy of the
+        nodes this pass will touch — copied under the mirror's lock
+        once (here, unless a windowed finish passed the copy it made
+        for all its lanes), so neither the native loop nor the Python
+        tail holds that lock (the applier's verify takes it)."""
+        self._net_cache: dict = {}
+        self._node_net: dict = {}
+        self._statics = statics
+        self._port_lcg = _randrange(1 << 30)
+        self._net_seed: dict = net_seed if net_seed is not None else \
+            mirror_for(statics).net_occupancy(self.state, set(chosen_l))
 
     def _proposed_allocs_all(self) -> list:
         """All non-terminal allocs under the in-flight plan: existing minus
@@ -342,29 +385,40 @@ class FastPlacementMixin:
 
     def _node_net_init(self, node_index: int, node):
         """Fast per-node network state: [used_ports, bw_used, bw_avail,
-        ip, device], or None when the topology needs the exact path
-        (multi-network nodes).  The reserved-only base is node-static and
-        cached on the fleet statics; per-eval state adds proposed allocs'
-        offers on top."""
+        ip, device, held_ports], or None when the topology needs the
+        exact path (multi-network nodes).  A port is taken when it is
+        in ``used_ports`` (this lane's own set: the node-static
+        reserved ports, its plan's picks) or in ``held_ports`` (a
+        frozenset the lane only reads).  The store's allocations come
+        from the usage mirror's occupancy (``_net_seed``) as
+        ``held_ports``, by reference, where it serves the node and the
+        plan evicts nothing there; otherwise from the exact walk of the
+        node's proposed allocs, into ``used_ports``."""
         base = self._net_base_for(node_index, node)
         if base is None:
             return None
         used = set(base[0])
         bw_used = base[1]
-        # O(1) emptiness probes (live, not precomputed: the plan grows
-        # during the finish loop): only nodes with store allocs or plan
-        # deltas need the exact proposed-alloc walk.
+        held = _NO_PORTS
         node_id = node.id
         plan = self.plan
-        if self.state.has_allocs_on_node(node_id) or \
+        self.net_inits += 1
+        seed = self._net_seed.get(node_index)
+        if seed is not None and node_id not in plan.node_update:
+            # The plan's own placements are read live: the plan grows
+            # during the finish loop.
+            held = seed[0]
+            bw_used += seed[1] + _add_offers(
+                used, plan.node_allocation.get(node_id, ()))
+        elif self.state.has_allocs_on_node(node_id) or \
                 node_id in plan.node_update or \
                 node_id in plan.node_allocation:
-            for alloc in self.ctx.proposed_allocs(node_id):
-                for tr in alloc.task_resources.values():
-                    for offer in tr.networks:
-                        used.update(offer.reserved_ports)
-                        bw_used += offer.mbits
-        return [used, bw_used, base[2], base[3], base[4]]
+            # O(1) emptiness probes: only nodes with store allocs or
+            # plan deltas need the exact proposed-alloc walk.
+            self.net_walks += 1
+            bw_used += _add_offers(used,
+                                   self.ctx.proposed_allocs(node_id))
+        return [used, bw_used, base[2], base[3], base[4], held]
 
     def _assign_networks_fast(self, node_index: int, node, plan_tasks):
         """O(1) port/bandwidth assignment for single-network dynamic-port
@@ -379,7 +433,7 @@ class FastPlacementMixin:
                 return self._assign_networks(
                     node, None, plan_tasks=plan_tasks)
             self._node_net[node_index] = st
-        used, bw_used, bw_avail, ip, device = st
+        used, bw_used, bw_avail, ip, device, held = st
 
         out = {}
         span = MAX_DYNAMIC_PORT - MIN_DYNAMIC_PORT
@@ -415,7 +469,7 @@ class FastPlacementMixin:
                 # reference's random picks; exact value is untested API).
                 lcg = (lcg * 1103515245 + 12345) & 0x3FFFFFFF
                 port = MIN_DYNAMIC_PORT + lcg % span
-                while port in used:
+                while port in used or port in held:
                     port = MIN_DYNAMIC_PORT + (port - MIN_DYNAMIC_PORT
                                                + 1) % span
                 used.add(port)
@@ -463,6 +517,8 @@ class FastPlacementMixin:
         cache = getattr(self, "_net_cache", None)
         net_idx = cache.get(node.id) if cache is not None else None
         if net_idx is None:
+            self.net_inits += 1
+            self.net_walks += 1
             net_idx = NetworkIndex()
             net_idx.set_node(node)
             net_idx.add_allocs(self.ctx.proposed_allocs(node.id))
@@ -1019,24 +1075,20 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
 
     def _finish_prepare(self, place: list, args: DeviceArgs,
                         chosen, scores,
-                        uuids: "list | None" = None) -> "_FinishState":
+                        uuids: "list | None" = None,
+                        net_seed: "dict | None" = None) -> "_FinishState":
         """Host-side finish state for one eval: per-plan network caches,
-        alloc/metric protos, list-form device choices, uuids (minted
-        here unless the pipeline passed a shared slab slice)."""
+        alloc/metric protos, list-form device choices, uuids and the
+        mirror's occupancy (minted / copied here unless a windowed
+        finish passed its shared slab slice / window copy)."""
         statics = args.statics
         device_time = time.perf_counter() - args.start
         per_time = device_time / max(1, len(place))
-        # Per-node NetworkIndex cache for this plan (exact path) and the
-        # fast per-node [used_ports, bw_used, bw_avail, ip, device] state.
-        self._net_cache: dict = {}
-        self._node_net: dict = {}
-        self._statics = statics
-        self._port_lcg = _randrange(1 << 30)
-
         fs = _FinishState()
         fs.place = place
         fs.args = args
         fs.chosen_l = chosen if type(chosen) is list else chosen.tolist()
+        self._finish_reset(statics, fs.chosen_l, net_seed)
         fs.scores_l = scores if type(scores) is list else scores.tolist()
         fs.uuids = uuids if uuids is not None else \
             generate_uuids(len(place))
@@ -1132,6 +1184,7 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
                 slab.ips, slab.devs, lazy_proto, SlabAlloc,
                 self._statics.nodes, self._node_net,
                 self._statics.net_base, self._net_base_for,
+                self._net_seed,
                 self.state.allocs_node_index(), self.ctx,
                 self.plan.node_update, self.plan.node_allocation,
                 self._port_lcg, MIN_DYNAMIC_PORT, MAX_DYNAMIC_PORT)
@@ -1139,17 +1192,20 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
     def _finish_consume_native(self, fs: "_FinishState",
                                result: tuple) -> None:
         """Fold one native finish result back into the finish state.
-        Columnar path: (n_done, lcg) — the slab seals its happy prefix.
-        Object path: (n_done, lcg, failed map); fmap stays empty under
-        generic semantics — the C loop bails on a task group's first
+        Columnar path: (n_done, lcg, node inits, of them walked) — the
+        slab seals its happy prefix.  Object path: (n_done, lcg, failed
+        map, node inits, walked); fmap stays empty under generic
+        semantics — the C loop bails on a task group's first
         chosen-less placement so the Python tail can rescue or explain
         it."""
         if fs.slab is not None:
-            fs.start_p, self._port_lcg = result
+            fs.start_p, self._port_lcg, inits, walks = result
             fs.slab.seal(fs.start_p)
-            return
-        fs.start_p, self._port_lcg, fmap = result
-        fs.failed_tg.update(fmap)
+        else:
+            fs.start_p, self._port_lcg, fmap, inits, walks = result
+            fs.failed_tg.update(fmap)
+        self.net_inits += inits
+        self.net_walks += walks
 
     def _finish_python_tail(self, fs: "_FinishState") -> None:
         """Per-placement Python finish loop from fs.start_p: exact host

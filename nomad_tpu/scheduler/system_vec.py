@@ -31,8 +31,6 @@ from __future__ import annotations
 
 import time
 
-from random import randrange as _randrange
-
 import numpy as np
 
 from nomad_tpu.models.constraints import compile_group_mask
@@ -223,11 +221,6 @@ class VectorSystemScheduler(SystemScheduler, FastPlacementMixin):
                     slots_c_holder, chosen, scores) -> None:
         # --- stage 3: finish (native prefix + Python resume) ------------
         nodes_arr = statics.nodes
-        self._net_cache = {}
-        self._node_net = {}
-        self._statics = statics
-        self._port_lcg = _randrange(1 << 30)
-
         plan = self.plan
         job = self.job
         uuids = generate_uuids(len(place))
@@ -243,6 +236,7 @@ class VectorSystemScheduler(SystemScheduler, FastPlacementMixin):
         mask_rejected: set = set()
         chosen_l = chosen.tolist()
         scores_l = scores.tolist()
+        self._finish_reset(statics, chosen_l)
 
         start_p = 0
         native = _native_bulk()

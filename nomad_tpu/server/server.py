@@ -354,6 +354,7 @@ class Server:
                 # The fused runner's dispatch mix: which engine (host
                 # twin / device / sharded) actually ran the kernels.
                 reg.register("batch_runner", w.runner.stats)
+                reg.register("finish", w.runner.finish_stats)
         if self.rpc_server is not None:
             reg.register("rpc", self.rpc_server.stats)
         self.obs_registry = reg

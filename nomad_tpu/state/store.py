@@ -363,17 +363,19 @@ class _ReadMixin:
 
     def has_allocs_on_node(self, node_id: str) -> bool:
         """O(1) emptiness probe — the scheduler finish path calls this
-        once per placed node to skip proposed-alloc scans on fresh
-        nodes."""
+        for a placed node the usage mirror's occupancy does not serve
+        (UsageMirror.net_occupancy), to skip the proposed-alloc walk on
+        fresh nodes."""
         return bool(self._t.allocs_by_node.get(node_id))
 
     def allocs_node_index(self) -> dict:
         """The raw node_id -> alloc-id-collection index, READ-ONLY.
 
         Handed to the native bulk finish (native/port_alloc.cpp) so the
-        per-node emptiness probe is a C dict lookup instead of a Python
-        call per placement.  Safe to borrow for an eval: writers copy
-        shared indexes before mutating (copy-on-write, _writable_index)."""
+        emptiness probe of a node the usage mirror's occupancy does not
+        serve — the walk's guard — is a C dict lookup instead of a
+        Python call.  Safe to borrow for an eval: writers copy shared
+        indexes before mutating (copy-on-write, _writable_index)."""
         return self._t.allocs_by_node
 
     def allocs_by_job(self, job_id: str) -> list:

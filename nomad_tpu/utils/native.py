@@ -51,7 +51,7 @@ def _try_build() -> None:
 # native/port_alloc.cpp's exported ABI_VERSION.  A same-name signature
 # change is invisible to hasattr() probes, so a stale prebuilt .so would
 # otherwise crash mid-eval.
-EXPECTED_ABI = 6
+EXPECTED_ABI = 7
 
 
 def _stale(repo: str) -> bool:

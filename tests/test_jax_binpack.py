@@ -409,6 +409,7 @@ def test_fast_network_rollback_keeps_cached_index_coherent():
     sched = JaxBinPackScheduler.__new__(JaxBinPackScheduler)
     sched._statics = build_fleet([node])
     sched._node_net = {}
+    sched._net_seed = {}
     sched._port_lcg = 12345
     sched.state = StateStore()
     sched.plan = Plan()

@@ -1125,6 +1125,8 @@ class TestWholePathSpans:
             assert metrics["nomad.http.blocking_wakes_changed"] == 0
             assert metrics["nomad.workers.batches"] >= 0
             assert metrics["nomad.workers.batch_busy_s"] >= 0.0
+            assert metrics["nomad.finish.node_inits"] >= \
+                metrics["nomad.finish.node_walks"] >= 0
         finally:
             for w in srv.workers:
                 w.set_pause(False)

@@ -27,6 +27,7 @@ from nomad_tpu.state import StateStore
 from nomad_tpu.structs import (
     ALLOC_CLIENT_STATUS_FAILED,
     Allocation,
+    NetworkResource,
     Plan,
     Resources,
     generate_uuid,
@@ -328,3 +329,137 @@ def test_scatter_rows_pads_to_pow2_and_stays_exact():
         want[idx] = rows
         usage_d = _scatter_rows(usage_d, idx, rows)
         np.testing.assert_array_equal(np.asarray(usage_d), want)
+
+
+# ---------------------------------------------------------------------------
+# net_occupancy: what the scheduler's finish seeds a node's ports and
+# bandwidth from (scheduler/jax_binpack.FastPlacementMixin._node_net_init)
+# ---------------------------------------------------------------------------
+
+def _net_alloc(node, offers, terminal=False) -> Allocation:
+    """One alloc on ``node``; ``offers`` is one (ip | None = the node's
+    own, device, ports, mbits) per task."""
+    own_ip = node.reserved.networks[0].ip
+    a = Allocation(
+        id=generate_uuid(), node_id=node.id, job_id="net",
+        resources=Resources(cpu=1, memory_mb=1),
+        task_resources={
+            f"t{i}": Resources(cpu=1, memory_mb=1, networks=[
+                NetworkResource(device=dev, ip=ip or own_ip, mbits=mb,
+                                reserved_ports=list(ports))])
+            for i, (ip, dev, ports, mb) in enumerate(offers)})
+    if terminal:
+        a.client_status = ALLOC_CLIENT_STATUS_FAILED
+    return a
+
+
+def _walked(store, node) -> tuple:
+    """What the finish's walk of the node's live allocs collects."""
+    ports: set = set()
+    mbits = 0
+    for a in store.allocs_by_node(node.id):
+        if a.terminal_status():
+            continue
+        for tr in a.task_resources.values():
+            for offer in tr.networks:
+                ports.update(offer.reserved_ports)
+                mbits += offer.mbits
+    return ports, mbits
+
+
+def test_net_occupancy_equals_the_walk_on_plain_nodes():
+    store, nodes = _mk_store(4)
+    statics = build_fleet(list(store.nodes()))
+    mirror = UsageMirror(statics)
+    store.upsert_allocs(20, [
+        _net_alloc(nodes[0], [(None, "eth0", [21000, 21001], 5)]),
+        _net_alloc(nodes[0], [(None, "eth0", [21002], 7),
+                              (None, "eth0", [], 3)]),
+        _net_alloc(nodes[1], [(None, "eth0", [30000], 1)]),
+        _net_alloc(nodes[1], [(None, "eth0", [30001], 9)], terminal=True),
+        _alloc(nodes[2].id),  # no network at all
+    ])
+    # First call switches net tracking on (one rebuild of the net
+    # dicts), brings the mirror to the state, and serves every node
+    # asked for — the empty ones with an empty occupancy.
+    occ = mirror.net_occupancy(store, [0, 1, 2, 3, 3, -1])
+    assert set(occ) == {0, 1, 2, 3}
+    for ni in range(4):
+        ports, mbits = _walked(store, nodes[ni])
+        assert (set(occ[ni][0]), occ[ni][1]) == (ports, mbits)
+        assert len(occ[ni][0]) == len(ports)
+    assert occ[2] == occ[3] == (frozenset(), 0)
+    # Deltas keep it exact: one alloc goes terminal, one arrives.
+    gone = store.allocs_by_node(nodes[0].id)[0].copy()
+    gone.client_status = ALLOC_CLIENT_STATUS_FAILED
+    store.update_alloc_from_client(21, gone)
+    store.upsert_allocs(22, [_net_alloc(nodes[3],
+                                        [(None, "eth0", [40000], 2)])])
+    occ = mirror.net_occupancy(store, range(4))
+    for ni in range(4):
+        ports, mbits = _walked(store, nodes[ni])
+        assert (set(occ[ni][0]), occ[ni][1]) == (ports, mbits)
+    assert mirror.rebuilds == 1
+    # A copy, not a view: later syncs do not reach into it.
+    store.upsert_allocs(23, [_net_alloc(nodes[3],
+                                        [(None, "eth0", [40001], 2)])])
+    mirror.sync(store)
+    assert occ[3][0] == {40000}
+
+
+def test_net_occupancy_leaves_out_what_needs_the_walk():
+    store, nodes = _mk_store(6)
+    multi = nodes[4]
+    multi.resources.networks.append(NetworkResource(
+        device="eth1", cidr="10.0.0.1/32", mbits=1000))
+    store.upsert_node(50, multi)
+    statics = build_fleet(list(store.nodes()))
+    mirror = UsageMirror(statics)
+    index_of = statics.index_of
+    store.upsert_allocs(60, [
+        # offers spanning two devices: NET_KEY_ODD
+        _net_alloc(nodes[0], [(None, "eth0", [21000], 1),
+                              (None, "eth1", [21001], 1)]),
+        # an offer off the node's own network
+        _net_alloc(nodes[1], [("10.9.9.9", "eth0", [21000], 1)]),
+        # one port held twice
+        _net_alloc(nodes[2], [(None, "eth0", [21000], 1)]),
+        _net_alloc(nodes[2], [(None, "eth0", [21000], 1)]),
+        # a task with two networks: the walk counts both, the mirror one
+        Allocation(
+            id=generate_uuid(), node_id=nodes[3].id, job_id="net",
+            resources=Resources(cpu=1, memory_mb=1),
+            task_resources={"t": Resources(cpu=1, memory_mb=1, networks=[
+                NetworkResource(device="eth0", mbits=1,
+                                ip=nodes[3].reserved.networks[0].ip,
+                                reserved_ports=[21000]),
+                NetworkResource(device="eth0", mbits=1,
+                                ip=nodes[3].reserved.networks[0].ip,
+                                reserved_ports=[21001])])}),
+        # a multi-network node (net_base_for -> None)
+        _net_alloc(multi, [(None, "eth0", [21000], 1)]),
+        # and a plain one
+        _net_alloc(nodes[5], [(None, "eth0", [21000], 1)]),
+    ])
+    occ = mirror.net_occupancy(store, range(6))
+    assert set(occ) == {index_of[nodes[5].id]}
+    assert occ[index_of[nodes[5].id]] == ({21000}, 1)
+
+
+def test_net_occupancy_serves_only_the_exact_snapshot():
+    store, nodes = _mk_store(2)
+    statics = build_fleet(list(store.nodes()))
+    mirror = UsageMirror(statics)
+    store.upsert_allocs(20, [_net_alloc(nodes[0],
+                                        [(None, "eth0", [21000], 1)])])
+    old = store.snapshot()
+    store.upsert_allocs(21, [_net_alloc(nodes[0],
+                                        [(None, "eth0", [21001], 1)])])
+    # A lagging mirror is brought up to the state asked for ...
+    assert mirror.net_occupancy(old, [0]) == {0: ({21000}, 1)}
+    assert mirror.index == 20
+    new = mirror.net_occupancy(store, [0])
+    assert set(new[0][0]) == {21000, 21001} and new[0][1] == 2
+    # ... and one that has moved past it serves nothing: the walk does.
+    assert mirror.net_occupancy(old, [0]) == {}
+    assert mirror.index == 21
