@@ -5,6 +5,8 @@ One process, one command, no arguments needed:
 
     python chip_smoke.py            # on a machine with a TPU
     python chip_smoke.py --rehearse # tiny size on the CPU, never a pass
+    python chip_smoke.py --config benchmarks/configs/<c>.json \
+        --traffic benchmarks/traffic/<t>.json   # a deployment's fleet and jobs
 
 In order: builds the native finish extension from source (a child
 process that never touches JAX) and places the compile cache; fails
@@ -20,6 +22,13 @@ ASSERTED to have run on the chip); compiles and runs every jitted
 kernel once at the smoke's shapes and compares it with its numpy twin;
 and, when it sees more than one chip, checks the sharded family, the
 mesh-resident twins and the device window verify.
+
+With ``--config`` and ``--traffic`` (a benchmark configuration file
+and an ``even_rate`` traffic file, read as data) the fleet is that
+deployment's machines and the jobs are its job shapes, and the run is
+the sequential reference and the ``executor = "device"`` phase alone:
+the cost model sends such jobs to the numpy twin, so no benchmark run
+shows them on the chip; this does.
 
 Any failed check raises; nothing records an error and carries on.
 Stdout is two lines, printed only when every check passed (progress
@@ -129,31 +138,33 @@ def make_fleet(seed: int, n: int) -> list:
     return fleet
 
 
+def mock_job(rng: random.Random, shape: str, k: int):
+    import nomad_tpu.mock as mock
+
+    job = mock.job()
+    job.id = seeded_uuid(rng)
+    job.name = f"smoke-{shape}-{k}"
+    return job
+
+
 def make_jobs(seed: int, size: dict) -> list:
     """[(shape, Job)]: config-4 shape (identical groups: cpu 100 / 64 MB
     / 5 Mbit / one dynamic port — they dedupe to ONE kernel slot),
     distinct asks (a prime-strided cpu/mem lattice from a seeded offset:
     every group keeps its own slot, so slot_step really runs once per
     group), C1M shape (one group, count = placements)."""
-    import nomad_tpu.mock as mock
     from nomad_tpu.structs import NetworkResource, Resources, Task, TaskGroup
 
     rng = random.Random(f"{seed}:jobs")
     n_place = size["placements"]
     n_c4, n_distinct, n_c1m = size["jobs"]
 
-    def base(shape: str, k: int):
-        job = mock.job()
-        job.id = seeded_uuid(rng)
-        job.name = f"smoke-{shape}-{k}"
-        return job
-
     def web(res: Resources) -> list:
         return [Task(name="web", driver="exec", resources=res)]
 
     jobs = []
     for k in range(n_c4):
-        job = base("config4", k)
+        job = mock_job(rng, "config4", k)
         job.task_groups = [TaskGroup(
             name=f"tg-{g}", count=1,
             tasks=web(Resources(cpu=100, memory_mb=64, networks=[
@@ -161,7 +172,7 @@ def make_jobs(seed: int, size: dict) -> list:
             for g in range(n_place)]
         jobs.append(("config4", job))
     for k in range(n_distinct):
-        job = base("distinct", k)
+        job = mock_job(rng, "distinct", k)
         off = rng.randrange(997 * 499)
         job.task_groups = [TaskGroup(
             name=f"tg-{g}", count=1,
@@ -170,13 +181,45 @@ def make_jobs(seed: int, size: dict) -> list:
             for g in range(n_place)]
         jobs.append(("distinct", job))
     for k in range(n_c1m):
-        job = base("c1m", k)
+        job = mock_job(rng, "c1m", k)
         job.task_groups = [TaskGroup(
             name="web", count=n_place,
             tasks=web(Resources(cpu=250, memory_mb=128, networks=[
                 NetworkResource(mbits=10, dynamic_ports=["http"])])))]
         jobs.append(("c1m", job))
     return jobs
+
+
+def deployment(config: dict, traffic: dict, seed: int, size: dict) -> tuple:
+    """(fleet, jobs) of a benchmark configuration (``nodes``, ``node``:
+    one machine shape) and an ``even_rate`` traffic file (``job``: type,
+    groups_cycle, count, one ask without network), at most
+    ``size["nodes"]`` nodes and ``size["placements"]`` copies a group."""
+    from nomad_tpu.structs import Resources, Task, TaskGroup
+
+    shape, spec = config["node"], traffic["job"]
+    fleet = make_fleet(seed, min(int(config["nodes"]), size["nodes"]))
+    for node in fleet:
+        res = node.resources
+        res.cpu, res.memory_mb = shape["cpu"], shape["memory_mb"]
+        res.disk_mb, res.iops = shape["disk_mb"], shape["iops"]
+        res.networks[0].mbits = shape["mbits"]
+        node.reserved = Resources()
+    rng = random.Random(f"{seed}:jobs")
+    count = min(int(spec["count"]), size["placements"])
+    jobs = []
+    for k in range(sum(size["jobs"])):
+        job = mock_job(rng, "deployment", k)
+        job.type = spec["type"]
+        job.task_groups = [TaskGroup(
+            name=f"t{g:02d}", count=count,
+            tasks=[Task(name="web", driver="exec", resources=Resources(
+                cpu=spec["ask"]["cpu"],
+                memory_mb=spec["ask"]["memory_mb"]))])
+            for g in range(spec["groups_cycle"][
+                k % len(spec["groups_cycle"])])]
+        jobs.append(("deployment", job))
+    return fleet, jobs
 
 
 def register_eval(job):
@@ -197,10 +240,10 @@ def running(allocs: list) -> list:
 # ---------------------------------------------------------------------------
 
 def sequential_reference(fleet: list, jobs: list):
-    """Harness + the sequential "service" scheduler over the same seeded
-    fleet and jobs, one eval at a time.  Returns (harness, placed per
-    job id); the harness — a real store now carrying 16 jobs' usage —
-    also feeds the kernel phase its fleet tensors."""
+    """Harness + the sequential scheduler of each job's type over the
+    same seeded fleet and jobs, one eval at a time.  Returns (harness,
+    placed per job id); the harness — a real store now carrying 16
+    jobs' usage — also feeds the kernel phase its fleet tensors."""
     from nomad_tpu.scheduler import Harness
 
     h = Harness()
@@ -209,7 +252,7 @@ def sequential_reference(fleet: list, jobs: list):
     placed = {}
     for _shape, job in jobs:
         h.state.upsert_job(h.next_index(), job.copy())
-        h.process("service", register_eval(job))
+        h.process(job.type, register_eval(job))
         placed[job.id] = len(running(h.state.allocs_by_job(job.id)))
     return h, placed
 
@@ -341,7 +384,7 @@ def served_phase(name: str, executor: str, fleet: list, jobs: list,
         sampled_shapes = set()
         for shape, job in jobs:
             in_state = {a.id: a for a in state.allocs_by_job(job.id)}
-            if shape == "c1m":
+            if shape in ("c1m", "deployment"):
                 got = running(api.job_allocations(job.id)[0])
                 check(len(got) == asked[job.id],
                       f"{name}: HTTP shows {len(got)} allocs of job "
@@ -972,7 +1015,13 @@ def main() -> int:
                     "command; reports ok=false by construction")
     ap.add_argument("--out", default=os.path.join(ROOT, ".chip_smoke"),
                     help="raft data dirs of the two served phases")
+    ap.add_argument("--config", help="a benchmark configuration file: "
+                    "its fleet, with --traffic")
+    ap.add_argument("--traffic", help="an even_rate traffic file: its "
+                    "job shapes, with --config")
     args = ap.parse_args()
+    check(bool(args.config) == bool(args.traffic),
+          "--config and --traffic go together")
     t_start = time.time()
     wall0 = time.perf_counter()
     if args.rehearse:
@@ -1016,26 +1065,37 @@ def main() -> int:
         "numpy": importlib.metadata.version("numpy")}
     say(f"device: {result['device']} versions: {result['versions']}")
 
-    fleet = make_fleet(args.seed, size["nodes"])
-    jobs = make_jobs(args.seed, size)
+    phases = (("phase_a", ""), ("phase_b", "device"))
+    if args.config:
+        with open(args.config) as fc, open(args.traffic) as ft:
+            fleet, jobs = deployment(json.load(fc), json.load(ft),
+                                     args.seed, size)
+        result["deployment"] = [args.config, args.traffic]
+        phases = phases[1:]
+    else:
+        fleet = make_fleet(args.seed, size["nodes"])
+        jobs = make_jobs(args.seed, size)
     t0 = time.perf_counter()
     harness, reference = sequential_reference(fleet, jobs)
     result["reference"] = {
-        "scheduler": "service (sequential)", "jobs": len(jobs),
+        "scheduler": f"{jobs[0][1].type} (sequential)", "jobs": len(jobs),
         "placements": sum(reference.values()),
         "wall_s": round(time.perf_counter() - t0, 2)}
     say(f"sequential reference: {result['reference']}")
 
-    for name, executor in (("phase_a", ""), ("phase_b", "device")):
+    for name, executor in phases:
         result[name] = served_phase(name, executor, fleet, jobs, reference,
                                     args.seed, args.out, platform)
         say(f"{name}: {result[name]}")
 
-    result["kernel_phase"] = kernel_phase(harness, jobs, size, args.seed,
-                                          platform)
-    result["dispatch_round_trip"] = dispatch_round_trip(size["rtt_samples"])
-    if len(devices) > 1:
-        result["multichip"] = multichip_phase(harness, jobs, fleet, size)
+    if not args.config:
+        result["kernel_phase"] = kernel_phase(harness, jobs, size,
+                                              args.seed, platform)
+        result["dispatch_round_trip"] = dispatch_round_trip(
+            size["rtt_samples"])
+        if len(devices) > 1:
+            result["multichip"] = multichip_phase(harness, jobs, fleet,
+                                                  size)
     result["compile_cache"]["entries_at_end"] = cache_entries(cache_dir)
     result["peak_bytes_in_use"] = {
         str(d): (d.memory_stats() or {}).get("peak_bytes_in_use")

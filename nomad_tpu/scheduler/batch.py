@@ -68,6 +68,22 @@ def _lane_spans(name: str, scheds, t0: float, t1: float,
                           else None, eval_id=ev.id, **tags)
 
 
+def dispatch_tags(rounds_mode: bool, rounds: int, engine: str) -> dict:
+    """What a lane's ``sched.dispatch`` span says of the kernel it rode
+    (nothing while tracing is off).  ``mode`` is the kernel the
+    dispatch ran — ``rounds`` (one scoring pass per slot and top-k
+    round) or ``sequence`` (one pass per placement; ``_fit_rounds``
+    says when) — with ``rounds`` the round count of that dispatch (a
+    fused window runs its widest lane's; 0 on the sequence kernel);
+    ``engine`` is who ran it: ``host`` (the numpy twin), ``device``
+    (the XLA kernel on one chip) or ``sharded`` (over a mesh), as
+    ``scheduler/executor.py`` chose."""
+    if not trace_mod.ENABLED:
+        return {}
+    return {"mode": "rounds" if rounds_mode else "sequence",
+            "rounds": rounds if rounds_mode else 0, "engine": engine}
+
+
 class BatchEvalRunner:
     """Fuses a batch of evaluations into one device dispatch.
 
@@ -346,6 +362,8 @@ class BatchEvalRunner:
         self.device_dispatches += 1
         if mesh is not None:
             self.sharded_dispatches += 1
+        kernel_tags = dispatch_tags(rounds_ok, rounds,
+                                    "device" if mesh is None else "sharded")
         # All fused lanes share the same snapshot base usage (fast-path
         # contract above); use the resident device copies when available
         # (single-device mirror copy, or on a mesh the sharded statics +
@@ -411,7 +429,7 @@ class BatchEvalRunner:
                         k_cap=k_cap, rounds=rounds)
                 chosen_s, score_s = fetch_results(chosen_s, score_s)
             _lane_spans("sched.dispatch", [s for s, _p, _a in pending],
-                        t_disp, _tnow(), fused=B)
+                        t_disp, _tnow(), fused=B, **kernel_tags)
             done = []
             for b, (sched, place, args) in enumerate(pending):
                 chosen, scores = rounds_to_placements(
@@ -441,7 +459,7 @@ class BatchEvalRunner:
                         penalty)
                 chosen, scores = fetch_results(chosen, scores)
             _lane_spans("sched.dispatch", [s for s, _p, _a in pending],
-                        t_disp, _tnow(), fused=B)
+                        t_disp, _tnow(), fused=B, **kernel_tags)
             self._finish_window(
                 [(sched, place, args, chosen[b], scores[b])
                  for b, (sched, place, args) in enumerate(pending)],
@@ -463,6 +481,7 @@ class BatchEvalRunner:
         statics = pending[0][2].statics
         base_usage = pending[0][2].view.usage  # host array
         n_real = statics.n_real
+        kernel_tags = dispatch_tags(rounds_ok, rounds, "host")
         done = []
         for sched, place, args in pending:
             t_disp = _tnow()
@@ -481,7 +500,7 @@ class BatchEvalRunner:
                     args.distinct, args.group_idx, args.valid,
                     float(args.penalty), n_real=n_real)
             _lane_spans("sched.dispatch", [sched], t_disp, _tnow(),
-                        host=True)
+                        host=True, **kernel_tags)
             self.host_dispatches += 1
             done.append((sched, place, args, chosen, scores))
         self._finish_window(done, retries)
@@ -505,7 +524,10 @@ class BatchEvalRunner:
         # gap, not an oversight.
         chosen, scores = sched.collect_device(args, handles)
         t1 = _tnow()
-        _lane_spans("sched.dispatch", [sched], t0, t1)
+        _lane_spans("sched.dispatch", [sched], t0, t1, **dispatch_tags(
+            args.rounds_eligible, args.rounds,
+            "host" if sched.dispatched_host else
+            "sharded" if sched.dispatched_sharded else "device"))
         sched.finish_deferred(place, args, chosen, scores)
         self._note_dispatch(sched)
         _lane_spans("sched.finish", [sched], t1, _tnow(),

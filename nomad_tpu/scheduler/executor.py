@@ -26,6 +26,16 @@ Resolution order (first set wins):
      ``set_executor_policy`` at server boot);
   3. ``auto``.
 
+What a lane took is on its ``sched.dispatch`` span (scheduler/batch.py
+``dispatch_tags``): ``engine`` = ``host`` (the numpy twin: the policy
+said host, or under ``auto`` lanes x steps x nodes stayed within
+``HOST_SINGLE_SHOT_COST``, steps being slots x rounds under top-k
+rounds and placements on the sequence kernel), ``device`` (the XLA
+kernel on one chip) or ``sharded`` (the same over a mesh), beside
+``mode`` and ``rounds``;
+``nomad.batch_runner.{host,device,sharded}_dispatches`` count the same
+choice always.
+
 The override only selects the executor; plan semantics are identical on
 both sides (tests/test_executor_parity.py gates this on every run).
 """

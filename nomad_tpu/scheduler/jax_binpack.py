@@ -213,7 +213,17 @@ def _fit_rounds(statics, view, feasible_h, asks, slot_placements,
     would place.  Still an estimate — nodes filling MID-dispatch can
     strand copies; the finish loop's sequential fallback rescues those
     exactly.  Returns (rounds, rounds_eligible); need > 16 rounds means
-    the eval is scan-shaped and the sequence kernel takes it."""
+    the eval is scan-shaped and the sequence kernel takes it.
+
+    What comes back is what the lane's ``sched.dispatch`` span reports
+    (scheduler/batch.py ``dispatch_tags``): ``mode`` = ``rounds`` with
+    ``rounds`` the count returned here (1 while every slot has at least
+    as many fitting nodes as copies; 2, 4, 8 or 16 once a slot has more
+    copies than ``min(fitting nodes, k_cap)``), or ``mode`` =
+    ``sequence`` when eligibility is lost, here or in the prep's gain
+    bound.  A fused window dispatches all its lanes with the widest
+    lane's ``rounds``, and on the sequence kernel if any lane needs
+    it."""
     n = statics.n_real
     if n == 0 or not slot_placements:
         return rounds, True

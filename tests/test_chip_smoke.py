@@ -37,25 +37,41 @@ def _start(args: list, out_dir, extra_env=None) -> subprocess.Popen:
 
 @pytest.fixture(scope="module")
 def runs(tmp_path_factory):
-    """The three invocations, started together (the rehearsal takes
-    ~13 s, and tier-1 is kill-bound): name -> (returncode, out, err)."""
+    """The invocations: three started together (the rehearsal takes
+    ~13 s, and tier-1 is kill-bound), then the deployment's (5 s; on
+    its own, so that the box is no busier than with three):
+    name -> (returncode, out, err)."""
     out_dir = tmp_path_factory.mktemp("chip_smoke")
-    procs = {
+    files = {"config": {"nodes": 64, "node": {
+                 "cpu": 9600, "memory_mb": 100000, "disk_mb": 102400,
+                 "iops": 150, "mbits": 1000}},
+             "traffic": {"job": {"type": "batch", "groups_cycle": [3, 4],
+                                 "count": 10,
+                                 "ask": {"cpu": 100, "memory_mb": 1041}}}}
+    for name, content in files.items():
+        (out_dir / f"{name}.json").write_text(json.dumps(content))
+    waves = [lambda: {
         "rehearsal": _start(["--rehearse"], out_dir),
         "no_chip": _start([], out_dir),
         "lever_set": _start(["--rehearse"], out_dir,
                             {"NOMAD_TPU_EXECUTOR": "host"}),
-    }
+    }, lambda: {
+        "deployment": _start(
+            ["--rehearse", "--config", str(out_dir / "config.json"),
+             "--traffic", str(out_dir / "traffic.json")], out_dir),
+    }]
     done = {}
-    try:
-        for name, proc in procs.items():
-            out, err = proc.communicate(timeout=600)
-            done[name] = (proc.returncode, out, err)
-    finally:
-        for proc in procs.values():
-            if proc.poll() is None:
-                proc.kill()
-                proc.wait()
+    for wave in waves:
+        procs = wave()
+        try:
+            for name, proc in procs.items():
+                out, err = proc.communicate(timeout=600)
+                done[name] = (proc.returncode, out, err)
+        finally:
+            for proc in procs.values():
+                if proc.poll() is None:
+                    proc.kill()
+                    proc.wait()
     return done
 
 
@@ -91,6 +107,26 @@ def test_rehearsal_runs_every_phase_and_never_reads_as_a_pass(runs):
     assert multi["device_verify"]["verdicts_equal_host_walk"] is True
     assert {k.split("@")[0] for k in multi["sharded_twins"]} == \
         {"capres", "feas", "usage"}
+
+
+def test_a_deployments_fleet_and_jobs_take_the_forced_device_phase(runs):
+    """``--config`` / ``--traffic``: that machine shape and those batch
+    jobs (3, 4, 3, 4, 3 groups x 10 copies), the sequential ``batch``
+    scheduler as the reference, every placement dispatch on the device
+    plane, and no other phase."""
+    rc, stdout, stderr = runs["deployment"]
+    assert rc == 0, (stdout[-2000:], stderr[-4000:])
+    out = json.loads(stdout.strip().splitlines()[0])
+    assert out["ok"] is False and len(out["deployment"]) == 2
+    assert out["reference"] == dict(
+        out["reference"], scheduler="batch (sequential)", jobs=5,
+        placements=170)
+    assert "phase_a" not in out and "kernel_phase" not in out
+    phase = out["phase_b"]
+    assert phase["nodes"] == 64 and phase["placements_committed"] == 170
+    assert phase["read_back_over_http"]["jobs_in_full"] == 5
+    assert phase["dispatch_mix"]["host_dispatches"] == 0
+    assert phase["dispatch_mix"]["device_dispatches"] >= 1
 
 
 def test_without_a_chip_the_command_fails_and_prints_no_result(runs):
