@@ -213,6 +213,9 @@ class FleetStatics:
     # None: the node-static half of the fast network assigner
     # (scheduler/jax_binpack.py _node_net_init).
     net_base: dict = field(default_factory=dict)
+    # node_index -> the plan verifier's node-static network verdict
+    # inputs (server/plan_apply._node_net_static).
+    net_static: dict = field(default_factory=dict)
     # Process-unique generation id: lets per-job prep caches key on the
     # fleet generation WITHOUT holding a strong ref that would pin
     # evicted generations (and their device buffers) alive.

@@ -44,7 +44,8 @@ pipelined runner's cross-thread stage spans all use it.
 Applier span taxonomy (the partitioned window verify, ISSUE 13): each
 member plan's tree carries ``plan.queued`` (enqueue -> window pop),
 then ``applier.window`` (shared t0/dur across the window, tagged
-``window`` size and ``components`` count), and under it one
+``window`` size, ``components`` count, the plan's ``claims`` and how
+many of them the per-claim walk decided, ``walked``), and under it one
 ``applier.verify`` span carrying the timing of the claim-graph
 COMPONENT that plan verified in (tagged ``component`` scheduling
 ordinal, ``size``, ``fallback``) — component walks run concurrently on

@@ -5,53 +5,65 @@ concurrency (server/plan_apply.py): under a contended storm it pays one
 verify + one commit per plan.  ``evaluate_window`` restructures the
 verify side for a whole *window* of pending plans:
 
-  - the per-node resource fit — the numpy-churn hot loop of
-    ``_evaluate_plan_vec`` — is computed for every (plan, node) claim in
-    the window with a handful of dense array ops against the base
-    snapshot's incremental usage mirror (models/fleet.py UsageMirror);
-  - the window is PARTITIONED into connected components of the claim
-    graph (``partition_window``: plans are vertices, joined when they
-    claim a node in common).  Plans in different components touch
-    disjoint node sets and therefore *cannot* conflict — each component
+  - the window's placement claims become a table of columns
+    (``_Claims``): a slab-backed plan (structs/alloc_slab.py) fills its
+    rows from the slab's columns in one gather, any other plan through
+    ``alloc_vec`` / ``_net_row``;
+  - ONE array pass over that table (``_evaluate_window_vec``) computes
+    every (plan, node) claim's verdict under the optimistic assumption
+    that every earlier claim of the window on the same node was
+    accepted: fit and bandwidth as prefix sums per node in eval order
+    over the base snapshot's incremental usage mirror (models/fleet.py
+    UsageMirror), ports against the node's live and reserved ports and
+    the earlier claims'.  A node's verdicts depend only on earlier
+    accepted claims on the same node, so on a node where every claim
+    passes and nothing is out of the ordinary the optimistic verdicts
+    ARE the sequential ones: final, with nothing to fold.  (The pass
+    has a fixed cost: a window of fewer than ``ARRAY_PASS_MIN_CLAIMS``
+    claims skips it and walks every claim);
+  - every other node — a rejection in its sequence, an eviction or
+    in-place update, an id claimed twice, an in-flight apply's
+    allocation, an odd network — takes the per-claim walk, PARTITIONED
+    into connected components of the claim graph
+    (``partition_window``: plans are vertices, joined when they claim a
+    node in common).  Plans in different components touch disjoint
+    node sets and therefore *cannot* conflict — each component
     verifies independently (concurrently, when the applier passes its
     component executor), while eval order is preserved exactly *within*
     each component;
-  - order sensitivity within a component rides a *component overlay*
-    (``_WindowState``) over a read-only per-window ``_Frame`` copied
-    from the mirror: each plan's accepted portion is folded into the
-    overlay before the next plan's verdicts — so plan i's claims are
-    checked against committed state plus every earlier claim that could
-    possibly interact with them, exactly the state sequential
-    application would have reached;
+  - order sensitivity within a component's walked nodes rides a
+    *component overlay* (``_WindowState``) over a read-only per-window
+    ``_Frame`` copied from the mirror: each plan's walked accepted
+    portion is folded into the overlay before the next plan's verdicts
+    — so plan i's claims are checked against committed state plus
+    every earlier claim that could possibly interact with them,
+    exactly the state sequential application would have reached;
   - claims the incremental path cannot serve (node not in the fleet,
     odd network topology) punt to the exact scalar walk against a
     component-local OptimisticSnapshot carrying the same folds, exactly
     as the per-plan verifier punts them.
 
-The frame is copied under the mirror lock and the lock is RELEASED
-before any component walks, so concurrent worker-side syncs are never
-blocked behind a window verify (the old code held the mirror for the
-whole pass).
+The mirror is locked for the gathers, the probes of the live port sets
+and the frame copy (the walked nodes only), and RELEASED before any
+component walks, so concurrent worker-side syncs are never blocked
+behind a window verify.
 
 Device-resident verify (``NOMAD_TPU_VERIFY``, ops/verify_policy.py):
 when the policy resolves ``device`` (or ``auto`` with the twins already
-resident), the dense base fit dispatches ONE sharded kernel per window
-against the mesh-resident ShardedResidency twins
-(parallel/mesh.window_verify_sharded) instead of gathering the host
-mirror arrays: under the mirror lock the verify takes a residency
-*lease* (models/fleet.py UsageMirror.window_lease — a reference to the
-immutable resident usage twin, never a copy and never an upload), and
-the claim-scatter + claim-sum/compare plus an optimistic scatter-add
-overlay fold (all earlier window plans' accepted deltas per node) run
-on the device.  Component walks consume the fetched numbers exactly
-where the host lists sat, and take the device fold verdict only when
-the walk can PROVE the optimistic assumption held (no in-flight
-overlay, no rejected earlier plan, no alloc id referenced twice in the
-window) — everything else, including every exact-walk punt
-(out-of-fleet nodes, odd port/topology shapes) and the byte-exact
-within-component ordering guarantee, runs the unchanged host code, so
-verdicts, accepted alloc sets and store fingerprints are byte-identical
-under either policy (tests/test_plan_batch.py host/device rigs).
+resident), the dense base fit and the optimistic fit verdicts come from
+ONE sharded kernel per window against the mesh-resident
+ShardedResidency twins (parallel/mesh.window_verify_sharded) instead of
+the host's gather and prefix sums: under the mirror lock the verify
+takes a residency *lease* (models/fleet.py UsageMirror.window_lease — a
+reference to the immutable resident usage twin, never a copy and never
+an upload), and the claim-scatter + claim-sum/compare plus the
+scatter-add of all earlier window plans' claims per node run on the
+device.  Either engine fills the same slot — optimistic fit verdicts,
+trusted only where the host pass proves them final — and everything
+else (bandwidth, ports, the walked nodes, every exact-walk punt) runs
+the same host code, so verdicts, accepted alloc sets and store
+fingerprints are byte-identical under either policy
+(tests/test_plan_batch.py host/device rigs).
 
 Deadline-aware component scheduling: components are ordered by their
 nearest member deadline (then window position), and the executor starts
@@ -60,26 +72,27 @@ verifies first, which together with the plan queue's deadline-promoted
 drain keeps ``expired_drops`` at 0.
 
 A plan whose claims overlap an earlier plan in the window (the
-order-sensitive prefix conflict) is reported as a ``fallback`` — its
-verdicts rode the component overlay rather than the clean dense pass —
-and counted by the applier's ``conflict_fallbacks`` stat.  Because two
+order-sensitive prefix conflict) is reported as a ``fallback`` and
+counted by the applier's ``conflict_fallbacks`` stat.  Because two
 overlapping plans are by construction in the same component, the flag
 means exactly what it meant when the window was one flat list.
 
 Results are identical to calling ``evaluate_plan`` per plan in eval
 order with the accepted portion of each plan folded into the view before
 the next — the property the group-commit parity rigs
-(tests/test_plan_batch.py) lock down for both the partitioned and the
-``partition=False`` sequential path.
+(tests/test_plan_batch.py) lock down for the array pass, the
+partitioned walk and the ``partition=False`` flat walk.
 """
 from __future__ import annotations
 
+import itertools
+import operator
 import time
 from typing import Optional
 
 import numpy as np
 
-from nomad_tpu.structs import PlanResult
+from nomad_tpu.structs import NODE_STATUS_READY, PlanResult
 
 from nomad_tpu.utils.metrics import metrics
 
@@ -94,14 +107,22 @@ _MISS = object()
 # concurrent verification exists for.
 MIN_CONCURRENT_COMPONENT = 8
 
+# The array pass has a fixed cost (some sixty numpy calls a window, a
+# gather a plan) that a window of few claims does not pay back unless
+# nearly all of them are on nodes it can decide, which it cannot know
+# beforehand: under this many (plan, node) claims a window walks them
+# all (PERF.md section 6, PR 29, has the measurements).
+ARRAY_PASS_MIN_CLAIMS = 512
+
 
 class WindowOutcome:
     """One plan's verdict within a window."""
 
-    __slots__ = ("result", "fallback", "component")
+    __slots__ = ("result", "fallback", "component", "claims", "walked")
 
     def __init__(self, result: PlanResult, fallback: bool,
-                 component: int = 0) -> None:
+                 component: int = 0, claims: int = 0,
+                 walked: int = 0) -> None:
         self.result = result
         # True when this plan's claims overlapped an earlier plan in the
         # window (or an in-flight apply) — the order-sensitive prefix
@@ -111,6 +132,10 @@ class WindowOutcome:
         # Scheduling-order index of the claim-graph component this plan
         # verified in (0 on the unpartitioned paths).
         self.component = component
+        # The plan's (plan, node) claims, and those of them the
+        # per-claim walk decided (the array pass decided the rest).
+        self.claims = claims
+        self.walked = walked
 
 
 class WindowVerdicts(list):
@@ -326,17 +351,14 @@ class _WindowState:
                     pc[p] = pc.get(p, 0) + 1
 
 
+def _ready(node) -> bool:
+    """Can this node take placements at all?"""
+    return node is not None and node.status == NODE_STATUS_READY \
+        and not node.drain
+
+
 def _touched(plan) -> set:
     return set(plan.node_update) | set(plan.node_allocation)
-
-
-def _plan_alloc_ids(plan) -> set:
-    ids = set()
-    for allocs in plan.node_update.values():
-        ids.update(a.id for a in allocs)
-    for allocs in plan.node_allocation.values():
-        ids.update(a.id for a in allocs)
-    return ids
 
 
 def _accepted_allocs(result) -> list:
@@ -349,7 +371,7 @@ def _accepted_allocs(result) -> list:
     return allocs
 
 
-def partition_window(plans: list) -> list:
+def partition_window(plans: list, plan_nodes=None) -> list:
     """Connected components of the window's claim graph: plans are
     vertices, joined when they claim (place on OR evict from) a node in
     common.  Returns a list of components, each an ascending list of
@@ -368,8 +390,10 @@ def partition_window(plans: list) -> list:
         return i
 
     owner: dict = {}
-    for i, plan in enumerate(plans):
-        for nid in _touched(plan):
+    if plan_nodes is None:
+        plan_nodes = [_touched(p) for p in plans]
+    for i, nodes in enumerate(plan_nodes):
+        for nid in nodes:
             j = owner.get(nid)
             if j is None:
                 owner[nid] = i
@@ -398,6 +422,21 @@ def evaluate_window(snap, plans: list, executor=None,
     the caller's overlay ends up exactly as sequential application would
     leave it.
 
+    The window's placement claims are read as columns and ONE array
+    pass decides every claim on a node where it can prove the answer
+    (``_array_pass``): every claim of the window on that node fits
+    under the all-earlier-claims-accepted prefix, and nothing on the
+    node is out of the ordinary.  The claims of every other node — a
+    rejection in the node's sequence, an eviction or in-place update,
+    an id claimed twice, an in-flight apply's allocation, an odd
+    network — take the per-claim walk (``_walk_component``), in eval
+    order within their claim-graph component.  A window of fewer than
+    ``ARRAY_PASS_MIN_CLAIMS`` claims is too small for the pass to pay:
+    all its claims walk, one plan alone through ``evaluate_plan``.
+    Each outcome says how many claims its plan made and how many of
+    them a walk decided; the totals are the counters
+    ``nomad.plan.claims`` / ``nomad.plan.claims_walked``.
+
     ``partition=True`` splits the window into claim-graph components
     (scheduled nearest-deadline-first, concurrently when ``executor``
     is given); ``partition=False`` keeps the flat one-overlay walk —
@@ -411,188 +450,220 @@ def evaluate_window(snap, plans: list, executor=None,
 
     overlay = snap if isinstance(snap, OptimisticSnapshot) \
         else OptimisticSnapshot(snap)
-    if len(plans) == 1:
-        # No cross-plan structure to exploit: the per-plan path already
-        # carries its own vectorized fit (plan_apply._evaluate_plan_vec).
-        # Same fallback definition as the window paths — overlap with
-        # the in-flight apply's overlay counts.
-        fallback = bool(_touched(plans[0])
-                        & {n for n in overlay._by_node if n})
-        result = evaluate_plan(snap, plans[0])
-        if overlay is snap:
-            # Only a caller-owned overlay needs the fold; a throwaway
-            # one built here is dead work.
-            overlay.upsert_allocs(_accepted_allocs(result))
-        return WindowVerdicts([WindowOutcome(result, fallback)])
-
     start = time.perf_counter()
-    outcomes = _evaluate_window_vec(overlay, plans, executor, partition)
+    if len(plans) == 1 and \
+            len(_touched(plans[0])) < ARRAY_PASS_MIN_CLAIMS:
+        # One small plan: no cross-plan structure to exploit and too
+        # few claims for the array pass.  The per-plan path carries its
+        # own vectorized fit (plan_apply._evaluate_plan_vec), and every
+        # claim is its walk's.
+        outcomes = None
+    else:
+        # Only a caller-owned overlay needs the fold; a throwaway one
+        # built here is dead work.
+        outcomes = _evaluate_window_vec(overlay, plans, executor,
+                                        partition, fold=overlay is snap)
     if outcomes is None:
-        # No incremental mirror for this snapshot: per-plan exact path
-        # against the running overlay, still in eval order.
+        # That, or no incremental mirror for this snapshot: per-plan
+        # exact path against the running overlay, still in eval order.
         outcomes = WindowVerdicts([])
         dirty: set = {n for n in overlay._by_node if n}
         for plan in plans:
             nodes = _touched(plan)
             result = evaluate_plan(overlay, plan)
-            outcomes.append(WindowOutcome(result, bool(nodes & dirty)))
-            overlay.upsert_allocs(_accepted_allocs(result))
+            outcomes.append(WindowOutcome(
+                result, bool(nodes & dirty),
+                claims=len(nodes), walked=len(nodes)))
+            if overlay is snap or len(plans) > 1:
+                overlay.upsert_allocs(_accepted_allocs(result))
             # Same fallback definition as the vec path's `claimed`:
             # every node an earlier plan TOUCHED (accepted or not), so
             # the stat means one thing regardless of which path ran.
             dirty |= nodes
     metrics.measure_since("nomad.plan.evaluate_window", start)
+    metrics.incr_counter("nomad.plan.claims",
+                         sum(o.claims for o in outcomes))
+    metrics.incr_counter("nomad.plan.claims_walked",
+                         sum(o.walked for o in outcomes))
     return outcomes
 
 
 class _Prep:
     """Everything the component walks share, frozen by the coordinator
-    before any component starts: the dense base-fit results, the frame,
+    before any component starts: the walked claims' records, the frame,
     and the in-flight overlay's contents.  Read-only once built.
 
-    ``devfit`` is None on the host engine; on a device dispatch it
-    carries the kernel's optimistic fold verdicts (``base_used``/
-    ``caps`` then hold the FETCHED device numbers — byte-identical to
-    the host gather, so the walks don't care which engine filled
-    them)."""
+    ``walk[i]`` maps a node id to the record of plan i's claim there,
+    for the claims the walk decides: True (evicts only: always fits),
+    False (node missing or not ready), None (node not in the fleet: the
+    scalar walk), or ``(ni, node, placements, removed ids, used, caps)``
+    with the dense base fit's numbers — the host gather's or the
+    device dispatch's, byte-identical.  A claim with no record was
+    decided by the array pass: accepted."""
 
-    __slots__ = ("plans", "plan_nodes", "verdicts", "pairs", "pair_of",
-                 "base_used", "caps", "frame", "index_of", "statics",
-                 "base", "refresh_index", "inflight", "inflight_nodes",
-                 "inflight_by_node", "inflight_by_id", "devfit")
-
-
-class _DeviceFit:
-    """Fetched per-pair results of one window_verify_sharded dispatch.
-
-    ``fits_seq[pair]`` is the device's optimistic overlay-fold verdict
-    — base fit plus ALL earlier same-component window plans' deltas
-    under the all-accepted assumption.  ``seq_ok`` is the window-level
-    eligibility: False when any alloc id is referenced by two claims
-    (double-evict / replace-after-place), where the optimistic prefix
-    cannot equal the host fold order.  _walk_component additionally
-    requires its own ``clean`` proof before trusting a verdict."""
-
-    __slots__ = ("fits_seq", "seq_ok")
+    __slots__ = ("plans", "plan_nodes", "walk", "frame", "index_of",
+                 "statics", "base", "refresh_index", "inflight",
+                 "inflight_nodes", "inflight_by_node", "inflight_by_id")
 
 
-def _window_device_args(plans, plan_nodes, verdicts, pairs, mirror,
-                        index_of, frame_ids, plan_comp, alloc_vec):
-    """Per-window fold descriptors for the device kernel, built under
-    the mirror lock (reads ``mirror.alloc_rows`` — the same rows the
-    ``_Frame`` copies).  Simulates ``_WindowState.fold`` for every
-    claim that can still be accepted (pass-1 rejections excluded,
-    ``failed_allocs`` included — the walk folds those even on
-    rejection), tagging each entry with its window plan index and
-    claim-graph component so the kernel's prefix mask reproduces the
-    component-local host fold order exactly."""
-    m_rows = mirror.alloc_rows
-    seq_ni: list = []
-    seq_vec: list = []
-    seq_order: list = []
-    seq_comp: list = []
-    ref_count: dict = {}
+class _Claims:
+    """The window's placement claims as columns, built once a window
+    and before the mirror is locked.  A pair is one (plan, node) claim
+    that places something, in eval order; a row is one placement, rows
+    of a pair adjacent; a port belongs to a row.
 
-    def sim_fold(a, i, ci) -> None:
-        aid = a.id
-        ref_count[aid] = ref_count.get(aid, 0) + 1
-        # Frame-restricted like _WindowState.alloc_row: an id outside
-        # the window's frame reads None on the host walk too.
-        row = m_rows.get(aid) if aid in frame_ids else None
+    A plan whose placements are rows of one ``AllocSlab``, none with a
+    heavy field reassigned, fills its rows from the slab's columns in
+    one gather (``AllocSlab.verify_columns``); any other plan fills them
+    through ``alloc_vec`` / ``_net_row``, one allocation at a time — the
+    same numbers either way."""
+
+    __slots__ = ("pair_plan", "pair_nid", "pair_lists", "pair_row0",
+                 "row_pair", "row_vec", "row_mbits", "row_netted",
+                 "row_ips", "row_devs", "row_ids", "port_row", "ports",
+                 "update_claims", "update_ids", "failed_ids")
+
+    def __init__(self, plans: list) -> None:
+        pair_plan: list = []
+        self.pair_nid = pair_nid = []
+        self.pair_lists = pair_lists = []
+        pair_cnt: list = []
+        chunks: list = []  # a verify_columns tuple a plan
+        self.row_ids = row_ids = []
+        # (plan, node) claims that evict or update something, and the
+        # ids of everything a plan names besides its placements.
+        self.update_claims = update_claims = []
+        self.update_ids = update_ids = []
+        self.failed_ids = failed_ids = []
+        get_id = operator.itemgetter("id")
+        for i, plan in enumerate(plans):
+            for nid, updates in plan.node_update.items():
+                if updates:
+                    update_claims.append((i, nid))
+                    update_ids.extend(a.id for a in updates)
+            failed_ids.extend(a.id for a in plan.failed_allocs)
+            na = plan.node_allocation
+            lists = [pl for pl in na.values() if pl]
+            if not lists:
+                continue
+            flat = list(itertools.chain.from_iterable(lists))
+            dicts = list(map(vars, flat))
+            slab = dicts[0].get("_slab")
+            cols = None
+            if slab is not None:
+                # Two dict probes a row prove it is a row of the plan's
+                # slab with no heavy field reassigned (a field only
+                # materialised reads as the columns do); every number
+                # then comes off columns.
+                rows = [d["_srow"] for d in dicts
+                        if d.get("_slab") is slab and "_hmut" not in d]
+                if len(rows) == len(dicts):
+                    cols = slab.verify_columns(
+                        np.asarray(rows, dtype=np.int64))
+            chunks.append(cols if cols is not None
+                          else _object_columns(flat))
+            row_ids.extend(map(get_id, dicts))
+            pair_plan.extend(itertools.repeat(i, len(lists)))
+            pair_nid.extend(na if len(lists) == len(na) else
+                            (nid for nid, pl in na.items() if pl))
+            pair_lists.extend(lists)
+            pair_cnt.extend(map(len, lists))
+        self.pair_plan = np.asarray(pair_plan, dtype=np.int64)
+        cnt = np.asarray(pair_cnt, dtype=np.int64)
+        self.pair_row0 = np.cumsum(cnt) - cnt
+        self.row_pair = np.repeat(np.arange(len(cnt)), cnt)
+        self.row_vec, self.row_mbits, self.row_netted, pcnt, \
+            self.ports = (np.concatenate([c[k] for c in chunks])
+                          for k in range(5))
+        self.row_ips = [ip for c in chunks for ip in c[5]]
+        self.row_devs = [dev for c in chunks for dev in c[6]]
+        self.port_row = np.repeat(np.arange(len(self.row_pair)), pcnt)
+
+
+def _object_columns(allocs: list) -> tuple:
+    """``AllocSlab.verify_columns`` for allocations read one at a time,
+    through ``alloc_vec`` / ``_net_row`` as the walk reads them."""
+    from nomad_tpu.models.fleet import _net_row, alloc_vec
+
+    k = len(allocs)
+    vec = np.empty((k, 4), dtype=np.float32)
+    mbits = np.zeros(k, dtype=np.int64)
+    netted = np.zeros(k, dtype=bool)
+    cnt = np.zeros(k, dtype=np.int64)
+    ports: list = []
+    ips: list = [None] * k
+    devs: list = [None] * k
+    for r, a in enumerate(allocs):
+        vec[r] = alloc_vec(a)[:4]
+        row = _net_row(a)
         if row is not None:
-            v = row[1]
-            seq_ni.append(row[0])
-            seq_vec.append([-float(v[0]), -float(v[1]), -float(v[2]),
-                           -float(v[3])])
-            seq_order.append(i)
-            seq_comp.append(ci)
-        if a.terminal_status():
-            return
-        ni = index_of.get(a.node_id, -1)
-        if ni < 0:
-            return
-        v = alloc_vec(a)
-        seq_ni.append(ni)
-        seq_vec.append([float(v[0]), float(v[1]), float(v[2]),
-                        float(v[3])])
-        seq_order.append(i)
-        seq_comp.append(ci)
+            netted[r] = True
+            mbits[r] = row[1]
+            cnt[r] = len(row[0])
+            ports.extend(row[0])
+            ips[r], devs[r] = row[2]
+    return (vec, mbits, netted, cnt, np.asarray(ports, dtype=np.int64),
+            ips, devs)
 
-    for i, plan in enumerate(plans):
-        ci = plan_comp[i]
-        pv = verdicts[i]
-        for nid in plan_nodes[i]:
-            if pv.get(nid, _MISS) is False:
-                continue  # pass-1 rejection: none of its allocs fold
-            for a in plan.node_update.get(nid, ()):
-                sim_fold(a, i, ci)
-            for a in plan.node_allocation.get(nid, ()):
-                sim_fold(a, i, ci)
-        for a in plan.failed_allocs:
-            sim_fold(a, i, ci)
-    seq_ok = all(c == 1 for c in ref_count.values())
 
-    pair_removed: list = []
-    for (_i, _nid, ni, _node, _placements, removed) in pairs:
-        r0 = r1 = r2 = r3 = 0.0
-        for aid in removed:
-            row = m_rows.get(aid)
-            if row is not None and row[0] == ni:
-                v = row[1]
-                r0 += float(v[0])
-                r1 += float(v[1])
-                r2 += float(v[2])
-                r3 += float(v[3])
-        pair_removed.append([r0, r1, r2, r3])
-
+def _window_device_args(tab, pair_ni, pair_valid, plan_comp) -> dict:
+    """Per-window fold descriptors for the device kernel, straight off
+    the claims table: a fold entry per placement of every claim that
+    can be accepted, tagged with its window plan index and claim-graph
+    component so the kernel's prefix mask is the one the host pass
+    applies.  Removals are left out (``pair_removed`` zero): a claim
+    that removes anything is walked, with the host's arithmetic."""
+    rows = pair_valid[tab.row_pair]
+    seq_pair = tab.row_pair[rows]
+    comp = np.asarray(plan_comp, dtype=np.int64)[tab.pair_plan]
     return {
-        "pair_ni": [p[2] for p in pairs],
-        "pair_order": [p[0] for p in pairs],
-        "pair_comp": [plan_comp[p[0]] for p in pairs],
-        "pair_removed": pair_removed,
-        "seq_ni": seq_ni,
-        "seq_vec": seq_vec,
-        "seq_order": seq_order,
-        "seq_comp": seq_comp,
-        "seq_ok": seq_ok,
+        "pair_ni": np.where(pair_valid, pair_ni, 0),
+        "pair_order": tab.pair_plan,
+        "pair_comp": comp,
+        "pair_removed": np.zeros((len(pair_ni), 4), dtype=np.float32),
+        "row_pair": tab.row_pair,
+        "row_vec": tab.row_vec,
+        "seq_ni": pair_ni[seq_pair],
+        "seq_vec": tab.row_vec[rows],
+        "seq_order": tab.pair_plan[seq_pair],
+        "seq_comp": comp[seq_pair],
     }
 
 
-def _dispatch_window_fit(mesh, capres, lease, dargs, vec_pair, vec_rows,
-                         n_pairs):
+def _dispatch_window_fit(mesh, capres, lease, dargs):
     """ONE sharded dispatch for the whole window's base fit + overlay
     fold, against the resident twins (``capres`` from the statics
     residency, ``lease`` from UsageMirror.window_lease).  Runs OUTSIDE
     the mirror lock — the descriptors are tiny host arrays, padded to
     one shared power-of-two bucket so distinct window sizes reuse the
-    trace.  Returns (used_rows, caps_rows, _DeviceFit, devinfo);
-    used/caps come back through devices.fetch_host and drop into
-    ``prep.base_used``/``prep.caps`` exactly where the host gather's
-    ``.tolist()`` sat."""
+    trace.  Returns (used, caps, fits, devinfo): used/caps come back
+    through devices.fetch_host and sit exactly where the host gather's
+    arrays would; ``fits`` is the optimistic all-earlier-accepted fit
+    verdict per claim, which the host pass computes from prefix sums
+    when the host engine runs."""
     from nomad_tpu.models.fleet import _pad_to
     from nomad_tpu.parallel.devices import fetch_host, transfer_counts
     from nomad_tpu.parallel.mesh import window_verify_sharded
 
-    bucket = _pad_to(max(n_pairs, len(vec_rows), len(dargs["seq_ni"])))
+    n_pairs = len(dargs["pair_ni"])
+    bucket = _pad_to(max(n_pairs, len(dargs["row_pair"])))
 
     def pad_i(vals, fill):
         arr = np.full(bucket, fill, dtype=np.int32)
-        if vals:
-            arr[:len(vals)] = vals
+        arr[:len(vals)] = vals
         return arr
 
     def pad_v(vals):
         arr = np.zeros((bucket, 4), dtype=np.float32)
-        if len(vals):
-            arr[:len(vals)] = np.asarray(vals, dtype=np.float32)[:, :4]
+        arr[:len(vals)] = vals
         return arr
 
     t0 = time.perf_counter()
     before = transfer_counts()
     used, caps, fits = window_verify_sharded(
         mesh, capres[0], capres[1], lease,
-        pad_i(dargs["pair_ni"], 0), pad_i(vec_pair, 0),
-        pad_v(vec_rows), pad_i(dargs["seq_ni"], -1),
+        pad_i(dargs["pair_ni"], 0), pad_i(dargs["row_pair"], 0),
+        pad_v(dargs["row_vec"]), pad_i(dargs["seq_ni"], -1),
         pad_v(dargs["seq_vec"]), pad_i(dargs["seq_order"], 0),
         pad_i(dargs["seq_comp"], -1), pad_i(dargs["pair_order"], 0),
         pad_i(dargs["pair_comp"], 0), pad_v(dargs["pair_removed"]))
@@ -600,36 +671,394 @@ def _dispatch_window_fit(mesh, capres, lease, dargs, vec_pair, vec_rows,
     caps = fetch_host(caps)
     fits = fetch_host(fits)
     after = transfer_counts()
-    devfit = _DeviceFit()
-    devfit.fits_seq = fits[:n_pairs]
-    devfit.seq_ok = dargs["seq_ok"]
     devinfo = {
         "dispatched": True,
         "fallback": None,
         "pairs": n_pairs,
         "bucket": int(bucket),
-        "seq_ok": dargs["seq_ok"],
         "h2d": after["h2d"] - before["h2d"],
         "d2h": after["d2h"] - before["d2h"],
         "wall": time.perf_counter() - t0,
     }
-    return (np.asarray(used[:n_pairs], dtype=np.float32).tolist(),
-            np.asarray(caps[:n_pairs], dtype=np.float32).tolist(),
-            devfit, devinfo)
+    return (np.asarray(used[:n_pairs], dtype=np.float32),
+            np.asarray(caps[:n_pairs], dtype=np.float32),
+            np.asarray(fits[:n_pairs], dtype=bool), devinfo)
 
 
-def _evaluate_window_vec(overlay, plans: list, executor,
-                         partition: bool) -> Optional[WindowVerdicts]:
-    """The vectorized window pass: dense base fit for every claim under
-    the mirror lock, then per-component in-order verdict walks against
-    the released frame.  Returns None when the snapshot cannot take the
-    incremental path at all."""
-    from nomad_tpu.models.fleet import alloc_vec, fleet_cache, mirror_for
-    from nomad_tpu.structs import NODE_STATUS_READY
+def _walk_all_records(prep, mirror) -> None:
+    """A record for every claim of the window, so that all of them
+    walk: classify each, one dense base-fit gather (usage + reserved +
+    sum-of-placements: the 4 dims Resources.superset checks, float32
+    like the mirror rows), and the frame over every touched node.
+    Caller holds the mirror lock."""
+    from nomad_tpu.models.fleet import alloc_vec
+
+    base = prep.base
+    statics = prep.statics
+    index_of = prep.index_of
+    pairs: list = []     # (plan_i, nid, ni, node, placements, removed)
+    vec_rows: list = []  # placement resource vectors
+    vec_pair: list = []  # pair index per vec row
+    frame_ids: set = set(prep.inflight_by_id)
+    frame_nis: set = set()
+    for i, plan in enumerate(prep.plans):
+        records = prep.walk[i]
+        for nid in prep.plan_nodes[i]:
+            placements = plan.node_allocation.get(nid)
+            removed = {a.id for a in plan.node_update.get(nid, ())}
+            frame_ids |= removed
+            ni = index_of.get(nid, -1)
+            if ni >= 0:
+                frame_nis.add(ni)
+            if not placements:
+                if removed:
+                    records[nid] = True  # evict-only: always fits
+                continue
+            frame_ids.update(a.id for a in placements)
+            node = base.node_by_id(nid)
+            if not _ready(node):
+                records[nid] = False
+            elif ni < 0:
+                records[nid] = None  # not in fleet: exact walk
+            else:
+                removed.update(a.id for a in placements)  # in-place upd
+                for a in placements:
+                    vec_pair.append(len(pairs))
+                    vec_rows.append(alloc_vec(a))
+                pairs.append((i, nid, ni, node, placements, removed))
+    if pairs:
+        ni_arr = np.fromiter((p[2] for p in pairs), dtype=np.int64,
+                             count=len(pairs))
+        delta = np.zeros((len(pairs), 4), dtype=np.float32)
+        np.add.at(delta, np.asarray(vec_pair, dtype=np.int64),
+                  np.asarray(vec_rows, dtype=np.float32)[:, :4])
+        used = mirror.usage[ni_arr, :4] + statics.reserved[ni_arr, :4] \
+            + delta
+        for pair, used_p, caps_p in zip(
+                pairs, used.tolist(),
+                statics.capacity[ni_arr, :4].tolist()):
+            prep.walk[pair[0]][pair[1]] = pair[2:] + (used_p, caps_p)
+    # The in-flight apply's allocs fold into component overlays, so
+    # their frame rows (and nodes) must ride along too.
+    for nid in prep.inflight_nodes:
+        ni = index_of.get(nid, -1)
+        if ni >= 0:
+            frame_nis.add(ni)
+    prep.frame = _Frame(mirror, frame_ids, frame_nis)
+
+
+def _array_pass(prep, mirror, comps: list, policy: str, dev_mesh,
+                devinfo) -> tuple:
+    """The window's claims as columns and one array pass over them:
+    fills ``prep.walk`` with the records of the claims that must walk
+    and ``prep.frame`` with the mirror state their walk reads; every
+    claim without a record is decided here, accepted.  Returns (False,
+    None) when the snapshot cannot take the incremental path, else
+    (True, the device engine's record).
+
+    The pass computes, for every (plan, node) claim that places
+    something, what the sequential order would see IF every earlier
+    claim of the window on that node had been accepted: the node's
+    usage + reserved + the prefix sum of those claims against its
+    capacity (asks are whole numbers under 2^24, so the float64 sums
+    are exact), reserved + live + prefix bandwidth against the NIC, and
+    each port against the node's live and reserved ports and the ports
+    of the earlier claims.  A node's verdicts depend only on earlier
+    ACCEPTED claims on the same node, so where all of a node's claims
+    pass, the optimistic answers are the exact ones: those claims are
+    accepted, and nothing is folded for them.
+
+    Every other node is walked, all its claims: a node whose sequence
+    holds a rejection; a node a plan evicts from or updates in place (an
+    id that is live in the mirror); an id claimed twice in the window;
+    a node an in-flight apply placed on, or an id it carries; a node
+    that is missing, not ready, out of the fleet, multi-network,
+    reserving off its own network, holding odd or doubled-up offers; an
+    offer off the node's (ip, device); and every node of a component
+    that holds an ``all_at_once`` plan, if any node of that component is
+    walked (a rejection there takes the plan's other claims back).
+
+    The mirror is locked for the sync, the gathers and the probes of
+    the live port sets, and for the frame — which copies the walked
+    nodes only; the table is built before it."""
+    from nomad_tpu.ops.verify_policy import VERIFY_DEVICE
+    from nomad_tpu.server.plan_apply import _node_net_static
+
+    plans = prep.plans
+    plan_nodes = prep.plan_nodes
+    base = prep.base
+    statics = prep.statics
+    index_of = prep.index_of
+    by_id = prep.inflight_by_id
+    plan_comp = [0] * len(plans)
+    for ci, comp in enumerate(comps):
+        for i in comp:
+            plan_comp[i] = ci
+
+    tab = _Claims(plans)
+    n_pairs = len(tab.pair_nid)
+    pair_ni = np.fromiter(
+        map(index_of.get, tab.pair_nid, itertools.repeat(-1)),
+        dtype=np.int64, count=n_pairs)
+    # The window's nodes, each once: ``pair_ord`` is a claim's node as
+    # an ordinal into them.
+    uniq_ni, pair_ord = np.unique(pair_ni, return_inverse=True)
+    uniq_l = uniq_ni.tolist()
+    n_nodes = len(uniq_l)
+    # walk_ord[k]: node k's claims take the per-claim walk.
+    walk_ord = np.zeros(n_nodes, dtype=bool)
+    nodes_u: list = [None] * n_nodes
+    ready_u = np.zeros(n_nodes, dtype=bool)
+    reserved_ports: list = [frozenset()] * n_nodes
+    bw_fixed = np.zeros(n_nodes, dtype=np.int64)  # reserved, then + live
+    bw_avail = np.zeros(n_nodes, dtype=np.int64)
+    ip_u: list = [None] * n_nodes
+    dev_u: list = [None] * n_nodes
+    node_by_id = base.node_by_id
+    node_ids = statics.node_ids
+    for k, ni in enumerate(uniq_l):
+        walk_ord[k] = True
+        if ni < 0:
+            continue  # not in the fleet: each claim finds its own node
+        node = nodes_u[k] = node_by_id(node_ids[ni])
+        if not _ready(node):
+            continue
+        ready_u[k] = True
+        static = _node_net_static(statics, node, ni)
+        if static:
+            reserved_ports[k], bw_fixed[k], bw_avail[k], \
+                (ip_u[k], dev_u[k]) = static
+            walk_ord[k] = False
+    pair_valid = ready_u[pair_ord]
+
+    def walk_nodes(nis) -> None:
+        """Mark the window's nodes among ``nis`` as walked."""
+        nis = np.asarray(nis, dtype=np.int64)
+        pos = np.searchsorted(uniq_ni, nis)
+        pos[pos == n_nodes] = 0
+        walk_ord[pos[uniq_ni[pos] == nis]] = True
+
+    walk_all = False
+    walk_nodes([index_of.get(nid, -1)
+                for nid in itertools.chain(
+                    (nid for _i, nid in tab.update_claims),
+                    prep.inflight_nodes)])
+    # An id named twice in the window is folded twice: its claims walk.
+    all_ids = tab.row_ids + tab.update_ids + tab.failed_ids
+    dup_ids: set = set()
+    if len(set(all_ids)) != len(all_ids):
+        seen: set = set()
+        dup_ids = {aid for aid in all_ids if aid in seen or seen.add(aid)}
+        walk_all = walk_all or not dup_ids.isdisjoint(tab.failed_ids)
+    row_ord = pair_ord[tab.row_pair]
+
+    def walk_rows_of(ids) -> None:
+        """Walk the node of every placement whose id is in ``ids``."""
+        walk_ord[row_ord[[r for r, aid in enumerate(tab.row_ids)
+                          if aid in ids]]] = True
+
+    if dup_ids:
+        walk_rows_of(dup_ids)
+    if by_id and not by_id.keys().isdisjoint(tab.row_ids):
+        walk_rows_of(by_id)
+    walk_all = walk_all or not by_id.keys().isdisjoint(tab.failed_ids)
+    # An offer off its node's (ip, device) — NET_KEY_ODD is one — needs
+    # the scalar walk.
+    row_ord_l = row_ord.tolist()
+    node_ips = list(map(ip_u.__getitem__, row_ord_l))
+    node_devs = list(map(dev_u.__getitem__, row_ord_l))
+    if node_ips != tab.row_ips or node_devs != tab.row_devs:
+        on_net = np.fromiter(map(operator.eq, tab.row_ips, node_ips),
+                             dtype=bool, count=len(row_ord_l)) \
+            & np.fromiter(map(operator.eq, tab.row_devs, node_devs),
+                          dtype=bool, count=len(row_ord_l))
+        walk_ord[row_ord[tab.row_netted & ~on_net]] = True
+    # A port claimed twice on one node inside the window.
+    port_ord = row_ord[tab.port_row]
+    port_keys = np.sort((port_ord << 32) | (tab.ports & 0xFFFFFFFF))
+    walk_ord[port_keys[1:][port_keys[1:] == port_keys[:-1]] >> 32] = True
+    # Own asks a claim, and its place in its node's sequence: claims
+    # sorted by node, eval order kept within a node.
+    delta = np.add.reduceat(tab.row_vec, tab.pair_row0, axis=0)
+    pair_mbits = np.add.reduceat(tab.row_mbits, tab.pair_row0)
+    order = np.argsort(pair_ord, kind="stable")
+    ord_s = pair_ord[order]
+    first = np.flatnonzero(np.r_[True, ord_s[1:] != ord_s[:-1]])
+    seg = np.repeat(np.arange(len(first)),
+                    np.diff(np.r_[first, n_pairs]))
+
+    def prefix(values):
+        """Sum of ``values`` (claims sorted by node) over the claims of
+        the same node up to and including each."""
+        cs = np.cumsum(values, axis=0)
+        return cs - (cs - values)[first][seg]
+
+    records = prep.walk
+    frame_ids: set = set(tab.update_ids)
+    frame_ids.update(by_id)
+
+    def decide(used, caps, fits) -> None:
+        """Close the set of walked nodes over the fit verdicts and the
+        all_at_once rule, and write the walked claims' records."""
+        walk_ord[ord_s[~fits]] = True
+        if walk_all:
+            walk_ord[:] = True
+        pair_walk = walk_ord[pair_ord]
+        if any(p.all_at_once for p in plans):
+            walked = {tab.pair_nid[p]
+                      for p in np.flatnonzero(pair_walk).tolist()}
+            walked.update(nid for _i, nid in tab.update_claims)
+            for comp in comps:
+                if any(plans[i].all_at_once for i in comp) and any(
+                        not plan_nodes[i].isdisjoint(walked)
+                        for i in comp):
+                    for i in comp:
+                        walked |= plan_nodes[i]
+            pair_walk = np.fromiter(map(walked.__contains__, tab.pair_nid),
+                                    dtype=bool, count=n_pairs)
+        walked_pairs = np.flatnonzero(pair_walk)
+        for p, i, k, used_p, caps_p in zip(
+                walked_pairs.tolist(),
+                tab.pair_plan[walked_pairs].tolist(),
+                pair_ord[walked_pairs].tolist(),
+                used[walked_pairs].tolist(), caps[walked_pairs].tolist()):
+            nid = tab.pair_nid[p]
+            ni = uniq_l[k]
+            node = nodes_u[k] if ni >= 0 else node_by_id(nid)
+            if not _ready(node):
+                records[i][nid] = False
+                continue
+            if ni < 0:
+                records[i][nid] = None  # not in fleet: exact walk
+                continue
+            placements = tab.pair_lists[p]
+            removed = {a.id for a in plans[i].node_update.get(nid, ())}
+            removed.update(a.id for a in placements)  # in-place upd
+            frame_ids.update(removed)
+            records[i][nid] = (ni, node, placements, removed,
+                               used_p, caps_p)
+        for i, nid in tab.update_claims:
+            records[i].setdefault(nid, True)  # evict-only: always fits
+
+    # The net dicts are mutated in place by concurrent worker syncs;
+    # hold the mirror for the composite read — but ONLY for the gathers,
+    # the probes and the frame copy: the walks run lock-free against
+    # the frame.
+    dev_args = None
+    dev_capres = None
+    dev_lease = None
+    with mirror.lock:
+        if not mirror.sync_net(base):
+            return False, None  # snapshot older than the mirror
+        # Live occupancy of the window's nodes.
+        keys_of = mirror.node_net_keys
+        dup_of = mirror.node_dup
+        bw_of = mirror.node_bw
+        ports_of = mirror.node_ports
+        live_ports: list = [()] * n_nodes
+        for k, ni in enumerate(uniq_l):
+            if walk_ord[k]:
+                continue
+            keys = keys_of.get(ni)
+            if (keys and (len(keys) > 1
+                          or (ip_u[k], dev_u[k]) not in keys)) \
+                    or dup_of.get(ni):
+                walk_ord[k] = True  # odd or doubled-up live offers
+                continue
+            pc = ports_of.get(ni)
+            if pc:
+                if not pc.keys().isdisjoint(reserved_ports[k]):
+                    walk_ord[k] = True  # live port on a reserved one
+                    continue
+                live_ports[k] = pc
+            bw_fixed[k] += bw_of.get(ni, 0)
+        # A claimed port that is live or reserved on its node.
+        taken = np.fromiter(
+            (p in live_ports[k] or p in reserved_ports[k]
+             for k, p in zip(port_ord.tolist(), tab.ports.tolist())),
+            dtype=bool, count=len(port_ord))
+        walk_ord[port_ord[taken]] = True
+        # An id that is live in the mirror is an in-place update (an
+        # id with a net row has a usage row).
+        live = mirror.alloc_rows.keys()
+        if not live.isdisjoint(tab.row_ids):
+            walk_rows_of(mirror.alloc_rows)
+        walk_all = walk_all or not live.isdisjoint(tab.failed_ids)
+        # Bandwidth: reserved + live + this and the earlier claims.
+        bw = bw_fixed[ord_s] + prefix(pair_mbits[order])
+        walk_ord[ord_s[bw > bw_avail[ord_s]]] = True
+
+        if dev_mesh is not None:
+            # Residency lease: references to the resident twins for
+            # THIS generation, or None — never an upload under the
+            # lock.
+            dev_lease = mirror.window_lease(dev_mesh)
+            dev_capres = statics.sharded.lookup(("capres", dev_mesh))
+        if dev_lease is not None and dev_capres is not None:
+            # Device engine: the dispatch (and every counted transfer)
+            # runs after release, so the walked nodes are not known
+            # yet: the frame copies every node of the window.
+            dev_args = _window_device_args(tab, pair_ni, pair_valid,
+                                           plan_comp)
+            frame_ids.update(tab.row_ids)
+            frame_nis = {ni for ni in uniq_l if ni >= 0}
+        else:
+            if policy == VERIFY_DEVICE:
+                devinfo = {"dispatched": False,
+                           "fallback": "lease-miss"
+                           if dev_lease is None else "capres-miss"}
+            # Host engine — dense fit inputs over every claim at once:
+            # the 4 dims Resources.superset checks, float32 like the
+            # mirror rows (exact for values < 2^24, i.e. any realistic
+            # node).
+            used = mirror.usage[pair_ni, :4] \
+                + statics.reserved[pair_ni, :4] + delta
+            caps = statics.capacity[pair_ni, :4]
+            d64 = delta[order].astype(np.float64)
+            fits = (used[order] + (prefix(d64) - d64)
+                    <= caps[order]).all(axis=1)
+            decide(used, caps, fits)
+            frame_nis = {ni for ni, w in zip(uniq_l, walk_ord.tolist())
+                         if w and ni >= 0}
+        # The in-flight apply's allocs fold into component overlays, so
+        # their frame rows (and nodes) must ride along too.
+        for nid in prep.inflight_nodes:
+            ni = index_of.get(nid, -1)
+            if ni >= 0:
+                frame_nis.add(ni)
+        prep.frame = _Frame(mirror, frame_ids, frame_nis)
+
+    if dev_args is not None:
+        try:
+            used, caps, fits, devinfo = _dispatch_window_fit(
+                dev_mesh, dev_capres, dev_lease, dev_args)
+        except Exception as e:
+            from nomad_tpu.parallel.devices import transient_device_fault
+            if not transient_device_fault(e):
+                raise  # e.g. a kernel the chip's compiler refuses
+            # Rare (runtime teardown, device OOM): the window still
+            # verifies exactly — the caller's per-plan scalar path.
+            return False, None
+        decide(used, caps, fits[order] | ~pair_valid[order])
+    return True, devinfo
+
+
+def _evaluate_window_vec(overlay, plans: list, executor, partition: bool,
+                         fold: bool = True) -> Optional[WindowVerdicts]:
+    """One window through the incremental path: the array pass over
+    the window's claims (``_array_pass``) decides what it can prove,
+    the per-claim walk (``_walk_component``, a claim-graph component at
+    a time, in eval order) decides the rest.  A window of fewer than
+    ``ARRAY_PASS_MIN_CLAIMS`` claims walks them all
+    (``_walk_all_records``): the pass has a fixed cost that so few
+    claims do not pay back.  Returns None when the snapshot cannot take
+    the incremental path at all."""
+    from nomad_tpu.models.fleet import fleet_cache, mirror_for
 
     base = overlay.base
     if getattr(base, "_t", None) is None:
         return None
+    plan_nodes = [_touched(p) for p in plans]
     if not any(any(p.node_allocation.values()) for p in plans):
         # Evict/update-only window: every per-node verdict is True by
         # definition; don't spin up the mirror's net tracking for it.
@@ -638,36 +1067,30 @@ def _evaluate_window_vec(overlay, plans: list, executor,
         # verdicts here are state-independent.
         outcomes = WindowVerdicts([])
         claimed = {n for n in overlay._by_node if n}
-        for plan in plans:
-            nodes = _touched(plan)
+        for plan, nodes in zip(plans, plan_nodes):
             result = PlanResult(
                 node_update={k: v for k, v in plan.node_update.items()
                              if v},
                 node_allocation={k: v for k, v
                                  in plan.node_allocation.items() if v},
                 failed_allocs=list(plan.failed_allocs))
-            outcomes.append(WindowOutcome(result, bool(nodes & claimed)))
-            overlay.upsert_allocs(_accepted_allocs(result))
+            outcomes.append(WindowOutcome(result, bool(nodes & claimed),
+                                          claims=len(nodes)))
+            if fold:
+                overlay.upsert_allocs(_accepted_allocs(result))
             claimed |= nodes
         return outcomes
 
     statics = fleet_cache.statics_for(base)
     mirror = mirror_for(statics)
-    capacity = statics.capacity
     index_of = statics.index_of
 
-    # Pass-2 components are computed up front (pure on the plans): the
-    # device fold descriptors need each plan's component id so the
-    # kernel's prefix mask stays component-local — exactly the overlay
-    # each host walk sees.
+    # Components are computed up front (pure on the plans): the device
+    # fold descriptors and the all_at_once rule both need each plan's.
     if partition:
-        comps = partition_window(plans)
+        comps = partition_window(plans, plan_nodes)
     else:
         comps = [list(range(len(plans)))]
-    plan_comp = [0] * len(plans)
-    for ci, comp in enumerate(comps):
-        for i in comp:
-            plan_comp[i] = ci
 
     # Device-verify policy (ops/verify_policy.py): mesh resolution and
     # any twin warm-up happen OUTSIDE the mirror lock; under the lock
@@ -697,6 +1120,7 @@ def _evaluate_window_vec(overlay, plans: list, executor,
 
     prep = _Prep()
     prep.plans = plans
+    prep.plan_nodes = plan_nodes
     prep.base = base
     prep.statics = statics
     prep.index_of = index_of
@@ -714,130 +1138,22 @@ def _evaluate_window_vec(overlay, plans: list, executor,
     for k, a in enumerate(prep.inflight):
         by_node.setdefault(a.node_id, []).append((k, a))
         by_id[a.id] = (k, a)
-    prep.plan_nodes = [_touched(p) for p in plans]
 
-    # The net dicts are mutated in place by concurrent worker syncs;
-    # hold the mirror for the composite read — but ONLY for the dense
-    # pass and the frame copy: the component walks run lock-free
-    # against the frame.
-    with mirror.lock:
-        if not mirror.sync_net(base):
-            return None  # snapshot older than the mirror: scalar truth
-        usage = mirror.usage
-
-        # Pass 1: classify every (plan, node) claim; gather the
-        # placement-carrying in-fleet ones into flat arrays for ONE
-        # dense base-fit pass (usage + reserved + sum-of-placements).
-        verdicts: list = [dict() for _ in plans]
-        pairs: list = []     # (plan_i, nid, ni, node, placements, removed)
-        vec_rows: list = []  # placement resource vectors
-        vec_pair: list = []  # pair index per vec row
-        frame_ids: set = set()
-        touched_nis: set = set()
-        for i, plan in enumerate(plans):
-            pv = verdicts[i]
-            for nid in prep.plan_nodes[i]:
-                placements = plan.node_allocation.get(nid)
-                removed = {a.id for a in plan.node_update.get(nid, ())}
-                frame_ids |= removed
-                if not placements:
-                    pv[nid] = True  # evict-only claims always fit
-                    ni = index_of.get(nid, -1)
-                    if ni >= 0:
-                        touched_nis.add(ni)
-                    continue
-                frame_ids.update(a.id for a in placements)
-                node = base.node_by_id(nid)
-                if node is None or node.status != NODE_STATUS_READY \
-                        or node.drain:
-                    pv[nid] = False
-                    continue
-                ni = index_of.get(nid, -1)
-                if ni < 0:
-                    pv[nid] = None  # not in fleet: exact walk
-                    continue
-                touched_nis.add(ni)
-                removed.update(a.id for a in placements)  # in-place upd
-                pair = len(pairs)
-                pairs.append((i, nid, ni, node, placements, removed))
-                for a in placements:
-                    vec_pair.append(pair)
-                    vec_rows.append(alloc_vec(a))
-
-        base_used: list = []
-        caps: list = []
-        dev_args = None
-        dev_capres = None
-        dev_lease = None
-        if pairs:
-            if dev_mesh is not None:
-                # Residency lease: references to the resident twins for
-                # THIS generation, or None — never an upload under the
-                # lock.
-                dev_lease = mirror.window_lease(dev_mesh)
-                dev_capres = statics.sharded.lookup(("capres", dev_mesh))
-            if dev_lease is not None and dev_capres is not None:
-                # Device engine: only the tiny fold descriptors are
-                # built under the lock; the dispatch (and every
-                # counted transfer) runs after release.
-                dev_args = _window_device_args(
-                    plans, prep.plan_nodes, verdicts, pairs, mirror,
-                    index_of, frame_ids, plan_comp, alloc_vec)
-            else:
-                if policy == VERIFY_DEVICE:
-                    devinfo = {"dispatched": False,
-                               "fallback": "lease-miss"
-                               if dev_lease is None else "capres-miss"}
-                # Host engine — dense fit inputs over every claim at
-                # once: the 4 dims Resources.superset checks, float32
-                # like the mirror rows (exact for values < 2^24, i.e.
-                # any realistic node).
-                ni_arr = np.fromiter((p[2] for p in pairs),
-                                     dtype=np.int64, count=len(pairs))
-                delta = np.zeros((len(pairs), 4), dtype=np.float32)
-                np.add.at(delta, np.asarray(vec_pair, dtype=np.int64),
-                          np.asarray(vec_rows, dtype=np.float32)[:, :4])
-                used = usage[ni_arr, :4] \
-                    + statics.reserved[ni_arr, :4] + delta
-                base_used = used.tolist()
-                caps = capacity[ni_arr, :4].tolist()
-
-        # The in-flight apply's allocs fold into component overlays, so
-        # their frame rows (and nodes) must ride along too.
-        for a in prep.inflight:
-            frame_ids.add(a.id)
-            ni = index_of.get(a.node_id, -1)
-            if ni >= 0:
-                touched_nis.add(ni)
-        prep.frame = _Frame(mirror, frame_ids, touched_nis)
-
-    prep.devfit = None
-    if dev_args is not None:
-        try:
-            base_used, caps, prep.devfit, devinfo = \
-                _dispatch_window_fit(dev_mesh, dev_capres, dev_lease,
-                                     dev_args, vec_pair, vec_rows,
-                                     len(pairs))
-        except Exception as e:
-            from nomad_tpu.parallel.devices import transient_device_fault
-            if not transient_device_fault(e):
-                raise  # e.g. a kernel the chip's compiler refuses
-            # Rare (runtime teardown, device OOM): the window still
-            # verifies exactly — the caller's per-plan scalar path.
+    prep.walk = [dict() for _ in plans]
+    if policy != VERIFY_DEVICE and \
+            sum(map(len, plan_nodes)) < ARRAY_PASS_MIN_CLAIMS:
+        with mirror.lock:
+            if not mirror.sync_net(base):
+                return None  # snapshot older than the mirror: scalar truth
+            _walk_all_records(prep, mirror)
+    else:
+        ok, devinfo = _array_pass(prep, mirror, comps, policy, dev_mesh,
+                                  devinfo)
+        if not ok:
             return None
 
-    prep.verdicts = verdicts
-    prep.pairs = pairs
-    prep.base_used = base_used
-    prep.caps = caps
-    pair_of: dict = {}
-    for pair, (i, nid, *_rest) in enumerate(pairs):
-        pair_of[(i, nid)] = pair
-    prep.pair_of = pair_of
-
-    # Pass 2: schedule and walk the components computed up front.
-    # Mirror lock released — the walks read only the frame, the base
-    # snapshot, and prep.
+    # Schedule and walk the components.  Mirror lock released — the
+    # walks read only the frame, the base snapshot, and prep.
     if len(comps) > 1:
         # Deadline-aware scheduling: nearest member deadline first
         # (ties by window position), so a near-deadline plan's
@@ -846,20 +1162,21 @@ def _evaluate_window_vec(overlay, plans: list, executor,
             deadline = min((plans[i].deadline for i in comp
                             if plans[i].deadline), default=float("inf"))
             return (deadline, comp[0])
-        order = sorted(range(len(comps)), key=lambda k: comp_key(comps[k]))
+        order_c = sorted(range(len(comps)),
+                         key=lambda k: comp_key(comps[k]))
     else:
-        order = list(range(len(comps)))
+        order_c = list(range(len(comps)))
 
     wall0 = time.perf_counter()
     tasks = [(lambda comp=comps[k]: _walk_component(prep, comp))
-             for k in order]
+             for k in order_c]
     if executor is not None and len(tasks) > 1 and \
             max(len(c) for c in comps) >= MIN_CONCURRENT_COMPONENT:
         results = executor.run_components(
             tasks, descs=[{"component": k, "plans": len(comps[k]),
                            "eval_ids": [plans[i].eval_id
                                         for i in comps[k]]}
-                          for k in order])
+                          for k in order_c])
     else:
         results = [t() for t in tasks]
     wall = time.perf_counter() - wall0
@@ -875,14 +1192,15 @@ def _evaluate_window_vec(overlay, plans: list, executor,
             outcome.component = ordinal
             slots[i] = outcome
             accepted_by_plan[i] = accepted
-    # Fold every accepted portion into the caller's overlay in eval
-    # order — the exact end state sequential application leaves.
-    for i in range(len(plans)):
-        overlay.upsert_allocs(accepted_by_plan[i])
+    if fold:
+        # Fold every accepted portion into the caller's overlay in eval
+        # order — the exact end state sequential application leaves.
+        for i in range(len(plans)):
+            overlay.upsert_allocs(accepted_by_plan[i])
     info = {
         "components": len(comps),
         "sizes": [len(c) for c in comps],
-        "order": order,
+        "order": order_c,
         "comp_walls": comp_walls,
         "comp_t0s": comp_t0s,  # perf_counter epoch (span conversion)
         "wall": wall,
@@ -898,10 +1216,13 @@ def _evaluate_window_vec(overlay, plans: list, executor,
 
 
 def _walk_component(prep, comp: list) -> tuple:
-    """In-order verdict walk of one claim-graph component against its
-    own overlay.  Returns ([(plan_index, WindowOutcome, accepted)],
-    t0_perf_counter, wall_seconds).  Reads only frozen prep state + the
-    base snapshot — safe on an executor thread."""
+    """In-order verdicts of one claim-graph component: the per-claim
+    walk, against the component's own overlay, for the claims that have
+    a record in ``prep.walk``; every other claim was decided by the
+    array pass (accepted) and only joins its plan's result.  Returns
+    ([(plan_index, WindowOutcome, accepted)], t0_perf_counter,
+    wall_seconds).  Reads only frozen prep state + the base snapshot —
+    safe on an executor thread."""
     from nomad_tpu.server.plan_apply import (
         OptimisticSnapshot,
         _evaluate_node_plan,
@@ -915,39 +1236,28 @@ def _walk_component(prep, comp: list) -> tuple:
     wm = _WindowState(prep.frame, prep.index_of)
     comp_view: Optional[OptimisticSnapshot] = None
     accepted_log: list = []
-    # Device fold verdicts apply only while the walk can PROVE the
-    # kernel's optimistic all-accepted prefix held for this component:
-    # window-unique alloc ids (seq_ok), no in-flight overlay folded in,
-    # and every earlier plan of the component fully accepted.  Any
-    # breach downgrades the REST of the component to the host
-    # arithmetic — which reads prep.base_used/prep.caps, numbers that
-    # are byte-identical under either engine.
-    dev = prep.devfit
-    dev_clean = dev is not None and dev.seq_ok
 
-    comp_nodes: set = set()
-    for i in comp:
-        comp_nodes |= prep.plan_nodes[i]
-    if prep.inflight:
-        # Only the in-flight allocs this component can see: anything on
-        # its nodes, or anything its plans replace/evict by id —
-        # gathered via the per-window indexes in O(component), folded
-        # in the overlay's insertion order (the fold order sequential
-        # application used).
+    if prep.inflight and any(prep.walk[i] for i in comp):
+        # Only the in-flight allocs this component's walk can see:
+        # anything on its walked nodes, or anything its walked claims
+        # replace/evict by id — gathered via the per-window indexes in
+        # O(component), folded in the overlay's insertion order (the
+        # fold order sequential application used).
         picked: dict = {}
-        for nid in comp_nodes:
-            for k, a in prep.inflight_by_node.get(nid, ()):
-                picked[k] = a
         by_id = prep.inflight_by_id
         for i in comp:
-            for aid in _plan_alloc_ids(plans[i]):
-                entry = by_id.get(aid)
-                if entry is not None:
-                    picked[entry[0]] = entry[1]
+            for nid, record in prep.walk[i].items():
+                for k, a in prep.inflight_by_node.get(nid, ()):
+                    picked[k] = a
+                ids = [a.id for a in plans[i].node_update.get(nid, ())]
+                if type(record) is tuple:
+                    ids.extend(record[3])
+                for aid in ids:
+                    entry = by_id.get(aid)
+                    if entry is not None:
+                        picked[entry[0]] = entry[1]
         for k in sorted(picked):
             wm.fold(picked[k])  # in-flight apply: committed state
-        if picked:
-            dev_clean = False  # overlay state the kernel never saw
 
     def view() -> OptimisticSnapshot:
         # Exact-walk punts are rare; the component's OptimisticSnapshot
@@ -966,46 +1276,48 @@ def _walk_component(prep, comp: list) -> tuple:
     last = comp[-1]
     for i in comp:
         plan = plans[i]
-        pv = prep.verdicts[i]
+        records = prep.walk[i]
         nodes = prep.plan_nodes[i]
+        updates = plan.node_update
+        placed = plan.node_allocation
         fallback = (not nodes.isdisjoint(claimed)) or \
                    (not nodes.isdisjoint(inflight_nodes))
         result = PlanResult(failed_allocs=list(plan.failed_allocs))
-        plan_ok = True
-        for nid in nodes:
-            ok = pv.get(nid, _MISS)
-            if ok is None:
+        # What the walk accepted: folded for the plans after this one.
+        # (What the array pass accepted is in their prefix already.)
+        fold_updates: list = []
+        fold_placed: list = []
+        if not records:
+            # The array pass decided every claim of this plan.
+            result.node_allocation = {nid: placed[nid] for nid in nodes
+                                      if placed.get(nid)}
+        for nid in (nodes if records else ()):
+            record = records.get(nid, _MISS)
+            if record is _MISS:
+                ok = True  # the array pass decided it
+            elif record is None:
                 # Vector-ineligible claim: exact walk against the
                 # component view (identical to the sequential verdict).
                 ok = _evaluate_node_plan(view(), plan, nid)
-            elif ok is _MISS:
-                pair = prep.pair_of[(i, nid)]
-                _i, _nid, ni, node, placements, removed = \
-                    prep.pairs[pair]
-                if dev_clean:
-                    # The kernel's overlay fold IS this arithmetic
-                    # (proof obligations met): take its verdict, keep
-                    # the exact net checks.
-                    ok = bool(dev.fits_seq[pair])
-                else:
-                    u0, u1, u2, u3 = prep.base_used[pair]
-                    d = wm.usage_delta.get(ni)
-                    if d is not None:
-                        u0 += d[0]
-                        u1 += d[1]
-                        u2 += d[2]
-                        u3 += d[3]
-                    for aid in removed:
-                        row = wm.alloc_row(aid)
-                        if row is not None and row[0] == ni:
-                            vec = row[1]
-                            u0 -= float(vec[0])
-                            u1 -= float(vec[1])
-                            u2 -= float(vec[2])
-                            u3 -= float(vec[3])
-                    c = prep.caps[pair]
-                    ok = (u0 <= c[0] and u1 <= c[1] and u2 <= c[2]
-                          and u3 <= c[3])
+            elif type(record) is tuple:
+                ni, node, placements, removed, used, caps = record
+                u0, u1, u2, u3 = used
+                d = wm.usage_delta.get(ni)
+                if d is not None:
+                    u0 += d[0]
+                    u1 += d[1]
+                    u2 += d[2]
+                    u3 += d[3]
+                for aid in removed:
+                    row = wm.alloc_row(aid)
+                    if row is not None and row[0] == ni:
+                        vec = row[1]
+                        u0 -= float(vec[0])
+                        u1 -= float(vec[1])
+                        u2 -= float(vec[2])
+                        u3 -= float(vec[3])
+                ok = (u0 <= caps[0] and u1 <= caps[1] and u2 <= caps[2]
+                      and u3 <= caps[3])
                 if ok:
                     # Port collisions + bandwidth: exact, against
                     # frame + component overlay (None punts the node
@@ -1014,31 +1326,34 @@ def _walk_component(prep, comp: list) -> tuple:
                                           placements, removed)
                     if ok is None:
                         ok = _evaluate_node_plan(view(), plan, nid)
+            else:
+                ok = record
             if ok:
-                if plan.node_update.get(nid):
-                    result.node_update[nid] = plan.node_update[nid]
-                if plan.node_allocation.get(nid):
-                    result.node_allocation[nid] = \
-                        plan.node_allocation[nid]
+                if updates.get(nid):
+                    result.node_update[nid] = updates[nid]
+                    fold_updates.extend(updates[nid])
+                if placed.get(nid):
+                    result.node_allocation[nid] = placed[nid]
+                    if record is not _MISS:
+                        fold_placed.extend(placed[nid])
                 continue
-            plan_ok = False
             result.refresh_index = prep.refresh_index
             if plan.all_at_once:
                 result.node_update = {}
                 result.node_allocation = {}
+                fold_updates = []
+                fold_placed = []
                 break
-        if not plan_ok:
-            # A rejected claim (or an aborted all_at_once plan) means
-            # later plans in the component see an overlay the kernel's
-            # all-accepted prefix did not model.
-            dev_clean = False
         accepted = _accepted_allocs(result)
         accepted_log.append(accepted)
         if comp_view is not None:
             comp_view.upsert_allocs(accepted)
         if i != last:
-            for alloc in accepted:
+            for alloc in itertools.chain(fold_updates, fold_placed,
+                                         result.failed_allocs):
                 wm.fold(alloc)
         claimed |= nodes
-        entries.append((i, WindowOutcome(result, fallback), accepted))
+        entries.append((i, WindowOutcome(
+            result, fallback, claims=len(nodes), walked=len(records)),
+            accepted))
     return entries, t0, time.perf_counter() - t0

@@ -399,7 +399,7 @@ def _window_verify_jit(capacity, reserved, usage, pair_ni, row_pair,
     # are component-local, and a removal entry can land on a mirror-row
     # node outside the claim graph, so node equality alone is not
     # enough), under the optimistic all-accepted assumption the host
-    # walk validates (plan_conflict._walk_component's ``clean`` guard).
+    # pass proves node by node (plan_conflict._evaluate_window_vec).
     fold = (seq_ni[None, :] == pair_ni[:, None]) \
         & (seq_order[None, :] < pair_order[:, None]) \
         & (seq_comp[None, :] == pair_comp[:, None])

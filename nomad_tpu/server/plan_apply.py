@@ -33,6 +33,8 @@ from nomad_tpu.utils.metrics import metrics
 
 logger = logging.getLogger("nomad_tpu.server.plan_apply")
 
+_MISS = object()
+
 
 class OptimisticSnapshot:
     """Read view = base snapshot + not-yet-committed alloc upserts.
@@ -47,10 +49,13 @@ class OptimisticSnapshot:
         self._by_node: dict = {}        # node id -> [alloc ids]
 
     def upsert_allocs(self, allocs: list) -> None:
+        overlay = self._overlay
+        by_node = self._by_node
         for a in allocs:
-            if a.id not in self._overlay:
-                self._by_node.setdefault(a.node_id, []).append(a.id)
-            self._overlay[a.id] = a
+            aid = a.id
+            if aid not in overlay:
+                by_node.setdefault(a.node_id, []).append(aid)
+            overlay[aid] = a
 
     # -- read API used by plan evaluation ---------------------------------
     def node_by_id(self, node_id: str):
@@ -191,37 +196,60 @@ def _evaluate_plan_vec(snap, plan: Plan, node_ids) -> Optional[dict]:
     return verdicts
 
 
+def _node_net_static(statics, node, ni: int):
+    """The node-static half of the port/bandwidth verdict, cached on
+    the fleet statics: ``(reserved ports, reserved mbits, bandwidth
+    capacity, (ip, device))``.  None when the node needs the scalar
+    NetworkIndex walk; False when nothing can ever fit on it."""
+    cache = statics.net_static
+    static = cache.get(ni, _MISS)
+    if static is not _MISS:
+        return static
+    from nomad_tpu.models.fleet import net_base_for
+
+    base = net_base_for(statics, ni, node)
+    if base is None:
+        static = None  # multi-network node: exact path
+    else:
+        frozen_used, bw_reserved, bw_avail, ip, device = base
+        static = (frozen_used, bw_reserved, bw_avail, (ip, device))
+        # The node's own reserved networks must ride the same (ip,
+        # device) too: the scalar walk accounts reserved ports per-ip
+        # and reserved bandwidth per-device, so an off-network
+        # reservation (or one with no device — whose bandwidth the
+        # scalar path books against a zero-capacity device) needs the
+        # exact walk.
+        if node.reserved is not None and node.reserved.networks:
+            total_reserved_ports = 0
+            for rn in node.reserved.networks:
+                if rn.ip != ip or rn.device != device:
+                    static = None
+                    break
+                total_reserved_ports += len(rn.reserved_ports)
+            else:
+                if total_reserved_ports > len(frozen_used):
+                    static = False  # reserved ports self-collide
+    cache[ni] = static
+    return static
+
+
 def _verify_node_net(mirror, statics, node, ni: int, placements,
                      removed_ids) -> Optional[bool]:
     """Exact port/bandwidth verdict for one node from the mirror's
     incremental per-node state: True fit, False reject, None = topology
     needs the scalar NetworkIndex walk.  Caller holds the mirror lock."""
-    from nomad_tpu.models.fleet import _net_row, net_base_for
+    from nomad_tpu.models.fleet import _net_row
 
-    base = net_base_for(statics, ni, node)
-    if base is None:
-        return None  # multi-network node: exact path
-    frozen_used, bw_reserved, bw_avail, ip, device = base
-    node_key = (ip, device)
+    static = _node_net_static(statics, node, ni)
+    if not static:
+        return static
+    frozen_used, bw_reserved, bw_avail, node_key = static
 
     # Existing offers must all live on the node's (ip, device) for the
     # merged per-node counting to be sound; odd rows force the exact walk.
     keys = mirror.node_net_keys.get(ni)
     if keys and (len(keys) > 1 or next(iter(keys)) != node_key):
         return None
-    # The node's own reserved networks must ride the same (ip, device)
-    # too: the scalar walk accounts reserved ports per-ip and reserved
-    # bandwidth per-device, so an off-network reservation (or one with
-    # no device — whose bandwidth the scalar path books against a
-    # zero-capacity device) needs the exact walk.
-    if node.reserved is not None and node.reserved.networks:
-        total_reserved_ports = 0
-        for rn in node.reserved.networks:
-            if rn.ip != ip or rn.device != device:
-                return None
-            total_reserved_ports += len(rn.reserved_ports)
-        if total_reserved_ports > len(frozen_used):
-            return False  # reserved ports self-collide: never fits
 
     removed_ports: dict = {}
     removed_mbits = 0
@@ -925,11 +953,12 @@ class PlanApplier:
         info = getattr(outcomes, "info", None)
         if tracer is not None:
             # Span taxonomy: one applier.window span per member plan
-            # (shared t0/dur, tagged window size + component count),
-            # and under it one applier.verify span carrying the
-            # member's COMPONENT timing — so a trace shows both the
-            # group-commit amortization (shared window walls) and
-            # which component each eval's verify actually rode.
+            # (shared t0/dur, tagged window size + component count, and
+            # the plan's claims with how many of them the per-claim
+            # walk decided), and under it one applier.verify span
+            # carrying the member's COMPONENT timing — so a trace shows
+            # both the group-commit amortization (shared window walls)
+            # and which component each eval's verify actually rode.
             dur_verify = tracer.now() - t_verify
             # perf_counter epoch -> tracer epoch for component t0s.
             perf_off = time.perf_counter() - tracer.now()
@@ -943,7 +972,8 @@ class PlanApplier:
                     parent_ctx=pending.plan.trace,
                     eval_id=pending.plan.eval_id,
                     window=len(pendings),
-                    components=info["components"] if info else 1)
+                    components=info["components"] if info else 1,
+                    claims=outcome.claims, walked=outcome.walked)
                 if info is not None:
                     k = outcome.component
                     tracer.record(

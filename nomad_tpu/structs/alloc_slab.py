@@ -192,7 +192,7 @@ class AllocSlab:
         "port_off",     # np.int64 [rows+1] prefix offsets into ports
         "n",            # sealed row count
         "_cache",       # row -> canonical SlabAlloc (lazy; see alloc())
-        "_slot_vec", "_slot_mbits", "_slot_has_net",
+        "_slot_vec", "_slot_mbits", "_slot_has_net", "_slot_cols",
         "_owned",       # row columns private to this slab (see patch_row)
     )
 
@@ -228,6 +228,7 @@ class AllocSlab:
         self._cache: "weakref.WeakValueDictionary" = \
             weakref.WeakValueDictionary()
         self._slot_vec: dict = {}
+        self._slot_cols: Optional[tuple] = None  # see verify_columns
         # Pre-derived per-slot network totals when the caller already
         # has them (the scheduler's col_meta cache); lazily derived
         # from ``slots`` otherwise.
@@ -285,6 +286,37 @@ class AllocSlab:
         o1 = int(self.port_off[r + 1])
         return (tuple(self.ports[o0:o1].tolist()), mbits[g],
                 (self.ips[r], self.devs[r]))
+
+    def verify_columns(self, rows: np.ndarray) -> tuple:
+        """What ``vec`` and ``net_row`` say of ``rows``, as columns
+        (the window verify's claims table, ops/plan_conflict.py):
+        ``(vec f32[k, 4], mbits i64[k], netted bool[k], ports per row
+        i64[k], ports i64[sum], ips [k], devs [k])``.  A row that is
+        not ``netted`` has no net row (``net_row`` returns None)."""
+        cols = self._slot_cols
+        if cols is None:
+            mbits, has_net = self._slot_net()
+            vec = np.zeros((len(self.slots), 4), dtype=np.float32)
+            for g, (size, _tasks) in enumerate(self.slots):
+                if size is not None:
+                    vec[g] = size.as_vector()[:4]
+            mb = np.asarray(mbits, dtype=np.int64)
+            cols = self._slot_cols = (
+                vec, mb, np.asarray(has_net, dtype=bool) | (mb != 0))
+        g = np.asarray(self.groups, dtype=np.int64)[rows]
+        netted = cols[2][g]
+        o0 = self.port_off[rows]
+        cnt = np.where(netted, self.port_off[rows + 1] - o0, 0)
+        total = int(cnt.sum())
+        # Row r's ports sit at ports[o0[r]:o0[r] + cnt[r]]: one gather
+        # for all of them.
+        ports = self.ports[
+            np.repeat(o0 - (np.cumsum(cnt) - cnt), cnt)
+            + np.arange(total)].astype(np.int64)
+        rows_l = rows.tolist()
+        return (cols[0][g], cols[1][g], netted, cnt, ports,
+                list(map(self.ips.__getitem__, rows_l)),
+                list(map(self.devs.__getitem__, rows_l)))
 
     # -- lazy materialization ----------------------------------------------
     def size_of(self, r: int):

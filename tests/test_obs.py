@@ -1055,6 +1055,39 @@ class TestWholePathSpans:
         assert {by_id[s["parent_id"]]["name"] for s in blocked} == \
             {"rpc.serve.Eval.GetEval"}
 
+    def test_window_span_counts_its_claims(self):
+        """``applier.window`` says how many claims its plan made and how
+        many of them the per-claim walk decided; ``nomad.plan.claims``
+        and ``.claims_walked`` under /v1/agent/metrics add up to the
+        spans' tags over a traced window."""
+        agent, api = _http_agent()
+
+        def counters() -> tuple:
+            code, body = _http_get(api, "/v1/agent/metrics?filter=plan")
+            assert code == 200
+            got = body["inmem"]["counters"]
+            return (got["nomad.plan.claims"],
+                    got["nomad.plan.claims_walked"])
+
+        try:
+            warm = api.job_register(_job(1))["eval_id"]
+            assert _await_eval(api, warm).status == "complete"
+            with trace.tracing(seed=29) as tracer:
+                before = counters()
+                eval_id = api.job_register(_job(4, count=3))["eval_id"]
+                assert _await_eval(api, eval_id).status == "complete"
+                after = counters()
+                spans = tracer.snapshot()
+        finally:
+            agent.shutdown()
+        windows = [_tags(s) for s in spans
+                   if s["name"] == "applier.window"]
+        assert windows and all(
+            t["eval_id"] == eval_id and t["claims"] >= 1
+            and 0 <= t["walked"] <= t["claims"] for t in windows)
+        assert after[0] - before[0] == sum(t["claims"] for t in windows)
+        assert after[1] - before[1] == sum(t["walked"] for t in windows)
+
     def test_leaf_spans_cover_the_interval(self):
         """The chain is contiguous: at most a tenth of socket-readable
         -> answer-written lies under no leaf span (the benchmark's

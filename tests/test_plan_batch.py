@@ -14,7 +14,7 @@ from __future__ import annotations
 import pytest
 
 import nomad_tpu.mock as mock
-from nomad_tpu.ops.plan_conflict import evaluate_window
+from nomad_tpu.ops.plan_conflict import _accepted_allocs, evaluate_window
 from nomad_tpu.server.eval_broker import EvalBroker
 from nomad_tpu.server.fsm import NomadFSM
 from nomad_tpu.server.plan_apply import (
@@ -1280,3 +1280,287 @@ class TestApplierServiceThreads:
         committer.stop()
         assert not any(t.name == "test-committer" and t.is_alive()
                        for t in threading.enumerate())
+
+
+# ---------------------------------------------------------------------------
+# 8. the window's claims as columns: the array pass against sequential truth
+# ---------------------------------------------------------------------------
+
+def _node_net(node) -> tuple:
+    """(ip, device) of a mock node's one network."""
+    return node.reserved.networks[0].ip, node.resources.networks[0].device
+
+
+def slab_allocs(rows, *, cpu=19, mem=39, mbits=1) -> list:
+    """Slab-backed allocations as the native finish emits them: one
+    AllocSlab of one task group whose task asks ``cpu`` / ``mem`` /
+    ``mbits`` and one dynamic port; ``rows`` is [(node, port)]."""
+    import numpy as np
+
+    import nomad_tpu.scheduler.jax_binpack as jb
+    from nomad_tpu.structs import AllocSlab, Task, TaskGroup
+
+    job = mock.job()
+    tg = TaskGroup(name="web", count=len(rows), tasks=[Task(
+        name="web", driver="exec", resources=Resources(
+            cpu=cpu, memory_mb=mem, networks=[NetworkResource(
+                mbits=mbits, dynamic_ports=["http"])]))])
+    job.task_groups = [tg]
+    n = len(rows)
+    slab = AllocSlab(
+        eval_id=generate_uuid(), job=job,
+        slots=jb.build_slots_c([(Resources(cpu=cpu, memory_mb=mem),
+                                 jb._net_plan_for(tg)[1])]),
+        metric_proto=dict(jb._METRIC_STATIC, nodes_evaluated=n,
+                          allocation_time=0.0),
+        groups=[0] * n, ids=[generate_uuid() for _ in range(n)],
+        names=[f"{job.id}.web[{r}]" for r in range(n)],
+        tgs=["web"] * n, scores=[1.0] * n,
+        port_off=np.arange(n + 1, dtype=np.int64), n_rows=n,
+        ports=np.asarray([port for _node, port in rows], dtype=np.int32))
+    for r, (node, _port) in enumerate(rows):
+        slab.node_ids[r] = node.id
+        slab.ips[r], slab.devs[r] = _node_net(node)
+    slab.seal(n)
+    return [slab.alloc(r) for r in range(n)]
+
+
+def _ordered_key(result: PlanResult) -> tuple:
+    """result_key with the dicts' order in it: the accepted portion
+    reaches the overlay, the log and the store in that order."""
+    return ([(n, [a.id for a in v])
+             for n, v in result.node_update.items()],
+            [(n, [a.id for a in v])
+             for n, v in result.node_allocation.items()],
+            [a.id for a in result.failed_allocs],
+            result.refresh_index > 0)
+
+
+def assert_columnar_parity(store: StateStore, plans: list,
+                           inflight=()) -> list:
+    """The window pass against sequential ``evaluate_plan`` + fold on
+    the same snapshot: the same PlanResults, in the same order, and the
+    same end overlay.  Returns the outcomes."""
+    snap = store.snapshot()
+    seq = OptimisticSnapshot(snap)
+    seq.upsert_allocs(list(inflight))
+    res_seq = []
+    for plan in plans:
+        result = evaluate_plan(seq, plan)
+        res_seq.append(result)
+        seq.upsert_allocs(_accepted_allocs(result))
+    col = OptimisticSnapshot(snap)
+    col.upsert_allocs(list(inflight))
+    outcomes = evaluate_window(col, plans)
+    assert [_ordered_key(o.result) for o in outcomes] == \
+        [_ordered_key(r) for r in res_seq]
+    assert list(col._overlay) == list(seq._overlay)
+    assert all(col._overlay[k] is seq._overlay[k] for k in col._overlay)
+    assert col._by_node == seq._by_node
+    return outcomes
+
+
+def _claims_walked(outcomes) -> list:
+    return [(o.claims, o.walked) for o in outcomes]
+
+
+def _store(nodes) -> StateStore:
+    store = StateStore()
+    for i, n in enumerate(nodes):
+        store.upsert_node(1000 + i, n)
+    return store
+
+
+def _case_bandwidth():
+    """The third claim on a node takes its bandwidth past the NIC: the
+    node's whole sequence walks, its neighbour stays with the pass."""
+    a, b = mock.node(0), mock.node(1)
+    store = _store([a, b])
+    store.upsert_allocs(1500, slab_allocs([(a, 19999)], mbits=300))
+    plans = [place_plan(*slab_allocs([(a, 20000 + k), (b, 20000 + k)],
+                                     mbits=300)) for k in range(3)]
+    return store, plans, (), [(2, 1)] * 3, [True, True, False]
+
+
+def _case_window_port():
+    """The same dynamic port twice on one node inside the window."""
+    a, b = mock.node(0), mock.node(1)
+    plans = [place_plan(*slab_allocs([(a, 20000), (b, 20001)])),
+             place_plan(*slab_allocs([(a, 20000), (b, 20002)]))]
+    return _store([a, b]), plans, (), [(2, 1)] * 2, [True, False]
+
+
+def _case_live_port():
+    """A port a committed allocation already holds on the node."""
+    a, b = mock.node(0), mock.node(1)
+    store = _store([a, b])
+    store.upsert_allocs(1500, slab_allocs([(a, 20000)]))
+    plans = [place_plan(*slab_allocs([(a, 20000), (b, 20000)])),
+             place_plan(*slab_allocs([(a, 20001), (b, 20001)]))]
+    return store, plans, (), [(2, 1)] * 2, [False, True]
+
+
+def _case_reserved_port():
+    """The node's own reserved port (22 on a mock node)."""
+    a, b = mock.node(0), mock.node(1)
+    plans = [place_plan(*slab_allocs([(a, 22), (b, 20000)]))]
+    return _store([a, b]), plans, (), [(2, 1)], [False]
+
+
+def _case_evict_frees():
+    """An eviction frees the capacity a later plan's claim needs."""
+    a, b = mock.node(0), mock.node(1)
+    store = _store([a, b])
+    existing = make_alloc(a, cpu=FREE_CPU)
+    store.upsert_allocs(1500, [existing])
+    evict = Plan(eval_id=generate_uuid())
+    evict.append_update(existing, ALLOC_DESIRED_STATUS_STOP, "gone")
+    plans = [evict,
+             place_plan(*slab_allocs([(a, 20000), (b, 20000)],
+                                     cpu=FREE_CPU))]
+    return store, plans, (), [(1, 1), (2, 1)], [True, True]
+
+
+def _case_all_at_once():
+    """An all_at_once plan with a rejection gives its other claims
+    back: its whole component walks, the other component does not."""
+    a, b, c = mock.node(0), mock.node(1), mock.node(2)
+    store = _store([a, b, c])
+    store.upsert_allocs(1500, [make_alloc(b, cpu=FREE_CPU)])
+    gang = place_plan(*slab_allocs([(a, 20000), (b, 20000)], cpu=2000))
+    gang.all_at_once = True
+    plans = [gang,
+             place_plan(*slab_allocs([(a, 20001)], cpu=2000)),
+             place_plan(*slab_allocs([(c, 20000)]))]
+    return store, plans, (), [(2, 2), (1, 1), (1, 0)], \
+        [False, True, True]
+
+
+def _case_inflight():
+    """An in-flight apply already filled one of the nodes."""
+    a, b = mock.node(0), mock.node(1)
+    plans = [place_plan(*slab_allocs([(a, 20000), (b, 20000)],
+                                     cpu=1000))]
+    return _store([a, b]), plans, [make_alloc(a, cpu=FREE_CPU)], \
+        [(2, 1)], [False]
+
+
+def _case_id_twice():
+    """One allocation id placed by two plans of the window."""
+    a, b = mock.node(0), mock.node(1)
+    twice = slab_allocs([(a, 20000)], cpu=1000)
+    plans = [place_plan(twice[0], *slab_allocs([(b, 20000)])),
+             place_plan(twice[0], *slab_allocs([(b, 20001)]))]
+    return _store([a, b]), plans, (), [(2, 1)] * 2, [True, True]
+
+
+def _case_net_key_odd():
+    """An allocation whose tasks' offers span two devices."""
+    a, b = mock.node(0), mock.node(1)
+    odd = make_alloc(a, cpu=200)
+    ip = _node_net(a)[0]
+    odd.task_resources = {
+        t: Resources(cpu=100, memory_mb=32, networks=[NetworkResource(
+            device=dev, ip=ip, mbits=5, reserved_ports=[port])])
+        for t, dev, port in (("web", "eth0", 9000), ("db", "eth1", 9001))}
+    plans = [place_plan(odd, *slab_allocs([(b, 20000)])),
+             place_plan(*slab_allocs([(a, 20000), (b, 20001)]))]
+    return _store([a, b]), plans, (), [(2, 1)] * 2, None
+
+
+def _case_multi_network():
+    """A node with two network devices keeps the scalar walk."""
+    a, b = mock.node(0), mock.node(1)
+    a.resources.networks.append(NetworkResource(
+        device="eth1", cidr="10.0.0.1/32", mbits=1000))
+    plans = [place_plan(*slab_allocs([(a, 20000), (b, 20000)]))]
+    return _store([a, b]), plans, (), [(2, 1)], None
+
+
+def _case_mixed_backing():
+    """Object-backed and slab-backed allocations, in one window and in
+    one plan: nothing in it needs the walk."""
+    a, b, c = mock.node(0), mock.node(1), mock.node(2)
+    plans = [place_plan(net_alloc(a, ports=[9000]),
+                        *slab_allocs([(b, 20000)])),
+             place_plan(*slab_allocs([(a, 20000), (c, 20000)])),
+             place_plan(net_alloc(b, ports=[9001]), make_alloc(c))]
+    return _store([a, b, c]), plans, (), [(2, 0)] * 3, \
+        [True, True, True]
+
+
+class TestColumnarWindowParity:
+    """The window pass reads the claims as columns and decides what it
+    can prove; the rest walks.  Whatever the split, the PlanResults and
+    the end overlay are sequential application's."""
+
+    @pytest.mark.parametrize("case", [
+        _case_bandwidth, _case_window_port, _case_live_port,
+        _case_reserved_port, _case_evict_frees, _case_all_at_once,
+        _case_inflight, _case_id_twice, _case_net_key_odd,
+        _case_multi_network, _case_mixed_backing,
+    ], ids=lambda f: f.__name__[6:])
+    @pytest.mark.parametrize("min_claims", [0, None],
+                             ids=["pass", "small-window"])
+    def test_case_leaves_the_pass_where_it_must(self, case, min_claims,
+                                                monkeypatch):
+        """Each case through the array pass (the size gate lifted) and,
+        as the small window it is, through the walk of every claim."""
+        import nomad_tpu.ops.plan_conflict as plan_conflict
+
+        store, plans, inflight, counts, full = case()
+        if min_claims is not None:
+            monkeypatch.setattr(plan_conflict, "ARRAY_PASS_MIN_CLAIMS",
+                                min_claims)
+        else:
+            assert sum(c for c, _w in counts) < \
+                plan_conflict.ARRAY_PASS_MIN_CLAIMS
+            counts = [(c, c) for c, _w in counts]
+        outcomes = assert_columnar_parity(store, plans, inflight)
+        assert _claims_walked(outcomes) == counts
+        if full is not None:
+            assert [o.result.full_commit(p)[0]
+                    for o, p in zip(outcomes, plans)] == full
+
+    @pytest.mark.parametrize("seed, n_plans", [(1, 6), (2, 8), (3, 7)])
+    def test_c1m_shaped_window(self, seed, n_plans):
+        """C1M's window: slab-backed plans of 1,000 one-placement claims
+        on the same 1,000 nodes, which hold up to 199 allocations and
+        199 ports each (203 of the ask fit a node), so the last lanes
+        are rejected on the nodes that fill.  Sequential truth here
+        commits each accepted portion to the store, as the applier's
+        raft apply does."""
+        import random
+
+        rng = random.Random(29_000 + seed)
+        nodes = [mock.node(i) for i in range(1000)]
+        store = _store(nodes)
+        held = [rng.choice((150, 180, 197, 198, 199)) for _ in nodes]
+        for lo in range(0, 1000, 100):
+            store.upsert_allocs(1500 + lo, slab_allocs(
+                [(node, 20000 + k)
+                 for node, n in zip(nodes[lo:lo + 100], held[lo:lo + 100])
+                 for k in range(n)]))
+        plans = []
+        for lane in range(n_plans):
+            order = list(range(1000))
+            rng.shuffle(order)
+            plans.append(place_plan(*slab_allocs(
+                [(nodes[k], 30000 + lane) for k in order])))
+
+        col = OptimisticSnapshot(store.snapshot())
+        outcomes = evaluate_window(col, plans)
+        res_seq = sequential_apply(store, plans, 5000)
+        assert [_ordered_key(o.result) for o in outcomes] == \
+            [_ordered_key(r) for r in res_seq]
+        assert list(col._overlay) == \
+            [a.id for r in res_seq for a in _accepted_allocs(r)]
+        # A node walks when its sequence holds a rejection: 203 fit.
+        walked = sum(1 for n in held if n + n_plans > 203)
+        assert walked > 0
+        assert _claims_walked(outcomes) == [(1000, walked)] * n_plans
+        rejected = [1000 - sum(len(v) for v in
+                               o.result.node_allocation.values())
+                    for o in outcomes]
+        assert rejected == [sum(1 for n in held if n + lane >= 203)
+                            for lane in range(n_plans)]
