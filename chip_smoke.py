@@ -20,8 +20,8 @@ default executor policy (the host/device dispatch mix is REPORTED),
 then under ``executor = "device"`` (every placement dispatch is
 ASSERTED to have run on the chip); compiles and runs every jitted
 kernel once at the smoke's shapes and compares it with its numpy twin;
-and, when it sees more than one chip, checks the sharded family, the
-mesh-resident twins and the device window verify.
+and, when it sees more than one chip, checks the sharded family and
+the mesh-resident twins.
 
 With ``--config`` and ``--traffic`` (a benchmark configuration file
 and an ``even_rate`` traffic file, read as data) the fleet is that
@@ -67,8 +67,7 @@ SIZES = {
 
 # Policy levers that would make the two phases something other than
 # "the default" and "the operator's device setting".
-FORBIDDEN_ENV = ("NOMAD_TPU_EXECUTOR", "NOMAD_TPU_MESH", "NOMAD_TPU_VERIFY",
-                 "NOMAD_TPU_FAULTS")
+FORBIDDEN_ENV = ("NOMAD_TPU_EXECUTOR", "NOMAD_TPU_MESH", "NOMAD_TPU_FAULTS")
 
 
 class SmokeFailure(Exception):
@@ -483,8 +482,7 @@ def served_phase(name: str, executor: str, fleet: list, jobs: list,
             "transfers": transfers,
             "breaker": breaker,
             "applier": {k: applier[k] for k in (
-                "commits", "dispatch_failures",
-                "device_verify_dispatches", "device_verify_fallbacks")},
+                "commits", "dispatch_failures")},
             "broker_nacks": m["nomad.broker.nacks"],
             "resident_arrays": sorted({l for l, _a in twins}),
             "sharded_twins": sharded_twins,
@@ -589,51 +587,6 @@ def twin_parity(chosen_d, scores_d, chosen_h, scores_h) -> dict:
                 float(delta.max()) if same.any() else 0.0}
 
 
-def window_case(rng: random.Random, bucket: int):
-    """Descriptors of one synthetic verify window over ``bucket`` claims
-    with asks that are NOT bf16 numbers (odd MHz/MB values above 256),
-    and the plain numpy answer computed from the same definitions as
-    parallel/mesh._window_verify_jit's docstring."""
-    import numpy as np
-
-    npair = bucket
-    # ~8 claims per node, so later claims' folds run past the capacity
-    # and the window holds both verdicts.
-    pair_ni = np.array([rng.randrange(max(8, bucket // 8))
-                        for _ in range(npair)], dtype=np.int32)
-    pair_order = np.arange(npair, dtype=np.int32)
-    pair_comp = (pair_ni % 7).astype(np.int32)
-    row_pair = np.arange(bucket, dtype=np.int32)
-    row_vec = np.array([[257 + 2 * rng.randrange(600),
-                         129 + 2 * rng.randrange(300), 0, 0]
-                        for _ in range(bucket)], dtype=np.float32)
-    # One fold entry per claim: the claim's own placement, visible to
-    # later same-component plans on the same node.
-    seq_ni, seq_vec = pair_ni.copy(), row_vec.copy()
-    seq_order, seq_comp = pair_order.copy(), pair_comp.copy()
-    pair_removed = np.zeros((npair, 4), dtype=np.float32)
-    return (pair_ni, row_pair, row_vec, seq_ni, seq_vec, seq_order,
-            seq_comp, pair_order, pair_comp, pair_removed)
-
-
-def window_reference(capacity, reserved, usage, desc):
-    import numpy as np
-
-    (pair_ni, row_pair, row_vec, seq_ni, seq_vec, seq_order, seq_comp,
-     pair_order, pair_comp, pair_removed) = desc
-    delta = np.zeros((len(pair_ni), 4), dtype=np.float64)
-    np.add.at(delta, row_pair, row_vec.astype(np.float64))
-    used = usage[pair_ni, :4].astype(np.float64) \
-        + reserved[pair_ni, :4] + delta
-    caps = capacity[pair_ni, :4].astype(np.float64)
-    fold = (seq_ni[None, :] == pair_ni[:, None]) \
-        & (seq_order[None, :] < pair_order[:, None]) \
-        & (seq_comp[None, :] == pair_comp[:, None])
-    used_seq = used + fold.astype(np.float64) @ seq_vec.astype(np.float64) \
-        - pair_removed
-    return used, caps, (used_seq <= caps).all(axis=1)
-
-
 def kernel_phase(h, jobs: list, size: dict, seed: int,
                  platform: str) -> dict:
     """Every jitted entry, compiled and run once at the smoke's shapes
@@ -643,7 +596,6 @@ def kernel_phase(h, jobs: list, size: dict, seed: int,
 
     from nomad_tpu.models import fleet as fleet_mod
     from nomad_tpu.ops import binpack, binpack_host
-    from nomad_tpu.parallel import mesh as mesh_mod
     from nomad_tpu.scheduler.pipeline import PROBE_SCORE_ATOL
 
     lanes = size["lanes"]
@@ -798,29 +750,6 @@ def kernel_phase(h, jobs: list, size: dict, seed: int,
     check(np.array_equal(scattered, want), "scatter_rows != numpy")
     kernels["scatter_rows"] = {**times, "equal_numpy": True}
 
-    # The window verify's base fit + overlay fold, with asks no bf16
-    # pass could carry.
-    say("kernel window_verify")
-    bucket = fleet_mod._pad_to(size["placements"])
-    desc = window_case(random.Random(f"{seed}:window"), bucket)
-    desc_d = [put(x) for x in desc]
-    (used_d, caps_d, fits_d), times = timed_kernel(
-        lambda: mesh_mod._window_verify_jit(cap, res, usage, *desc_d),
-        platform)
-    used_h, caps_h, fits_h = window_reference(
-        statics.capacity, statics.reserved, usage_h, desc)
-    check(np.array_equal(used_d.astype(np.float64), used_h)
-          and np.array_equal(caps_d.astype(np.float64), caps_h),
-          "window_verify used/caps != the float64 reference")
-    check(np.array_equal(fits_d, fits_h),
-          f"window_verify: {int((fits_d != fits_h).sum())} verdicts "
-          "differ from the float64 reference")
-    check(0 < int(fits_h.sum()) < len(fits_h),
-          "window case must hold both verdicts")
-    kernels["window_verify"] = {
-        **times, "bucket": bucket, "verdicts_equal": True,
-        "fits": int(fits_h.sum()), "rejects": int((~fits_h).sum())}
-
     out["outputs_on_platform"] = platform
     return out
 
@@ -853,21 +782,13 @@ def dispatch_round_trip(samples: int) -> dict:
 # more than one chip
 # ---------------------------------------------------------------------------
 
-def multichip_phase(h, jobs: list, fleet: list, size: dict) -> dict:
-    """Sharded == unsharded placements at the smoke's shape, and a
-    dispatched device window verify whose verdicts equal the host
-    walk's on asks no bf16 pass could carry."""
+def multichip_phase(h, jobs: list, size: dict) -> dict:
+    """Sharded == unsharded placements at the smoke's shape."""
     import jax
     import numpy as np
 
     from nomad_tpu.ops import binpack
-    from nomad_tpu.ops.plan_conflict import evaluate_window
-    from nomad_tpu.ops.verify_policy import verify_override
     from nomad_tpu.parallel import mesh as mesh_mod
-    from nomad_tpu.state.store import StateStore
-    from nomad_tpu.structs import (ALLOC_CLIENT_STATUS_PENDING,
-                                   ALLOC_DESIRED_STATUS_RUN, Allocation,
-                                   Plan, Resources, generate_uuid)
 
     n_dev = len(jax.devices())
     lanes = size["lanes"]
@@ -949,58 +870,6 @@ def multichip_phase(h, jobs: list, fleet: list, size: dict) -> dict:
                                       stack(a.valid), pen_b),
          tie_permuted=two_d)
     out["storm_mesh"] = {k: int(v) for k, v in mesh2.shape.items()}
-
-    # Device window verify vs the host walk.  Free cpu per node is 3900
-    # MHz.  Node X takes 1301 + 1301 + 1298 = 3900 (fits exactly; a bf16
-    # pass would read 1304 + 1304 and reject the third); node Y takes
-    # 1299 + 1299 + 1303 = 3901 (the third must be rejected; a bf16 pass
-    # would read 1296 + 1296 and accept it).
-    say("device window verify")
-    nodes = [n.copy() for n in fleet[:64]]
-    store = StateStore()
-    for i, node in enumerate(nodes):
-        store.upsert_node(1000 + i, node)
-
-    def plan_for(node, cpu):
-        plan = Plan(eval_id=generate_uuid(), priority=50)
-        plan.append_alloc(Allocation(
-            id=generate_uuid(), node_id=node.id, job_id="smoke-window",
-            task_group="web", resources=Resources(cpu=cpu, memory_mb=517),
-            desired_status=ALLOC_DESIRED_STATUS_RUN,
-            client_status=ALLOC_CLIENT_STATUS_PENDING))
-        return plan
-
-    asks = []
-    for pair in range(0, 32, 2):
-        asks += [(nodes[pair], 1301), (nodes[pair + 1], 1299)]
-    for pair in range(0, 32, 2):
-        asks += [(nodes[pair], 1301), (nodes[pair + 1], 1299)]
-    for pair in range(0, 32, 2):
-        asks += [(nodes[pair], 1298), (nodes[pair + 1], 1303)]
-    plans = [plan_for(node, cpu) for node, cpu in asks]
-
-    def verdicts(outcomes):
-        return [sorted(a.id for allocs in o.result.node_allocation.values()
-                       for a in allocs) for o in outcomes]
-
-    with verify_override("host"):
-        host = verdicts(evaluate_window(store, plans))
-    with verify_override("device"):
-        evaluate_window(store, plans)  # warm the residency lease
-        outcomes = evaluate_window(store, plans)
-    info = outcomes.info["device"] if outcomes.info else None
-    check(info is not None and info["dispatched"],
-          f"device window verify did not dispatch: {info}")
-    check(verdicts(outcomes) == host,
-          "device window verdicts != the host walk's")
-    accepted = [bool(v) for v in host]
-    want = [True] * 64 + [True, False] * 16
-    check(accepted == want,
-          "host walk verdicts are not the arithmetic truth")
-    out["device_verify"] = {
-        "dispatched": True, "pairs": info["pairs"],
-        "bucket": info["bucket"], "verdicts_equal_host_walk": True,
-        "accepted": sum(accepted), "rejected": len(accepted) - sum(accepted)}
     return out
 
 
@@ -1094,8 +963,7 @@ def main() -> int:
         result["dispatch_round_trip"] = dispatch_round_trip(
             size["rtt_samples"])
         if len(devices) > 1:
-            result["multichip"] = multichip_phase(harness, jobs, fleet,
-                                                  size)
+            result["multichip"] = multichip_phase(harness, jobs, size)
     result["compile_cache"]["entries_at_end"] = cache_entries(cache_dir)
     result["peak_bytes_in_use"] = {
         str(d): (d.memory_stats() or {}).get("peak_bytes_in_use")

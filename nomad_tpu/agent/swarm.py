@@ -1,9 +1,9 @@
 """Agent swarm: N simulated agents on K connections and ONE timer wheel.
 
-The client half of the serving-plane story (bench row
-``5d_client_swarm``): ten thousand heartbeating, long-polling agents
-must cost the *client* harness O(connections + one wheel), or the bench
-would measure its own thread army instead of the server.  Three pieces:
+The client half of the serving-plane story: ten thousand heartbeating,
+long-polling agents must cost the *client* harness O(connections + one
+wheel), or a benchmark would measure its own thread army instead of
+the server.  Three pieces:
 
 - **Shared mux sessions** (:class:`_Chan`): all agents multiplex over a
   handful of 0x03 sessions (``MuxConn.call_async`` — callback waiters,

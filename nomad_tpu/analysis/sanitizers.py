@@ -260,7 +260,6 @@ KERNEL_REGISTRY = (
     ("nomad_tpu.ops.binpack", "place_rounds"),
     ("nomad_tpu.ops.binpack", "place_rounds_batch"),
     ("nomad_tpu.ops.binpack", "place_sequence_batch"),
-    ("nomad_tpu.parallel.mesh", "_window_verify_jit"),
 )
 
 # One kernel serves many (fleet size, placement bucket, static-arg)
@@ -299,8 +298,6 @@ TRANSFER_SEAMS = (
     ("nomad_tpu.parallel.mesh", None, "place_rounds_sharded"),
     ("nomad_tpu.parallel.mesh", None, "place_rounds_batch_sharded"),
     ("nomad_tpu.parallel.mesh", None, "place_sequence_batch_sharded"),
-    ("nomad_tpu.parallel.mesh", None, "window_verify_sharded"),
-    ("nomad_tpu.ops.plan_conflict", None, "_dispatch_window_fit"),
 )
 
 

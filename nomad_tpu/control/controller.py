@@ -50,7 +50,7 @@ from nomad_tpu.obs import trace as trace_mod
 logger = logging.getLogger("nomad_tpu.control")
 
 # Bounded per-knob position history (initial -> ... -> current): the
-# bench's convergence rows record it as the knob's trajectory.
+# knob's trajectory.
 TRAJECTORY_MAX = 128
 
 

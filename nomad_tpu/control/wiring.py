@@ -316,10 +316,10 @@ def server_controller(server, interval: Optional[float] = None,
 def applier_controller(applier, plan_queue, broker=None,
                        interval: float = 0.1, seed: int = 0
                        ) -> Controller:
-    """A standalone commit-pipeline controller (bench 5f's convergence
-    rig and applier-only test harnesses): same knobs and drivers as the
-    server wiring, gauges from a private registry over the applier/
-    queue/broker stats providers."""
+    """A standalone commit-pipeline controller (applier-only test
+    harnesses): same knobs and drivers as the server wiring, gauges
+    from a private registry over the applier/queue/broker stats
+    providers."""
     from nomad_tpu.obs import MetricsRegistry
 
     reg = MetricsRegistry()

@@ -50,7 +50,7 @@ def freeze_storage(raft) -> None:
 
 class CrashHarness:
     """Kill/reboot rig for the crash-recovery proofs
-    (tests/test_crash_recovery.py, bench 5e_failover)."""
+    (tests/test_crash_recovery.py)."""
 
     def __init__(self) -> None:
         self.dead: list = []   # abandoned husks awaiting reap()

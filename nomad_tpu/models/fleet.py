@@ -1010,22 +1010,6 @@ class UsageMirror:
                 hit = self._sharded.adopt(key, arrays)
             return hit[0]
 
-    def window_lease(self, mesh):
-        """Residency LEASE for a window verify: the mesh-resident usage
-        twin for the mirror's CURRENT generation, or None when it is not
-        resident.  Must be called under ``self.lock`` — the lease rule
-        is that a verify reads a consistent generation WITHOUT copying
-        under the mirror lock: resident twins are maintained exactly
-        equal to ``self.usage`` by _update_device, device arrays are
-        immutable (a later sync REPLACES the twin, never mutates it),
-        so the returned array stays valid for the whole window after
-        the lock releases.  Never uploads (that would be a fleet-sized
-        transfer under the lock — devlint transfer-under-lock); cold
-        callers warm the twin through device_usage_sharded OUTSIDE the
-        lock and take the lease on a later window."""
-        hit = self._sharded.lookup(("usage", mesh))
-        return hit[0] if hit is not None else None
-
     # -- views -------------------------------------------------------------
     def _view_locked(self, plan, job_id: str) -> FleetView:
         statics = self.statics

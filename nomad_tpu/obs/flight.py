@@ -208,8 +208,7 @@ class StallWatchdog:
         """``extra_fn`` (optional, zero-arg -> dict) is called AT trip
         time on the watchdog thread and merged into the incident's
         extra — the guarded section's own attribution of what it is
-        stuck on (the plan applier passes its component executor's
-        ``active()``, so a wedged window names the slow component)."""
+        stuck on (the plan applier names the window's eval ids)."""
         with self._cond:
             self._seq += 1
             token = f"g{self._seq}"
@@ -337,7 +336,7 @@ def trip(reason: str, extra: Optional[dict] = None) -> Optional[str]:
 def guard(name: str, timeout: float, extra_fn=None):
     """Stall-guard a section: if it overstays ``timeout`` the watchdog
     trips ``stall.<name>``, merging ``extra_fn()`` (the section's own
-    attribution — e.g. which window component is still verifying) into
+    attribution — e.g. which evals the wedged window was verifying) into
     the incident extra.  No-op when no recorder is installed."""
     watchdog = _WATCHDOG
     if watchdog is None:

@@ -41,17 +41,17 @@ that synthesizes a finished span from explicit (t0, dur, ctx) — the
 broker's queue-wait span, the applier's per-plan window spans, and the
 pipelined runner's cross-thread stage spans all use it.
 
-Applier span taxonomy (the partitioned window verify, ISSUE 13): each
-member plan's tree carries ``plan.queued`` (enqueue -> window pop),
-then ``applier.window`` (shared t0/dur across the window, tagged
-``window`` size, ``components`` count, the plan's ``claims`` and how
-many of them the per-claim walk decided, ``walked``), and under it one
+Applier span taxonomy (the window verify): each member plan's tree
+carries ``plan.queued`` (enqueue -> window pop), then
+``applier.window`` (shared t0/dur across the window, tagged ``window``
+size, ``components`` count, the plan's ``claims`` and how many of them
+the per-claim walk decided, ``walked``), and under it one
 ``applier.verify`` span carrying the timing of the claim-graph
 COMPONENT that plan verified in (tagged ``component`` scheduling
-ordinal, ``size``, ``fallback``) — component walks run concurrently on
-the applier's ComponentExecutor, so sibling verify spans under the same
-window overlap in time, which is the concurrency made visible.
-``raft.apply`` follows as before (shared per window, one per member).
+ordinal, ``size``, ``fallback``) — components walk one after another
+on the applier thread, nearest deadline first, so sibling verify spans
+under the same window never overlap.  ``raft.apply`` follows (shared
+per window, one per member).
 
 Control-plane taxonomy (ISSUE 14): the feedback controller records one
 ``control.tick`` span per evaluation (tags ``tick``, ``adjusted``)

@@ -2,25 +2,27 @@
 
 The leader's plan applier is the serialization point of optimistic
 concurrency (server/plan_apply.py): under a contended storm it pays one
-verify + one commit per plan.  ``evaluate_window`` restructures the
-verify side for a whole *window* of pending plans:
+verify + one commit per plan.  ``evaluate_window`` verifies a whole
+*window* of pending plans, and is the one way a window is verified:
 
   - the window's placement claims become a table of columns
     (``_Claims``): a slab-backed plan (structs/alloc_slab.py) fills its
     rows from the slab's columns in one gather, any other plan through
     ``alloc_vec`` / ``_net_row``;
-  - ONE array pass over that table (``_evaluate_window_vec``) computes
-    every (plan, node) claim's verdict under the optimistic assumption
-    that every earlier claim of the window on the same node was
-    accepted: fit and bandwidth as prefix sums per node in eval order
-    over the base snapshot's incremental usage mirror (models/fleet.py
+  - ONE array pass over that table (``_array_pass``) computes every
+    (plan, node) claim's verdict under the optimistic assumption that
+    every earlier claim of the window on the same node was accepted:
+    fit and bandwidth as prefix sums per node in eval order over the
+    base snapshot's incremental usage mirror (models/fleet.py
     UsageMirror), ports against the node's live and reserved ports and
     the earlier claims'.  A node's verdicts depend only on earlier
     accepted claims on the same node, so on a node where every claim
     passes and nothing is out of the ordinary the optimistic verdicts
     ARE the sequential ones: final, with nothing to fold.  (The pass
     has a fixed cost: a window of fewer than ``ARRAY_PASS_MIN_CLAIMS``
-    claims skips it and walks every claim);
+    claims skips it and walks every claim; one small plan alone goes
+    through ``plan_apply.evaluate_plan``, which is also the scalar
+    reference every parity rig replays);
   - every other node — a rejection in its sequence, an eviction or
     in-place update, an id claimed twice, an in-flight apply's
     allocation, an odd network — takes the per-claim walk, PARTITIONED
@@ -28,9 +30,8 @@ verify side for a whole *window* of pending plans:
     (``partition_window``: plans are vertices, joined when they claim a
     node in common).  Plans in different components touch disjoint
     node sets and therefore *cannot* conflict — each component
-    verifies independently (concurrently, when the applier passes its
-    component executor), while eval order is preserved exactly *within*
-    each component;
+    verifies independently, inline on the caller's thread, while eval
+    order is preserved exactly *within* each component;
   - order sensitivity within a component's walked nodes rides a
     *component overlay* (``_WindowState``) over a read-only per-window
     ``_Frame`` copied from the mirror: each plan's walked accepted
@@ -46,42 +47,24 @@ verify side for a whole *window* of pending plans:
 The mirror is locked for the gathers, the probes of the live port sets
 and the frame copy (the walked nodes only), and RELEASED before any
 component walks, so concurrent worker-side syncs are never blocked
-behind a window verify.
+behind a window verify.  Verify is host code: it dispatches nothing to
+a device and moves nothing across the host/device seam.
 
-Device-resident verify (``NOMAD_TPU_VERIFY``, ops/verify_policy.py):
-when the policy resolves ``device`` (or ``auto`` with the twins already
-resident), the dense base fit and the optimistic fit verdicts come from
-ONE sharded kernel per window against the mesh-resident
-ShardedResidency twins (parallel/mesh.window_verify_sharded) instead of
-the host's gather and prefix sums: under the mirror lock the verify
-takes a residency *lease* (models/fleet.py UsageMirror.window_lease — a
-reference to the immutable resident usage twin, never a copy and never
-an upload), and the claim-scatter + claim-sum/compare plus the
-scatter-add of all earlier window plans' claims per node run on the
-device.  Either engine fills the same slot — optimistic fit verdicts,
-trusted only where the host pass proves them final — and everything
-else (bandwidth, ports, the walked nodes, every exact-walk punt) runs
-the same host code, so verdicts, accepted alloc sets and store
-fingerprints are byte-identical under either policy
-(tests/test_plan_batch.py host/device rigs).
-
-Deadline-aware component scheduling: components are ordered by their
-nearest member deadline (then window position), and the executor starts
-them in that order — under saturation a near-deadline plan's component
-verifies first, which together with the plan queue's deadline-promoted
-drain keeps ``expired_drops`` at 0.
+Deadline-aware component scheduling: components walk in the order of
+their nearest member deadline (then window position) — under saturation
+a near-deadline plan's component verifies first, which together with
+the plan queue's deadline-promoted drain keeps ``expired_drops`` at 0.
 
 A plan whose claims overlap an earlier plan in the window (the
 order-sensitive prefix conflict) is reported as a ``fallback`` and
-counted by the applier's ``conflict_fallbacks`` stat.  Because two
-overlapping plans are by construction in the same component, the flag
-means exactly what it meant when the window was one flat list.
+counted by the applier's ``conflict_fallbacks`` stat.  Two overlapping
+plans are by construction in the same component.
 
 Results are identical to calling ``evaluate_plan`` per plan in eval
 order with the accepted portion of each plan folded into the view before
 the next — the property the group-commit parity rigs
-(tests/test_plan_batch.py) lock down for the array pass, the
-partitioned walk and the ``partition=False`` flat walk.
+(tests/test_plan_batch.py) lock down for the array pass and the
+all-walk path.
 """
 from __future__ import annotations
 
@@ -97,15 +80,6 @@ from nomad_tpu.structs import NODE_STATUS_READY, PlanResult
 from nomad_tpu.utils.metrics import metrics
 
 _MISS = object()
-
-# Components below this size verify inline on the applier thread even
-# when an executor is available: a saturated-but-uncontended window is
-# dozens of single-plan components whose walks are a few microseconds
-# of GIL-bound Python — worker handoff costs more than it buys.  A
-# component at or past this size carries a real conflict cluster (an
-# ordered chain of folds and possibly exact-walk punts), which is what
-# concurrent verification exists for.
-MIN_CONCURRENT_COMPONENT = 8
 
 # The array pass has a fixed cost (some sixty numpy calls a window, a
 # gather a plan) that a window of few claims does not pay back unless
@@ -130,7 +104,7 @@ class WindowOutcome:
         # the clean dense pass.
         self.fallback = fallback
         # Scheduling-order index of the claim-graph component this plan
-        # verified in (0 on the unpartitioned paths).
+        # verified in (0 on the per-plan path).
         self.component = component
         # The plan's (plan, node) claims, and those of them the
         # per-claim walk decided (the array pass decided the rest).
@@ -140,7 +114,7 @@ class WindowOutcome:
 
 class WindowVerdicts(list):
     """The outcomes list plus window-level partition/scheduling info
-    (``.info`` — None on the paths that never partitioned)."""
+    (``.info`` — None on the per-plan path)."""
 
     def __init__(self, outcomes, info: Optional[dict] = None) -> None:
         super().__init__(outcomes)
@@ -190,9 +164,8 @@ class _Frame:
     walks consume, restricted to the window's touched nodes and claimed
     alloc ids.  Copied under the mirror lock, read without it — the
     lock is released before any component verifies, so worker-side
-    mirror syncs never queue behind a window, and component walks on
-    executor threads never read mirror state the lock discipline
-    guards."""
+    mirror syncs never queue behind a window, and the component walks
+    never read mirror state the lock discipline guards."""
 
     __slots__ = ("alloc_rows", "net_rows", "node_ports", "node_bw",
                  "node_net_keys", "node_dup")
@@ -411,8 +384,7 @@ def partition_window(plans: list, plan_nodes=None) -> list:
     return [comps[r] for r in sorted(comps)]
 
 
-def evaluate_window(snap, plans: list, executor=None,
-                    partition: bool = True) -> WindowVerdicts:
+def evaluate_window(snap, plans: list) -> WindowVerdicts:
     """Verify a window of plans; returns one WindowOutcome per plan,
     results identical to sequential ``evaluate_plan`` + fold-into-
     overlay per plan in eval order.
@@ -430,18 +402,13 @@ def evaluate_window(snap, plans: list, executor=None,
     rejection in the node's sequence, an eviction or in-place update,
     an id claimed twice, an in-flight apply's allocation, an odd
     network — take the per-claim walk (``_walk_component``), in eval
-    order within their claim-graph component.  A window of fewer than
+    order within their claim-graph component, the component with the
+    nearest member deadline first.  A window of fewer than
     ``ARRAY_PASS_MIN_CLAIMS`` claims is too small for the pass to pay:
     all its claims walk, one plan alone through ``evaluate_plan``.
     Each outcome says how many claims its plan made and how many of
     them a walk decided; the totals are the counters
     ``nomad.plan.claims`` / ``nomad.plan.claims_walked``.
-
-    ``partition=True`` splits the window into claim-graph components
-    (scheduled nearest-deadline-first, concurrently when ``executor``
-    is given); ``partition=False`` keeps the flat one-overlay walk —
-    the pre-partition behavior, kept as the bench's in-run sequential
-    baseline and exercised by the parity rigs.
     """
     from nomad_tpu.server.plan_apply import (
         OptimisticSnapshot,
@@ -461,8 +428,8 @@ def evaluate_window(snap, plans: list, executor=None,
     else:
         # Only a caller-owned overlay needs the fold; a throwaway one
         # built here is dead work.
-        outcomes = _evaluate_window_vec(overlay, plans, executor,
-                                        partition, fold=overlay is snap)
+        outcomes = _evaluate_window_vec(overlay, plans,
+                                        fold=overlay is snap)
     if outcomes is None:
         # That, or no incremental mirror for this snapshot: per-plan
         # exact path against the running overlay, still in eval order.
@@ -497,8 +464,7 @@ class _Prep:
     for the claims the walk decides: True (evicts only: always fits),
     False (node missing or not ready), None (node not in the fleet: the
     scalar walk), or ``(ni, node, placements, removed ids, used, caps)``
-    with the dense base fit's numbers — the host gather's or the
-    device dispatch's, byte-identical.  A claim with no record was
+    with the dense base fit's numbers.  A claim with no record was
     decided by the array pass: accepted."""
 
     __slots__ = ("plans", "plan_nodes", "walk", "frame", "index_of",
@@ -606,85 +572,6 @@ def _object_columns(allocs: list) -> tuple:
             ips, devs)
 
 
-def _window_device_args(tab, pair_ni, pair_valid, plan_comp) -> dict:
-    """Per-window fold descriptors for the device kernel, straight off
-    the claims table: a fold entry per placement of every claim that
-    can be accepted, tagged with its window plan index and claim-graph
-    component so the kernel's prefix mask is the one the host pass
-    applies.  Removals are left out (``pair_removed`` zero): a claim
-    that removes anything is walked, with the host's arithmetic."""
-    rows = pair_valid[tab.row_pair]
-    seq_pair = tab.row_pair[rows]
-    comp = np.asarray(plan_comp, dtype=np.int64)[tab.pair_plan]
-    return {
-        "pair_ni": np.where(pair_valid, pair_ni, 0),
-        "pair_order": tab.pair_plan,
-        "pair_comp": comp,
-        "pair_removed": np.zeros((len(pair_ni), 4), dtype=np.float32),
-        "row_pair": tab.row_pair,
-        "row_vec": tab.row_vec,
-        "seq_ni": pair_ni[seq_pair],
-        "seq_vec": tab.row_vec[rows],
-        "seq_order": tab.pair_plan[seq_pair],
-        "seq_comp": comp[seq_pair],
-    }
-
-
-def _dispatch_window_fit(mesh, capres, lease, dargs):
-    """ONE sharded dispatch for the whole window's base fit + overlay
-    fold, against the resident twins (``capres`` from the statics
-    residency, ``lease`` from UsageMirror.window_lease).  Runs OUTSIDE
-    the mirror lock — the descriptors are tiny host arrays, padded to
-    one shared power-of-two bucket so distinct window sizes reuse the
-    trace.  Returns (used, caps, fits, devinfo): used/caps come back
-    through devices.fetch_host and sit exactly where the host gather's
-    arrays would; ``fits`` is the optimistic all-earlier-accepted fit
-    verdict per claim, which the host pass computes from prefix sums
-    when the host engine runs."""
-    from nomad_tpu.models.fleet import _pad_to
-    from nomad_tpu.parallel.devices import fetch_host, transfer_counts
-    from nomad_tpu.parallel.mesh import window_verify_sharded
-
-    n_pairs = len(dargs["pair_ni"])
-    bucket = _pad_to(max(n_pairs, len(dargs["row_pair"])))
-
-    def pad_i(vals, fill):
-        arr = np.full(bucket, fill, dtype=np.int32)
-        arr[:len(vals)] = vals
-        return arr
-
-    def pad_v(vals):
-        arr = np.zeros((bucket, 4), dtype=np.float32)
-        arr[:len(vals)] = vals
-        return arr
-
-    t0 = time.perf_counter()
-    before = transfer_counts()
-    used, caps, fits = window_verify_sharded(
-        mesh, capres[0], capres[1], lease,
-        pad_i(dargs["pair_ni"], 0), pad_i(dargs["row_pair"], 0),
-        pad_v(dargs["row_vec"]), pad_i(dargs["seq_ni"], -1),
-        pad_v(dargs["seq_vec"]), pad_i(dargs["seq_order"], 0),
-        pad_i(dargs["seq_comp"], -1), pad_i(dargs["pair_order"], 0),
-        pad_i(dargs["pair_comp"], 0), pad_v(dargs["pair_removed"]))
-    used = fetch_host(used)
-    caps = fetch_host(caps)
-    fits = fetch_host(fits)
-    after = transfer_counts()
-    devinfo = {
-        "dispatched": True,
-        "fallback": None,
-        "pairs": n_pairs,
-        "bucket": int(bucket),
-        "h2d": after["h2d"] - before["h2d"],
-        "d2h": after["d2h"] - before["d2h"],
-        "wall": time.perf_counter() - t0,
-    }
-    return (np.asarray(used[:n_pairs], dtype=np.float32),
-            np.asarray(caps[:n_pairs], dtype=np.float32),
-            np.asarray(fits[:n_pairs], dtype=bool), devinfo)
-
-
 def _walk_all_records(prep, mirror) -> None:
     """A record for every claim of the window, so that all of them
     walk: classify each, one dense base-fit gather (usage + reserved +
@@ -747,14 +634,12 @@ def _walk_all_records(prep, mirror) -> None:
     prep.frame = _Frame(mirror, frame_ids, frame_nis)
 
 
-def _array_pass(prep, mirror, comps: list, policy: str, dev_mesh,
-                devinfo) -> tuple:
+def _array_pass(prep, mirror, comps: list) -> bool:
     """The window's claims as columns and one array pass over them:
     fills ``prep.walk`` with the records of the claims that must walk
     and ``prep.frame`` with the mirror state their walk reads; every
-    claim without a record is decided here, accepted.  Returns (False,
-    None) when the snapshot cannot take the incremental path, else
-    (True, the device engine's record).
+    claim without a record is decided here, accepted.  Returns False
+    when the snapshot cannot take the incremental path.
 
     The pass computes, for every (plan, node) claim that places
     something, what the sequential order would see IF every earlier
@@ -781,7 +666,6 @@ def _array_pass(prep, mirror, comps: list, policy: str, dev_mesh,
     The mirror is locked for the sync, the gathers and the probes of
     the live port sets, and for the frame — which copies the walked
     nodes only; the table is built before it."""
-    from nomad_tpu.ops.verify_policy import VERIFY_DEVICE
     from nomad_tpu.server.plan_apply import _node_net_static
 
     plans = prep.plans
@@ -790,10 +674,6 @@ def _array_pass(prep, mirror, comps: list, policy: str, dev_mesh,
     statics = prep.statics
     index_of = prep.index_of
     by_id = prep.inflight_by_id
-    plan_comp = [0] * len(plans)
-    for ci, comp in enumerate(comps):
-        for i in comp:
-            plan_comp[i] = ci
 
     tab = _Claims(plans)
     n_pairs = len(tab.pair_nid)
@@ -808,7 +688,6 @@ def _array_pass(prep, mirror, comps: list, policy: str, dev_mesh,
     # walk_ord[k]: node k's claims take the per-claim walk.
     walk_ord = np.zeros(n_nodes, dtype=bool)
     nodes_u: list = [None] * n_nodes
-    ready_u = np.zeros(n_nodes, dtype=bool)
     reserved_ports: list = [frozenset()] * n_nodes
     bw_fixed = np.zeros(n_nodes, dtype=np.int64)  # reserved, then + live
     bw_avail = np.zeros(n_nodes, dtype=np.int64)
@@ -823,13 +702,11 @@ def _array_pass(prep, mirror, comps: list, policy: str, dev_mesh,
         node = nodes_u[k] = node_by_id(node_ids[ni])
         if not _ready(node):
             continue
-        ready_u[k] = True
         static = _node_net_static(statics, node, ni)
         if static:
             reserved_ports[k], bw_fixed[k], bw_avail[k], \
                 (ip_u[k], dev_u[k]) = static
             walk_ord[k] = False
-    pair_valid = ready_u[pair_ord]
 
     def walk_nodes(nis) -> None:
         """Mark the window's nodes among ``nis`` as walked."""
@@ -897,9 +774,62 @@ def _array_pass(prep, mirror, comps: list, policy: str, dev_mesh,
     frame_ids: set = set(tab.update_ids)
     frame_ids.update(by_id)
 
-    def decide(used, caps, fits) -> None:
-        """Close the set of walked nodes over the fit verdicts and the
-        all_at_once rule, and write the walked claims' records."""
+    # The net dicts are mutated in place by concurrent worker syncs;
+    # hold the mirror for the composite read — but ONLY for the gathers,
+    # the probes and the frame copy: the walks run lock-free against
+    # the frame.
+    with mirror.lock:
+        if not mirror.sync_net(base):
+            return False  # snapshot older than the mirror
+        # Live occupancy of the window's nodes.
+        keys_of = mirror.node_net_keys
+        dup_of = mirror.node_dup
+        bw_of = mirror.node_bw
+        ports_of = mirror.node_ports
+        live_ports: list = [()] * n_nodes
+        for k, ni in enumerate(uniq_l):
+            if walk_ord[k]:
+                continue
+            keys = keys_of.get(ni)
+            if (keys and (len(keys) > 1
+                          or (ip_u[k], dev_u[k]) not in keys)) \
+                    or dup_of.get(ni):
+                walk_ord[k] = True  # odd or doubled-up live offers
+                continue
+            pc = ports_of.get(ni)
+            if pc:
+                if not pc.keys().isdisjoint(reserved_ports[k]):
+                    walk_ord[k] = True  # live port on a reserved one
+                    continue
+                live_ports[k] = pc
+            bw_fixed[k] += bw_of.get(ni, 0)
+        # A claimed port that is live or reserved on its node.
+        taken = np.fromiter(
+            (p in live_ports[k] or p in reserved_ports[k]
+             for k, p in zip(port_ord.tolist(), tab.ports.tolist())),
+            dtype=bool, count=len(port_ord))
+        walk_ord[port_ord[taken]] = True
+        # An id that is live in the mirror is an in-place update (an
+        # id with a net row has a usage row).
+        live = mirror.alloc_rows.keys()
+        if not live.isdisjoint(tab.row_ids):
+            walk_rows_of(mirror.alloc_rows)
+        walk_all = walk_all or not live.isdisjoint(tab.failed_ids)
+        # Bandwidth: reserved + live + this and the earlier claims.
+        bw = bw_fixed[ord_s] + prefix(pair_mbits[order])
+        walk_ord[ord_s[bw > bw_avail[ord_s]]] = True
+
+        # Dense fit inputs over every claim at once: the 4 dims
+        # Resources.superset checks, float32 like the mirror rows
+        # (exact for values < 2^24, i.e. any realistic node).
+        used = mirror.usage[pair_ni, :4] \
+            + statics.reserved[pair_ni, :4] + delta
+        caps = statics.capacity[pair_ni, :4]
+        d64 = delta[order].astype(np.float64)
+        fits = (used[order] + (prefix(d64) - d64)
+                <= caps[order]).all(axis=1)
+        # Close the set of walked nodes over the fit verdicts and the
+        # all_at_once rule, and write the walked claims' records.
         walk_ord[ord_s[~fits]] = True
         if walk_all:
             walk_ord[:] = True
@@ -939,87 +869,8 @@ def _array_pass(prep, mirror, comps: list, policy: str, dev_mesh,
                                used_p, caps_p)
         for i, nid in tab.update_claims:
             records[i].setdefault(nid, True)  # evict-only: always fits
-
-    # The net dicts are mutated in place by concurrent worker syncs;
-    # hold the mirror for the composite read — but ONLY for the gathers,
-    # the probes and the frame copy: the walks run lock-free against
-    # the frame.
-    dev_args = None
-    dev_capres = None
-    dev_lease = None
-    with mirror.lock:
-        if not mirror.sync_net(base):
-            return False, None  # snapshot older than the mirror
-        # Live occupancy of the window's nodes.
-        keys_of = mirror.node_net_keys
-        dup_of = mirror.node_dup
-        bw_of = mirror.node_bw
-        ports_of = mirror.node_ports
-        live_ports: list = [()] * n_nodes
-        for k, ni in enumerate(uniq_l):
-            if walk_ord[k]:
-                continue
-            keys = keys_of.get(ni)
-            if (keys and (len(keys) > 1
-                          or (ip_u[k], dev_u[k]) not in keys)) \
-                    or dup_of.get(ni):
-                walk_ord[k] = True  # odd or doubled-up live offers
-                continue
-            pc = ports_of.get(ni)
-            if pc:
-                if not pc.keys().isdisjoint(reserved_ports[k]):
-                    walk_ord[k] = True  # live port on a reserved one
-                    continue
-                live_ports[k] = pc
-            bw_fixed[k] += bw_of.get(ni, 0)
-        # A claimed port that is live or reserved on its node.
-        taken = np.fromiter(
-            (p in live_ports[k] or p in reserved_ports[k]
-             for k, p in zip(port_ord.tolist(), tab.ports.tolist())),
-            dtype=bool, count=len(port_ord))
-        walk_ord[port_ord[taken]] = True
-        # An id that is live in the mirror is an in-place update (an
-        # id with a net row has a usage row).
-        live = mirror.alloc_rows.keys()
-        if not live.isdisjoint(tab.row_ids):
-            walk_rows_of(mirror.alloc_rows)
-        walk_all = walk_all or not live.isdisjoint(tab.failed_ids)
-        # Bandwidth: reserved + live + this and the earlier claims.
-        bw = bw_fixed[ord_s] + prefix(pair_mbits[order])
-        walk_ord[ord_s[bw > bw_avail[ord_s]]] = True
-
-        if dev_mesh is not None:
-            # Residency lease: references to the resident twins for
-            # THIS generation, or None — never an upload under the
-            # lock.
-            dev_lease = mirror.window_lease(dev_mesh)
-            dev_capres = statics.sharded.lookup(("capres", dev_mesh))
-        if dev_lease is not None and dev_capres is not None:
-            # Device engine: the dispatch (and every counted transfer)
-            # runs after release, so the walked nodes are not known
-            # yet: the frame copies every node of the window.
-            dev_args = _window_device_args(tab, pair_ni, pair_valid,
-                                           plan_comp)
-            frame_ids.update(tab.row_ids)
-            frame_nis = {ni for ni in uniq_l if ni >= 0}
-        else:
-            if policy == VERIFY_DEVICE:
-                devinfo = {"dispatched": False,
-                           "fallback": "lease-miss"
-                           if dev_lease is None else "capres-miss"}
-            # Host engine — dense fit inputs over every claim at once:
-            # the 4 dims Resources.superset checks, float32 like the
-            # mirror rows (exact for values < 2^24, i.e. any realistic
-            # node).
-            used = mirror.usage[pair_ni, :4] \
-                + statics.reserved[pair_ni, :4] + delta
-            caps = statics.capacity[pair_ni, :4]
-            d64 = delta[order].astype(np.float64)
-            fits = (used[order] + (prefix(d64) - d64)
-                    <= caps[order]).all(axis=1)
-            decide(used, caps, fits)
-            frame_nis = {ni for ni, w in zip(uniq_l, walk_ord.tolist())
-                         if w and ni >= 0}
+        frame_nis = {ni for ni, w in zip(uniq_l, walk_ord.tolist())
+                     if w and ni >= 0}
         # The in-flight apply's allocs fold into component overlays, so
         # their frame rows (and nodes) must ride along too.
         for nid in prep.inflight_nodes:
@@ -1028,22 +879,10 @@ def _array_pass(prep, mirror, comps: list, policy: str, dev_mesh,
                 frame_nis.add(ni)
         prep.frame = _Frame(mirror, frame_ids, frame_nis)
 
-    if dev_args is not None:
-        try:
-            used, caps, fits, devinfo = _dispatch_window_fit(
-                dev_mesh, dev_capres, dev_lease, dev_args)
-        except Exception as e:
-            from nomad_tpu.parallel.devices import transient_device_fault
-            if not transient_device_fault(e):
-                raise  # e.g. a kernel the chip's compiler refuses
-            # Rare (runtime teardown, device OOM): the window still
-            # verifies exactly — the caller's per-plan scalar path.
-            return False, None
-        decide(used, caps, fits[order] | ~pair_valid[order])
-    return True, devinfo
+    return True
 
 
-def _evaluate_window_vec(overlay, plans: list, executor, partition: bool,
+def _evaluate_window_vec(overlay, plans: list,
                          fold: bool = True) -> Optional[WindowVerdicts]:
     """One window through the incremental path: the array pass over
     the window's claims (``_array_pass``) decides what it can prove,
@@ -1085,38 +924,9 @@ def _evaluate_window_vec(overlay, plans: list, executor, partition: bool,
     mirror = mirror_for(statics)
     index_of = statics.index_of
 
-    # Components are computed up front (pure on the plans): the device
-    # fold descriptors and the all_at_once rule both need each plan's.
-    if partition:
-        comps = partition_window(plans, plan_nodes)
-    else:
-        comps = [list(range(len(plans)))]
-
-    # Device-verify policy (ops/verify_policy.py): mesh resolution and
-    # any twin warm-up happen OUTSIDE the mirror lock; under the lock
-    # the device path only LOOKS UP residency (the window-lease rule).
-    from nomad_tpu.ops.verify_policy import (
-        VERIFY_DEVICE,
-        VERIFY_HOST,
-        verify_policy,
-    )
-
-    policy = verify_policy()
-    dev_mesh = None
-    devinfo = None
-    if policy != VERIFY_HOST:
-        from nomad_tpu.parallel.mesh import dispatch_mesh
-        dev_mesh = dispatch_mesh(1, statics.n_pad)
-        if dev_mesh is None:
-            if policy == VERIFY_DEVICE:
-                devinfo = {"dispatched": False, "fallback": "no-mesh"}
-        elif policy == VERIFY_DEVICE:
-            # Forced intent: warm the twins now (no-op when resident)
-            # so this window — or the next — holds the lease.  ``auto``
-            # never uploads: it takes the device path only when the
-            # twins are already there.
-            statics.device_capacity_reserved_sharded(dev_mesh)
-            mirror.device_usage_sharded(dev_mesh, mirror.usage)
+    # Components are computed up front (pure on the plans): the
+    # all_at_once rule of the array pass needs each plan's.
+    comps = partition_window(plans, plan_nodes)
 
     prep = _Prep()
     prep.plans = plans
@@ -1140,46 +950,25 @@ def _evaluate_window_vec(overlay, plans: list, executor, partition: bool,
         by_id[a.id] = (k, a)
 
     prep.walk = [dict() for _ in plans]
-    if policy != VERIFY_DEVICE and \
-            sum(map(len, plan_nodes)) < ARRAY_PASS_MIN_CLAIMS:
+    if sum(map(len, plan_nodes)) < ARRAY_PASS_MIN_CLAIMS:
         with mirror.lock:
             if not mirror.sync_net(base):
                 return None  # snapshot older than the mirror: scalar truth
             _walk_all_records(prep, mirror)
-    else:
-        ok, devinfo = _array_pass(prep, mirror, comps, policy, dev_mesh,
-                                  devinfo)
-        if not ok:
-            return None
+    elif not _array_pass(prep, mirror, comps):
+        return None
 
-    # Schedule and walk the components.  Mirror lock released — the
-    # walks read only the frame, the base snapshot, and prep.
-    if len(comps) > 1:
-        # Deadline-aware scheduling: nearest member deadline first
-        # (ties by window position), so a near-deadline plan's
-        # component is never last in line behind the executor.
-        def comp_key(comp):
-            deadline = min((plans[i].deadline for i in comp
-                            if plans[i].deadline), default=float("inf"))
-            return (deadline, comp[0])
-        order_c = sorted(range(len(comps)),
-                         key=lambda k: comp_key(comps[k]))
-    else:
-        order_c = list(range(len(comps)))
+    # Walk the components.  Mirror lock released — the walks read only
+    # the frame, the base snapshot, and prep.  Nearest member deadline
+    # first (ties by window position), so a near-deadline plan's
+    # component is never last in line.
+    def comp_key(k: int) -> tuple:
+        deadline = min((plans[i].deadline for i in comps[k]
+                        if plans[i].deadline), default=float("inf"))
+        return (deadline, comps[k][0])
 
-    wall0 = time.perf_counter()
-    tasks = [(lambda comp=comps[k]: _walk_component(prep, comp))
-             for k in order_c]
-    if executor is not None and len(tasks) > 1 and \
-            max(len(c) for c in comps) >= MIN_CONCURRENT_COMPONENT:
-        results = executor.run_components(
-            tasks, descs=[{"component": k, "plans": len(comps[k]),
-                           "eval_ids": [plans[i].eval_id
-                                        for i in comps[k]]}
-                          for k in order_c])
-    else:
-        results = [t() for t in tasks]
-    wall = time.perf_counter() - wall0
+    order_c = sorted(range(len(comps)), key=comp_key)
+    results = [_walk_component(prep, comps[k]) for k in order_c]
 
     slots: list = [None] * len(plans)
     comp_walls: list = []
@@ -1203,14 +992,6 @@ def _evaluate_window_vec(overlay, plans: list, executor, partition: bool,
         "order": order_c,
         "comp_walls": comp_walls,
         "comp_t0s": comp_t0s,  # perf_counter epoch (span conversion)
-        "wall": wall,
-        # How much wall the partition saved vs walking the same
-        # components serially (1.0 = none; GIL-bound walks cap this).
-        "speedup": (sum(comp_walls) / wall) if wall > 0 else 1.0,
-        # Device-verify engine record: None when the host engine ran by
-        # policy; else dispatch/fallback details for the applier's
-        # device_verify_* stats and the applier.verify.device span.
-        "device": devinfo,
     }
     return WindowVerdicts(slots, info)
 
@@ -1221,8 +1002,7 @@ def _walk_component(prep, comp: list) -> tuple:
     a record in ``prep.walk``; every other claim was decided by the
     array pass (accepted) and only joins its plan's result.  Returns
     ([(plan_index, WindowOutcome, accepted)], t0_perf_counter,
-    wall_seconds).  Reads only frozen prep state + the base snapshot —
-    safe on an executor thread."""
+    wall_seconds).  Reads only frozen prep state + the base snapshot."""
     from nomad_tpu.server.plan_apply import (
         OptimisticSnapshot,
         _evaluate_node_plan,

@@ -35,7 +35,7 @@ def configure_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns its directory.
 
     Called once by every entry point that compiles (agent boot,
-    bench.py, chip_smoke.py) before the first jit.  When
+    chip_smoke.py, benchmarks/run.py) before the first jit.  When
     ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
     nothing is set here; otherwise the cache lives at the fixed,
     git-ignored ``<checkout>/.jax_cache``.  The path is part of the
@@ -84,8 +84,7 @@ def transient_device_fault(e: Exception) -> bool:
 # everything that crosses the PCIe/ICI boundary goes through one of the
 # explicit seams below (put_counted / ensure_on_default / mesh._put /
 # ShardedResidency / fetch_host), so "how many transfers per eval" is a
-# number the bench can record instead of a guess
-# (BENCH host_transfers_per_eval).
+# number a run can record instead of a guess.
 
 _TRANSFER_LOCK = threading.Lock()
 _TRANSFERS = {"h2d": 0, "d2h": 0, "d2d": 0}
@@ -246,8 +245,7 @@ def classify_move(src_platform: str, dst_platform: str) -> str:
     mesh._put so the odometer cannot drift between seams): a move
     whose source or destination is the cpu backend crosses the host
     boundary — cpu jax buffers live in host memory — and counting it
-    d2d would under-report the h2d odometer the bench's
-    host_transfers_per_eval is built on."""
+    d2d would under-report the h2d odometer."""
     if src_platform == "cpu" and dst_platform != "cpu":
         return "h2d"
     if dst_platform == "cpu" and src_platform != "cpu":

@@ -22,13 +22,10 @@ import os
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from nomad_tpu.obs import trace as trace_mod
 from nomad_tpu.ops.binpack import _place_rounds, _place_sequence
-from nomad_tpu.parallel.devices import NO_DISPATCH, device_dispatch
 
 FLEET_AXIS = "fleet"
 LANE_AXIS = "lanes"
@@ -65,8 +62,8 @@ def _mesh_policy():
 @contextlib.contextmanager
 def mesh_override(value):
     """Temporarily force the mesh policy ("off", "auto", or a device
-    count) — the bench's unsharded twins and the tier-1 parity rigs
-    compare sharded against single-device runs through this."""
+    count) — the tier-1 parity rigs compare sharded against
+    single-device runs through this."""
     prior = _OVERRIDE[0]
     _OVERRIDE[0] = value
     try:
@@ -343,107 +340,3 @@ def place_sequence_batch_sharded(mesh: Mesh, capacity, reserved, usage0,
     return _place_sequence_batch_sharded_jit(
         capacity, reserved, usage0, jc0, feasible, asks, distinct,
         group_idx, valid, penalty)
-
-
-# -- window-verify kernel --------------------------------------------------
-# The group-commit applier's cross-plan base fit (ops/plan_conflict.py
-# _evaluate_window_vec), re-expressed against the mesh-resident twins:
-# one dispatch per window, fleet tensors never leave the mesh.  Work
-# descriptors are tiny (one row per (plan, node) claim / placement /
-# fold entry, all padded to ONE shared power-of-two bucket so distinct
-# window sizes reuse the trace), so the dispatch cost is flat in fleet
-# size — the property bench 5f's fleet-scaling sub-table asserts.
-
-
-@jax.jit
-def _window_verify_jit(capacity, reserved, usage, pair_ni, row_pair,
-                       row_vec, seq_ni, seq_vec, seq_order, seq_comp,
-                       pair_order, pair_comp, pair_removed):
-    """used/caps/fits for every (plan, node) claim of one window.
-
-    capacity/reserved/usage are the [N, D] node-axis-sharded resident
-    twins; everything else is a replicated per-window descriptor padded
-    to a shared bucket B:
-
-      pair_ni      i32[B]    claimed node index per pair (0-padded)
-      row_pair     i32[B]    pair index per placement row (0-padded)
-      row_vec      f32[B,4]  placement resource vectors (0-padded)
-      seq_ni       i32[B]    fold-entry node index (-1-padded)
-      seq_vec      f32[B,4]  fold-entry delta (adds +, removals -)
-      seq_order    i32[B]    fold-entry window plan index
-      seq_comp     i32[B]    fold-entry claim-graph component (-1-pad)
-      pair_order   i32[B]    pair's window plan index
-      pair_comp    i32[B]    pair's claim-graph component
-      pair_removed f32[B,4]  pair's own removed-row sums (frame rows)
-
-    All resource values are small integers in float32 and every sum
-    here is a plain float32 add (scatter-add, masked reduce — never a
-    matmul, which a TPU runs as a bf16 pass at default precision and
-    would round an ask of 517 MHz to 516), so the sums are exact and
-    order-independent: the device numbers (and the verdicts compared
-    from them) are byte-identical to the host dense pass (the same
-    argument _evaluate_window_vec already relies on).
-    """
-    npair = pair_ni.shape[0]
-    # Claim-scatter: each pair's placement rows sum into its delta row.
-    delta = jnp.zeros((npair, 4), dtype=jnp.float32)
-    delta = delta.at[row_pair].add(row_vec)
-    # Claim-sum: gather the sharded twins at the claimed rows (XLA
-    # resolves the cross-shard gather with collectives — the work
-    # descriptors are replicated, the fleet axis never gathers whole).
-    used = usage[pair_ni, :4] + reserved[pair_ni, :4] + delta
-    caps = capacity[pair_ni, :4]
-    # Window-scoped overlay: the component folds as ONE scatter-add —
-    # pair p's overlay is the sum of every fold entry on its node from
-    # strictly-earlier window plans of p's OWN component (host walks
-    # are component-local, and a removal entry can land on a mirror-row
-    # node outside the claim graph, so node equality alone is not
-    # enough), under the optimistic all-accepted assumption the host
-    # pass proves node by node (plan_conflict._evaluate_window_vec).
-    fold = (seq_ni[None, :] == pair_ni[:, None]) \
-        & (seq_order[None, :] < pair_order[:, None]) \
-        & (seq_comp[None, :] == pair_comp[:, None])
-    # One masked float32 reduce per resource column (fold entries along
-    # the minor axis) — see the exactness note in the docstring.
-    overlay = jnp.stack(
-        [jnp.sum(jnp.where(fold, seq_vec[None, :, d], jnp.float32(0.0)),
-                 axis=1) for d in range(4)], axis=1)
-    used_seq = used + overlay - pair_removed
-    fits_seq = jnp.all(used_seq <= caps, axis=1)
-    return used, caps, fits_seq
-
-
-def window_verify_sharded(mesh: Mesh, capacity, reserved, usage, pair_ni,
-                          row_pair, row_vec, seq_ni, seq_vec, seq_order,
-                          seq_comp, pair_order, pair_comp, pair_removed):
-    """One window's base fit + optimistic overlay fold, node axis
-    sharded over ``mesh``.
-
-    capacity/reserved/usage normally arrive as the already-resident
-    ShardedResidency twins (zero transfers — _put skips them); the
-    per-window descriptors are placed replicated and counted.  The
-    caller fetches the three results through devices.fetch_host — the
-    sanctioned d2h seam — so the whole verify dispatch is implicit-
-    transfer-free under the hard transfer guard."""
-    node, _, repl = _shardings(mesh)
-    capacity = _put(capacity, node)
-    reserved = _put(reserved, node)
-    usage = _put(usage, node)
-    pair_ni = _put(pair_ni, repl)
-    row_pair = _put(row_pair, repl)
-    row_vec = _put(row_vec, repl)
-    seq_ni = _put(seq_ni, repl)
-    seq_vec = _put(seq_vec, repl)
-    seq_order = _put(seq_order, repl)
-    seq_comp = _put(seq_comp, repl)
-    pair_order = _put(pair_order, repl)
-    pair_comp = _put(pair_comp, repl)
-    pair_removed = _put(pair_removed, repl)
-    with (device_dispatch(_window_verify_jit, async_=True,
-                          n_pad=capacity.shape[0],
-                          rows=pair_ni.shape[0])
-          if trace_mod.ENABLED else NO_DISPATCH):
-        return _window_verify_jit(capacity, reserved, usage, pair_ni,
-                                  row_pair, row_vec, seq_ni, seq_vec,
-                                  seq_order, seq_comp, pair_order,
-                                  pair_comp, pair_removed)

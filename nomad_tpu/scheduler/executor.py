@@ -12,10 +12,9 @@ The jax-binpack scheduler picks between two executors per dispatch
           the round trip behind host work.
 
 ``auto`` (the default) applies the cost model.  ``host`` / ``device``
-force one side — the bench's `4_device_pipelined` row, the multi-chip
-dry run, and the host/device parity smoke all need a *forcible* device
-path, and an operator diagnosing a slow chip wants the same lever
-without editing code.
+force one side — the multi-chip dry run and the host/device parity
+smoke both need a *forcible* device path, and an operator diagnosing a
+slow chip wants the same lever without editing code.
 
 Resolution order (first set wins):
 
@@ -84,7 +83,7 @@ def set_executor_policy(value: str) -> None:
 def executor_policy() -> str:
     """The effective policy right now: env var, then configured value,
     then ``auto``.  Read per dispatch — cheap (one getenv) and it keeps
-    the bench's scoped overrides race-free with respect to restarts."""
+    scoped overrides race-free with respect to restarts."""
     env = os.environ.get(ENV_VAR)
     if env:
         return _validate(env, f"${ENV_VAR}")

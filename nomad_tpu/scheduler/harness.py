@@ -5,7 +5,7 @@ Capability parity with the reference's Harness rig
 an in-memory Planner that applies plans directly to state and records
 Plans/Evals/CreateEvals; `RejectPlan` injects plan-rejection faults to
 exercise the refresh/retry path.  This is the primary TDD loop for both the
-Python and the JAX schedulers, and the driver for bench.py.
+Python and the JAX schedulers.
 """
 from __future__ import annotations
 
@@ -122,7 +122,7 @@ class VerifyingPlanner:
     def __init__(self, h: Harness) -> None:
         self.h = h
         self.conflicts = 0  # plans that came back partial/rejected
-        # Group-commit observability (bench 5b fields):
+        # Group-commit observability:
         self.commits = 0            # commit operations (group or single)
         self.committed_plans = 0    # plans those commits carried
         self.conflict_fallbacks = 0  # window plans needing the exact

@@ -634,7 +634,7 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
     dispatched_host: "bool | None" = None
     # Whether the last device dispatch ran node-axis-sharded over a
     # mesh (parallel/mesh.dispatch_mesh resolved one) — the runner's
-    # sharded_dispatches counter and the bench's sharded rows read it.
+    # sharded_dispatches counter reads it.
     dispatched_sharded: "bool | None" = None
 
     def _dev_const(self, args: "DeviceArgs", key: str,

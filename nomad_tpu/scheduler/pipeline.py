@@ -181,10 +181,9 @@ class PipelinedEvalRunner(BatchEvalRunner):
     deltas (migrations, in-place updates) pipeline like any other.
 
     ``latencies`` records per-eval wall seconds (begin -> plan
-    submitted) for the bench's percentile reporting.  ``stage_times``
-    accumulates per-stage wall seconds (begin/dispatch/collect/finish/
-    submit) across the run — the single-eval host-floor profile the
-    bench's bottleneck note reports.  ``host_dispatches`` /
+    submitted).  ``stage_times`` accumulates per-stage wall seconds
+    (begin/dispatch/collect/finish/submit) across the run — the
+    single-eval host-floor profile.  ``host_dispatches`` /
     ``device_dispatches`` count which executor each dispatch actually
     used (NOMAD_TPU_EXECUTOR forces it; scheduler/executor.py).
     """

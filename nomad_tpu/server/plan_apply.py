@@ -320,186 +320,14 @@ def _evaluate_node_plan(snap, plan: Plan, node_id: str) -> bool:
     return fit
 
 
-class _ComponentBatch:
-    """One window's worth of component-walk tasks, consumed
-    front-to-back by the executor's workers plus the coordinator."""
-
-    __slots__ = ("tasks", "descs", "results", "next", "completed",
-                 "error", "done")
-
-    def __init__(self, tasks: list, descs: list) -> None:
-        self.tasks = tasks
-        self.descs = descs
-        self.results = [None] * len(tasks)
-        self.next = 0
-        self.completed = 0
-        self.error: Optional[Exception] = None
-        self.done = threading.Event()
-
-
-class ComponentExecutor:
-    """Small worker pool verifying a window's claim-graph components
-    concurrently (ops/plan_conflict.evaluate_window passes its
-    deadline-ordered component tasks here).
-
-    Tasks are consumed strictly front-to-back, so the deadline order
-    the scheduler chose IS the start order; the coordinator (the
-    applier thread) participates, so ``workers=0`` degrades to inline
-    execution.  ``active()`` snapshots what every thread is verifying
-    right now — the flight recorder's ``applier.window`` stall guard
-    attaches it to incident dumps, so a wedged window names the slow
-    component instead of just the window."""
-
-    def __init__(self, workers: int = 2,
-                 name: str = "plan-components") -> None:
-        self.workers = max(0, int(workers))
-        self.name = name
-        self._lock = threading.Lock()
-        self._cond = threading.Condition(self._lock)
-        self._batch: Optional[_ComponentBatch] = None
-        self._threads: list = []
-        self._stopped = False
-        self._active: dict = {}   # thread name -> (desc, started)
-        self.batches = 0          # windows dispatched; guarded
-        self.components_run = 0   # component walks executed; guarded
-
-    def run_components(self, tasks: list, descs=None) -> list:
-        """Run every task, concurrently when workers exist; returns
-        results in task order.  The first task exception (components
-        must not raise in normal operation) re-raises here, after every
-        task has been consumed.
-
-        Tasks are dispatched as ``workers + 1`` CONTIGUOUS chunks of
-        the deadline-ordered list — one condition wake per worker per
-        window, not per component (a saturated window is dozens of
-        single-plan components, and per-task handoff cost more than the
-        walks).  The coordinator takes the first chunk, so the
-        nearest-deadline components start immediately on the applier
-        thread even if every worker is cold."""
-        descs = descs if descs is not None else [None] * len(tasks)
-        inline = False
-        chunks: list = []
-        with self._cond:
-            self.components_run += len(tasks)
-            if self._stopped or self.workers == 0 or len(tasks) <= 2 \
-                    or self._batch is not None:
-                inline = True
-            else:
-                n_chunks = min(len(tasks), self.workers + 1)
-                step = -(-len(tasks) // n_chunks)  # ceil division
-                for lo in range(0, len(tasks), step):
-                    sl = slice(lo, min(lo + step, len(tasks)))
-                    chunks.append((sl, tasks[sl], descs[sl]))
-                batch = _ComponentBatch(
-                    [self._chunk_task(ts) for _sl, ts, _d in chunks],
-                    [{"components": [d for d in ds if d]}
-                     for _sl, _ts, ds in chunks])
-                self._batch = batch
-                self.batches += 1
-                self._ensure_threads_locked()
-                self._cond.notify_all()
-        if inline:
-            return [self._run_one(task, desc)
-                    for task, desc in zip(tasks, descs)]
-        self._drain(batch)
-        batch.done.wait()
-        with self._cond:
-            self._batch = None
-        if batch.error is not None:
-            raise batch.error
-        out: list = [None] * len(tasks)
-        for (sl, _ts, _ds), chunk_results in zip(chunks, batch.results):
-            out[sl] = chunk_results
-        return out
-
-    @staticmethod
-    def _chunk_task(chunk_tasks: list):
-        return lambda: [t() for t in chunk_tasks]
-
-    def _run_one(self, task, desc):
-        me = threading.current_thread().name
-        with self._lock:
-            self._active[me] = (desc, time.monotonic())
-        try:
-            return task()
-        finally:
-            with self._lock:
-                self._active.pop(me, None)
-
-    def _drain(self, batch: _ComponentBatch) -> None:
-        while True:
-            with self._cond:
-                i = batch.next
-                if i >= len(batch.tasks):
-                    return
-                batch.next = i + 1
-            try:
-                result = self._run_one(batch.tasks[i], batch.descs[i])
-                batch.results[i] = result
-            except Exception as e:
-                if batch.error is None:
-                    batch.error = e
-            finally:
-                with self._cond:
-                    batch.completed += 1
-                    if batch.completed == len(batch.tasks):
-                        batch.done.set()
-
-    def _ensure_threads_locked(self) -> None:
-        while len(self._threads) < self.workers:
-            t = threading.Thread(
-                target=self._worker, daemon=True,
-                name=f"{self.name}-{len(self._threads)}")
-            self._threads.append(t)
-            t.start()
-
-    def _worker(self) -> None:
-        while True:
-            with self._cond:
-                while not self._stopped and (
-                        self._batch is None
-                        or self._batch.next >= len(self._batch.tasks)):
-                    self._cond.wait()
-                if self._stopped:
-                    return
-                batch = self._batch
-            self._drain(batch)
-
-    def active(self) -> dict:
-        """What every executor thread is verifying right now — the
-        stall guard's per-component attribution."""
-        now = time.monotonic()
-        with self._lock:
-            return {"verifying": [
-                dict(desc or {}, thread=name,
-                     age_s=round(now - started, 3))
-                for name, (desc, started) in self._active.items()]}
-
-    def stats(self) -> dict:
-        with self._lock:
-            return {"workers": self.workers,
-                    "batches": self.batches,
-                    "components_run": self.components_run,
-                    "active": len(self._active)}
-
-    def stop(self, timeout: float = 2.0) -> None:
-        with self._cond:
-            self._stopped = True
-            self._cond.notify_all()
-            threads = list(self._threads)
-        for t in threads:
-            if t is not threading.current_thread():
-                t.join(timeout)
-
-
 class _Committer:
     """ONE long-lived FIFO thread executing the commit tail of each
     window — wire encode, raft dispatch, commit wait, future responds —
     in window order, off the applier thread.
 
     This deepens the reference's verify/apply overlap (plan_apply.go:
-    68-85): the applier thread's serialized section shrinks to token
-    fence + partitioned verify + overlay fold, while the encode, the
+    68-85): the applier thread's serialized section is token fence +
+    window verify + overlay fold, while the encode, the
     raft apply and (with InmemRaft) the synchronous FSM decode +
     batched store upsert — the priciest per-plan stages of the whole
     pipeline — ride here.  FIFO preserves the dispatch order and the
@@ -602,32 +430,25 @@ class PlanApplier:
     Each iteration pops every pending plan (up to ``max_window``,
     gathering briefly under saturation so windows drain full), fences
     the whole window's tokens in ONE broker call, verifies it with the
-    partitioned cross-plan conflict pass
-    (ops/plan_conflict.evaluate_window — claim-graph components
-    verified concurrently on the ComponentExecutor, nearest-deadline
-    component first, byte-exact eval order within each component), and
-    commits ALL accepted portions as ONE raft apply carrying a
-    multi-plan FSM message — amortizing the Raft/FSM/native overhead
-    that made the serialized commit the contended storm's floor.
-    Per-plan futures are responded with results identical to sequential
-    application in eval order; the overlapped verify/apply
+    cross-plan conflict pass (ops/plan_conflict.evaluate_window — the
+    array pass over the window's claims, the per-claim walk by
+    claim-graph component, nearest-deadline component first, byte-exact
+    eval order within each component), and hands the commit tail to
+    the ``_Committer``: ALL accepted portions as ONE raft apply
+    carrying a multi-plan FSM message — amortizing the Raft/FSM/native
+    overhead that made the serialized commit the contended storm's
+    floor.  Per-plan futures are responded with results identical to
+    sequential application in eval order; the overlapped verify/apply
     snapshot-overlay semantics extend to batches (the next window
-    verifies against the in-flight window's overlay).
-
-    ``sequential=True`` restores the pre-partition behavior — per-plan
-    token fence, one flat verify walk, no gather — and exists as the
-    bench's in-run baseline (bench 5f measures the partitioned path
-    against it on the same host)."""
+    verifies against the in-flight windows' overlay)."""
 
     # A verify+commit window past this wall is a wedged leader, not a
     # big window: trip the flight recorder (when one is installed).
     WINDOW_STALL_S = 30.0
 
     def __init__(self, plan_queue, eval_broker, raft, state_fn,
-                 max_window: int = 64, component_workers: int = 2,
-                 gather_s: float = 0.02,
-                 deadline_horizon: float = 0.25,
-                 sequential: bool = False) -> None:
+                 max_window: int = 64, gather_s: float = 0.02,
+                 deadline_horizon: float = 0.25) -> None:
         self.plan_queue = plan_queue
         self.eval_broker = eval_broker
         self.raft = raft
@@ -642,9 +463,6 @@ class PlanApplier:
         # to the front of the drained window (plan_queue.drain_pending)
         # and their components verify first.
         self.deadline_horizon = deadline_horizon
-        self.sequential = sequential
-        self.components = ComponentExecutor(
-            workers=0 if sequential else component_workers)
         self._committer = _Committer()
         # Commit-pipeline depth bound: at most this many windows may be
         # queued/executing in the committer before the applier blocks —
@@ -652,7 +470,7 @@ class PlanApplier:
         # ahead of committed state).
         self.max_inflight_commits = 2
         self._thread: Optional[threading.Thread] = None
-        # Group-commit observability (bench 5b/5f fields ride on these).
+        # Group-commit observability.
         self._stats_lock = threading.Lock()
         self.commits = 0            # raft applies dispatched
         self.plans_committed = 0    # plans carried by those applies
@@ -665,18 +483,6 @@ class PlanApplier:
         #                             on a result nobody is waiting for
         self.components_verified = 0  # claim-graph components walked
         self.component_plans = 0      # plans those components carried
-        self._speedup_sum = 0.0       # per-window cross-component
-        self._speedup_n = 0           # concurrency (sum walls / wall)
-        # The serialized commit section's wall cost (token fence
-        # + window verify + overlay fold on the partitioned path; plus
-        # wire encode + raft dispatch + FSM apply on the sequential
-        # one — everything the applier thread itself must finish before
-        # the next window), and the plans that rode it:
-        # serial_ms_per_plan is the direct measure of "the commit point
-        # is no longer one ordered stream" that bench 5f asserts at
-        # matched window occupancy.
-        self.serial_seconds = 0.0
-        self.serial_plans = 0
         # Control-plane gauges: wall the applier spent blocked on a
         # full commit pipeline (the max_inflight_commits AIMD's grow
         # signal — sustained backpressure means more run-ahead would
@@ -684,18 +490,6 @@ class PlanApplier:
         self.commit_backpressure_s = 0.0
         self.dispatch_failures = 0
         self.gather_wall_s = 0.0  # wall spent in the window gather
-        # Device-verify engine (ops/verify_policy.py lever): windows
-        # whose base fit ran as one sharded dispatch against the
-        # resident twins, windows where a device-policy verify fell
-        # back to the host engine (cold lease, no mesh), and the
-        # counted explicit transfers those dispatches cost — off the
-        # parallel/devices odometer, so "zero implicit transfers"
-        # stays checkable per window.
-        self.device_verify_dispatches = 0
-        self.device_verify_fallbacks = 0
-        self.device_verify_h2d = 0
-        self.device_verify_d2h = 0
-        self.device_verify_wall_s = 0.0
         # Set by a committer job whose raft DISPATCH failed (nothing
         # entered the log): the overlay folded that window's allocs
         # before hand-off, so the applier must serialize the pipeline
@@ -715,15 +509,12 @@ class PlanApplier:
             self._thread.join(timeout)
 
     def shutdown(self, timeout: float = 2.0) -> None:
-        """Terminal teardown: reap the component executor's workers and
-        the committer (the applier thread itself exits when the queue
-        is disabled)."""
-        self.components.stop(timeout)
+        """Terminal teardown: reap the committer (the applier thread
+        itself exits when the queue is disabled)."""
         self._committer.stop(timeout)
         self.join(timeout)
 
     def run(self) -> None:
-        wait_future = None
         snap: Optional[OptimisticSnapshot] = None
         while True:
             t_deq = time.monotonic()
@@ -755,9 +546,7 @@ class PlanApplier:
                     self.gather_wall_s += time.monotonic() - t_gather
             window = [pending]
             window += self.plan_queue.drain_pending(
-                self.max_window - 1,
-                horizon=None if self.sequential
-                else self.deadline_horizon)
+                self.max_window - 1, horizon=self.deadline_horizon)
             try:
                 # Stall watchdog (obs/flight.py): a window that
                 # overstays WINDOW_STALL_S trips an incident dump with
@@ -766,13 +555,15 @@ class PlanApplier:
                 # undebuggable after the fact.  No-op when no flight
                 # recorder is installed.
                 # extra_fn: the incident dump names WHAT was being
-                # verified when the window wedged — the executor's
-                # per-component attribution, not just "the window".
-                with flight_mod.guard("applier.window",
-                                      self.WINDOW_STALL_S,
-                                      extra_fn=self.components.active):
-                    wait_future, snap = self._apply_window(
-                        window, wait_future, snap)
+                # verified when the window wedged — its evals, not just
+                # "the window".
+                with flight_mod.guard(
+                        "applier.window", self.WINDOW_STALL_S,
+                        extra_fn=lambda: {"verifying": {
+                            "plans": len(window),
+                            "eval_ids": [p.plan.eval_id
+                                         for p in window]}}):
+                    snap = self._apply_window(window, snap)
             except Exception as e:
                 # Popped futures must ALWAYS be responded: an applier
                 # dying with them in hand would park their workers
@@ -783,29 +574,25 @@ class PlanApplier:
                 # hand back torn fields); the rest get the error, which
                 # is truthful — _apply_window answers every committed
                 # member itself before anything else can raise.
-                # Serialize out the in-flight apply before dropping the
-                # overlay: the next window's fresh snapshot must include
-                # it or verification re-admits conflicts.
                 logger.exception("plan applier: unexpected failure")
                 for pend in window:
                     if not pend.done():
                         pend.respond(None, e)
-                if wait_future is not None:
-                    try:
-                        self._wait_commit(wait_future)
-                    except Exception:
-                        pass
-                if not self.sequential:
-                    # In-flight applies live in the committer pipeline:
-                    # drain it too, or the fresh snapshot could miss a
-                    # commit and re-admit its conflicts.
-                    self._committer.wait_drained(timeout=30.0)
-                wait_future, snap = None, None
+                # In-flight applies live in the committer pipeline:
+                # drain it before dropping the overlay, or the next
+                # window's fresh snapshot could miss a commit and
+                # re-admit its conflicts.
+                self._committer.wait_drained(timeout=30.0)
+                snap = None
 
-    def _fence(self, pending) -> bool:
-        """Token fencing: the eval must be outstanding and the token
-        must match (guards split-brain schedulers, plan_apply.go:53).
-        Responds the future and returns False on a fencing failure.
+    def _fence_window(self, window) -> list:
+        """Token fencing, the whole window in ONE broker call
+        (``outstanding_many`` reads the token mirror behind its leaf
+        lock; per-plan ``outstanding`` queued the applier behind the
+        submitter herd's enqueue/dequeue/ack convoy once per plan): the
+        eval must be outstanding and the token must match (guards
+        split-brain schedulers, plan_apply.go:53).  Responds the future
+        of every plan that fails and returns the rest.
 
         Deadline drop first (overload control plane): a plan whose
         propagated deadline passed gets an ``ErrDeadlineExceeded``
@@ -813,34 +600,6 @@ class PlanApplier:
         wait has expired and the broker's nack timer has (or is about
         to) redeliver the eval, so a commit here would only race the
         retry toward double placement while burning the leader."""
-        from .overload import ErrDeadlineExceeded
-
-        plan = pending.plan
-        if plan.deadline and time.monotonic() > plan.deadline:
-            with self._stats_lock:
-                self.expired_drops += 1
-            pending.respond(None, ErrDeadlineExceeded(
-                f"plan for eval {plan.eval_id} expired in queue"))
-            return False
-        token, ok = self.eval_broker.outstanding(plan.eval_id)
-        if not ok:
-            pending.respond(None, RuntimeError(
-                "evaluation is not outstanding"))
-            return False
-        if plan.eval_token != token:
-            pending.respond(None, RuntimeError(
-                "evaluation token does not match"))
-            return False
-        return True
-
-    def _fence_window(self, window) -> list:
-        """The whole window's token fence in ONE broker call
-        (``outstanding_many`` reads the token mirror behind its leaf
-        lock): per-plan ``outstanding`` queued the applier behind the
-        submitter herd's enqueue/dequeue/ack convoy once per plan, and
-        under bench 5f's 256 submitters those waits were over half the
-        applier's wall.  Same verdicts as :meth:`_fence`, same response
-        semantics, same stats."""
         from .overload import ErrDeadlineExceeded
 
         tokens = self.eval_broker.outstanding_many(
@@ -870,39 +629,18 @@ class PlanApplier:
                 self.expired_drops += expired
         return pendings
 
-    def _apply_window(self, window, wait_future, snap):
-        """Verify + group-commit one drained window; returns the
-        (wait_future, snap) verify/apply-overlap state carried to the
-        next iteration."""
-        from nomad_tpu.ops.plan_conflict import evaluate_window
+    def _apply_window(self, window, snap):
+        """Fence and verify one drained window and hand its commit tail
+        to the committer; returns the optimistic overlay carried to the
+        next iteration (the verify/apply overlap)."""
+        from nomad_tpu.ops.plan_conflict import (
+            _accepted_allocs,
+            evaluate_window,
+        )
 
-        # Serialized-section accounting: everything this method does
-        # except waiting out in-flight applies (those waits are the
-        # verify/apply overlap — by design not serialized against this
-        # window's verify).  Wall clock deliberately: the applier
-        # thread's wall between windows — GIL waits included — is what
-        # actually bounds its commit cadence.  (Thread-CPU time would
-        # be cleaner noise-wise, but CLOCK_THREAD_CPUTIME_ID ticks at
-        # ~10 ms on this class of kernel, which zeroes sub-ms
-        # sections.)  bench 5f asserts serial_ms_per_plan against the
-        # sequential baseline at matched window occupancy.
-        t_mark = time.perf_counter()
-        serial = 0.0
-        n_window = len(window)
-
-        def _book() -> None:
-            with self._stats_lock:
-                self.serial_seconds += \
-                    serial + (time.perf_counter() - t_mark)
-                self.serial_plans += n_window
-
-        if self.sequential:
-            pendings = [p for p in window if self._fence(p)]
-        else:
-            pendings = self._fence_window(window)
+        pendings = self._fence_window(window)
         if not pendings:
-            _book()
-            return wait_future, snap
+            return snap
         tracer = trace_mod.tracer() if trace_mod.ENABLED else None
         if tracer is not None:
             # Queue-wait spans: enqueue (PlanFuture.trace_t0) -> window
@@ -918,39 +656,31 @@ class PlanApplier:
         # If every in-flight apply finished, drop the stale overlay;
         # else keep verifying against the optimistic view (this is the
         # verify/apply overlap, plan_apply.go:68-85, extended to whole
-        # windows and — on the partitioned path — to the committer
-        # pipeline's bounded queue of windows).
-        if wait_future is not None and wait_future.done():
-            wait_future = None
-            snap = None
-        if not self.sequential:
+        # windows and to the committer pipeline's bounded queue of
+        # windows).
+        with self._stats_lock:
+            dispatch_failed = self._dispatch_failed
+        if dispatch_failed:
+            # A hand-off's dispatch failed AFTER its allocs folded
+            # into the overlay: those folds are phantoms (nothing
+            # entered the log).  Serialize the pipeline out — other
+            # in-flight windows' folds are real and must land before a
+            # fresh snapshot can replace them — and clear the flag only
+            # once DRAINED: windows already queued behind the failure
+            # were verified against the phantoms, and their commit jobs
+            # must still see the flag to refuse them.
+            self._committer.wait_drained(timeout=60.0)
             with self._stats_lock:
-                dispatch_failed = self._dispatch_failed
-            if dispatch_failed:
-                # A hand-off's dispatch failed AFTER its allocs folded
-                # into the overlay: those folds are phantoms (nothing
-                # entered the log).  Serialize the pipeline out —
-                # other in-flight windows' folds are real and must
-                # land before a fresh snapshot can replace them — and
-                # clear the flag only once DRAINED: windows already
-                # queued behind the failure were verified against the
-                # phantoms, and their commit jobs must still see the
-                # flag to refuse them.
-                self._committer.wait_drained(timeout=60.0)
-                with self._stats_lock:
-                    self._dispatch_failed = False
-                snap = None
-            elif snap is not None and self._committer.drained():
-                snap = None
+                self._dispatch_failed = False
+            snap = None
+        elif snap is not None and self._committer.drained():
+            snap = None
         if snap is None:
             snap = OptimisticSnapshot(self.state_fn().snapshot())
 
         t_verify = tracer.now() if tracer is not None else 0.0
-        outcomes = evaluate_window(
-            snap, [p.plan for p in pendings],
-            executor=None if self.sequential else self.components,
-            partition=not self.sequential)
-        info = getattr(outcomes, "info", None)
+        outcomes = evaluate_window(snap, [p.plan for p in pendings])
+        info = outcomes.info
         if tracer is not None:
             # Span taxonomy: one applier.window span per member plan
             # (shared t0/dur, tagged window size + component count, and
@@ -962,8 +692,6 @@ class PlanApplier:
             dur_verify = tracer.now() - t_verify
             # perf_counter epoch -> tracer epoch for component t0s.
             perf_off = time.perf_counter() - tracer.now()
-            dev_span = info.get("device") if info is not None else None
-            dev_recorded = False
             for pending, outcome in zip(pendings, outcomes):
                 if not pending.plan.trace:
                     continue
@@ -991,20 +719,6 @@ class PlanApplier:
                         parent_ctx=wctx,
                         eval_id=pending.plan.eval_id,
                         component=0, fallback=outcome.fallback)
-                if dev_span is not None and dev_span.get("dispatched") \
-                        and not dev_recorded:
-                    # ONE per-window device-dispatch span, beside the
-                    # per-component applier.verify spans, anchored to
-                    # the first traced member's window span.
-                    dev_recorded = True
-                    tracer.record(
-                        "applier.verify.device", t_verify,
-                        dev_span.get("wall", 0.0), parent_ctx=wctx,
-                        window=len(pendings),
-                        pairs=dev_span.get("pairs", 0),
-                        bucket=dev_span.get("bucket", 0),
-                        h2d=dev_span.get("h2d", 0),
-                        d2h=dev_span.get("d2h", 0))
         committers = []  # (pending, result) with state to commit
         fallbacks = 0
         for pending, outcome in zip(pendings, outcomes):
@@ -1020,107 +734,35 @@ class PlanApplier:
             if info is not None:
                 self.components_verified += info["components"]
                 self.component_plans += len(pendings)
-                self._speedup_sum += info["speedup"]
-                self._speedup_n += 1
-                dev = info.get("device")
-                if dev is not None:
-                    if dev.get("dispatched"):
-                        self.device_verify_dispatches += 1
-                        self.device_verify_h2d += dev.get("h2d", 0)
-                        self.device_verify_d2h += dev.get("d2h", 0)
-                        self.device_verify_wall_s += \
-                            dev.get("wall", 0.0)
-                    else:
-                        self.device_verify_fallbacks += 1
         if not committers:
-            _book()
-            return wait_future, snap
-
-        from nomad_tpu.ops.plan_conflict import _accepted_allocs
+            return snap
 
         alloc_lists = [_accepted_allocs(result)
                        for _pending, result in committers]
 
-        if not self.sequential:
-            # Partitioned path: the commit tail — wire encode, raft
-            # dispatch, commit wait, responds — rides the FIFO
-            # committer pipeline, off this thread.  The accepted
-            # portions are ALREADY folded into ``snap``
-            # (evaluate_window mutates the caller-owned overlay in
-            # eval order — its documented contract), so the next
-            # window's verify sees them without any re-fold here.
-            # Bound the pipeline depth (backpressure excluded from the
-            # serialized-section accounting: it IS the verify/apply
-            # overlap), then hand off.
-            t_bp = time.perf_counter()
-            serial += t_bp - t_mark
-            self._committer.wait_depth_below(self.max_inflight_commits,
-                                             timeout=60.0)
-            t_mark = time.perf_counter()
-            with self._stats_lock:
-                # Backpressure wall (the wait above): the controller's
-                # grow signal for max_inflight_commits.
-                self.commit_backpressure_s += t_mark - t_bp
-            try:
-                self._committer.submit(
-                    lambda: self._commit_job(committers, alloc_lists,
-                                             tracer))
-            except Exception:
-                # Committer gone (teardown): commit inline — futures
-                # must always resolve.
-                self._commit_job(committers, alloc_lists, tracer)
-            _book()
-            return None, snap
-
-        # Sequential (baseline) path: one apply in flight at a time —
-        # wait for the previous one and refresh the snapshot before
-        # dispatching (plan_apply.go:100-110; the evaluation above
-        # already ran against the optimistic view), then encode and
-        # dispatch ON this thread, exactly the pre-partition applier.
-        if wait_future is not None:
-            serial += time.perf_counter() - t_mark
-            try:
-                self._wait_commit(wait_future)
-            except Exception:
-                pass
-            wait_future = None
-            t_mark = time.perf_counter()
-        snap = OptimisticSnapshot(self.state_fn().snapshot())
-
-        future, t_apply = self._dispatch_window(committers,
-                                                alloc_lists, tracer)
-        if future is None:
-            # Dispatch failed; every member future already answered.
-            # The overlay folded nothing yet; the fresh snapshot above
-            # is still truthful for the next window.
-            _book()
-            return None, snap
-
-        try:
-            # Optimistically fold every committed plan into the overlay
-            # so the next window verifies against it.
-            for allocs in alloc_lists:
-                snap.upsert_allocs(allocs)
-            wait_future = future
-        except Exception:
-            # Overlay lost: serialize this apply out and start the next
-            # window from a fresh post-commit snapshot.
-            logger.exception("plan applier: overlay fold failed; "
-                             "serializing this apply")
-            try:
-                self._wait_commit(future)
-            except Exception:
-                pass
-            wait_future, snap = None, None
+        # The commit tail — wire encode, raft dispatch, commit wait,
+        # responds — rides the FIFO committer pipeline, off this
+        # thread.  The accepted portions are ALREADY folded into
+        # ``snap`` (evaluate_window mutates the caller-owned overlay in
+        # eval order — its documented contract), so the next window's
+        # verify sees them without any re-fold here.  Bound the
+        # pipeline depth, then hand off.
+        t_bp = time.perf_counter()
+        self._committer.wait_depth_below(self.max_inflight_commits,
+                                         timeout=60.0)
+        with self._stats_lock:
+            # Backpressure wall (the wait above): the controller's
+            # grow signal for max_inflight_commits.
+            self.commit_backpressure_s += time.perf_counter() - t_bp
         try:
             self._committer.submit(
-                lambda: self._await_and_respond(future, committers,
-                                                t_apply, tracer))
+                lambda: self._commit_job(committers, alloc_lists,
+                                         tracer))
         except Exception:
-            self._await_and_respond(future, committers, t_apply,
-                                    tracer)  # degraded but always answers
-        _book()
-        return wait_future, snap
+            # Committer gone (teardown): commit inline — futures
+            # must always resolve.
+            self._commit_job(committers, alloc_lists, tracer)
+        return snap
 
     def _dispatch_window(self, committers, alloc_lists, tracer):
         """Encode one window's accepted portions and dispatch ONE raft
@@ -1169,7 +811,7 @@ class PlanApplier:
             # Flag BEFORE responding: a submitter that observes the
             # error and retries must find the next window already
             # committed to dropping this window's phantom overlay
-            # folds (the partitioned path folds before hand-off).
+            # folds (the verify folds before hand-off).
             with self._stats_lock:
                 self._dispatch_failed = True
                 self.dispatch_failures += 1
@@ -1261,8 +903,7 @@ class PlanApplier:
     def stats(self) -> dict:
         """Group-commit counters: commits, plans carried, mean window
         occupancy, conflict fallbacks, and the partitioned-verify
-        fields (components walked, mean plans per component, mean
-        cross-component concurrency)."""
+        fields (components walked, mean plans per component)."""
         with self._stats_lock:
             commits = self.commits
             plans = self.plans_committed
@@ -1271,18 +912,9 @@ class PlanApplier:
             expired = self.expired_drops
             components = self.components_verified
             comp_plans = self.component_plans
-            speedup_sum = self._speedup_sum
-            speedup_n = self._speedup_n
-            serial_s = self.serial_seconds
-            serial_plans = self.serial_plans
             backpressure_s = self.commit_backpressure_s
             dispatch_failures = self.dispatch_failures
             gather_wall_s = self.gather_wall_s
-            dev_dispatches = self.device_verify_dispatches
-            dev_fallbacks = self.device_verify_fallbacks
-            dev_h2d = self.device_verify_h2d
-            dev_d2h = self.device_verify_d2h
-            dev_wall_s = self.device_verify_wall_s
         return {
             "gather_wall_s": gather_wall_s,
             # The live knob positions (the control plane's actuators
@@ -1303,21 +935,5 @@ class PlanApplier:
             "components": components,
             "component_occupancy":
                 comp_plans / components if components else 0.0,
-            "cross_component_speedup":
-                speedup_sum / speedup_n if speedup_n else 1.0,
-            "serial_seconds": serial_s,
-            "serial_ms_per_plan":
-                serial_s / serial_plans * 1000.0 if serial_plans
-                else 0.0,
-            # Device-verify engine counters (NOMAD_TPU_VERIFY): sharded
-            # window dispatches, device-policy windows that fell back
-            # to the host engine, and the per-window explicit-transfer
-            # odometer deltas those dispatches cost (descriptor h2d +
-            # the three fetched results d2h; never a fleet tensor).
-            "device_verify_dispatches": dev_dispatches,
-            "device_verify_fallbacks": dev_fallbacks,
-            "device_verify_h2d": dev_h2d,
-            "device_verify_d2h": dev_d2h,
-            "device_verify_wall_s": dev_wall_s,
             "windows": windows,
         }

@@ -338,12 +338,6 @@ class Server:
         reg.register("broker", self.eval_broker.stats)
         reg.register("plan_queue", self.plan_queue.stats)
         reg.register("applier", self.plan_applier.stats)
-        # The partitioned verify's component executor: worker count,
-        # windows dispatched, components run, live walks (ISSUE 13 —
-        # an incident reader correlates these with the flight
-        # recorder's per-component stall attribution).
-        reg.register("applier_components",
-                     self.plan_applier.components.stats)
         reg.register("overload", self.overload.stats)
         reg.register("heartbeat", self.heartbeats.stats)
         # fsm.state is REPLACED on snapshot restore: resolve per read.

@@ -14,8 +14,7 @@ the actual cycle rate (the domain objects are reference-acyclic; cycles
 come only from incidental plumbing).  GC stays ENABLED — true cycles are
 still reclaimed, just far less often.
 
-Called from Server startup and from bench.py (applied to both the device
-and sequential paths, so benchmarks stay honest).
+Called from Server startup.
 """
 from __future__ import annotations
 

@@ -302,8 +302,7 @@ def test_leader_failover_mid_storm():
         # Load-tolerant: the two survivors may flap leadership for a
         # while when the host is starving their tickers — wait for a
         # leader that HOLDS, with a generous bar (this soak proves
-        # convergence invariants, not election latency; bench 5e owns
-        # the timing numbers).
+        # convergence invariants, not election latency).
         wait_for_stable_leader(survivors, timeout=60)
 
         # Every raft-committed eval must reach a terminal status on a

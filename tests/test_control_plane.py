@@ -6,9 +6,7 @@ gauges the metrics registry already publishes and adjusts the live
 knobs through railed actuators — with every decision observable
 (control.tick/control.adjust spans, the ``controller`` registry
 provider) and every misbehavior self-indicting (flight dumps on
-reversal and rail saturation).  The convergence proof lives in
-bench.py (5c/5f rerun with 4x-mis-set constants); these tests pin the
-mechanisms.
+reversal and rail saturation).  These tests pin the mechanisms.
 """
 from __future__ import annotations
 
@@ -585,17 +583,40 @@ class TestChaosDepthRetreat:
         the EWMA decays back under the probe band — recovers
         additively, WITHOUT oscillating (reversal count bounded by the
         two phase changes; the hold band between 2x and 4x of the
-        learned floor is what prevents flapping)."""
+        learned floor is what prevents flapping).
+
+        The EWMA is fed what the rig injects, not the wall clock: every
+        sample the runner takes reads a fixed healthy round trip plus
+        the delays injected since the sample before.  On the wall
+        clock a jit compile or a starved core landing in phase A set
+        the learned floor so high that 0.25 s never read as four times
+        it."""
+        import threading
+
         from nomad_tpu.scheduler.executor import executor_override
         from nomad_tpu.scheduler.pipeline import PipelinedEvalRunner
 
         h, jobs = _pipeline_world(8, 40)
+        healthy_s, delay_s = 0.002, 0.25
+        plan = FaultPlan(seed=5).add("device.dispatch", "delay",
+                                     secs=delay_s, count=6)
         with executor_override("device"):
             runner = PipelinedEvalRunner(
                 h.state.snapshot(), h, depth=8,
                 state_refresh=lambda: h.state.snapshot())
-            # Warm the compile/prep caches so the floor the driver
-            # learns is the steady-state RTT, not the first compile.
+            note_wall = runner._note_rtt
+            fires_lock = threading.Lock()
+            fires_seen = [0]
+
+            def note_injected(_wall_seconds):
+                with fires_lock:  # front and drain stage both sample
+                    fires = plan.fire_count("device.dispatch")
+                    fresh = fires - fires_seen[0]
+                    fires_seen[0] = fires
+                note_wall(healthy_s + delay_s * fresh)
+
+            runner._note_rtt = note_injected
+            # Warm the compile/prep caches.
             runner.process([_mk_eval(j) for j in jobs[:4]])
             with runner._count_lock:
                 runner._rtt_ewma = 0.0  # drop warmup samples
@@ -614,8 +635,6 @@ class TestChaosDepthRetreat:
             assert runner.depth >= 8 or runner.depth >= depth_seen[0]
 
             # Phase B (chaos): seeded dispatch delays, every dispatch.
-            plan = FaultPlan(seed=5).add("device.dispatch", "delay",
-                                         secs=0.25, count=6)
             with faultinject.injected(plan):
                 round_trip(jobs[12:15])
                 round_trip(jobs[15:18])

@@ -4,9 +4,7 @@ The NOMAD_TPU_EXECUTOR override (scheduler/executor.py) only selects
 WHICH engine runs the placement kernels — numpy twins or the jit
 kernels — never what is planned.  This suite forces a micro eval
 stream through PipelinedEvalRunner both ways on the CPU backend and
-asserts identical placed counts and scores, gating the bench's
-`4_device_pipelined` row (which runs the same code with the device
-forced) on every tier-1 run.
+asserts identical placed counts and scores on every tier-1 run.
 """
 from __future__ import annotations
 

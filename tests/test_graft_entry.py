@@ -37,10 +37,10 @@ def test_dryrun_multichip_8():
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-4000:])
     assert "dryrun_multichip(8)" in r.stdout
     assert "parity" in r.stdout
-    # The forced-device pipeline (NOMAD_TPU_EXECUTOR=device twin of the
-    # bench's 4_device_pipelined row) must really dispatch on the mesh
-    # platform — AND, with sharding first-class, every one of those
-    # dispatches must have ridden the node-axis mesh.
+    # The forced-device pipeline (NOMAD_TPU_EXECUTOR=device) must
+    # really dispatch on the mesh platform — AND, with sharding
+    # first-class, every one of those dispatches must have ridden the
+    # node-axis mesh.
     m = re.search(r"executor=device device_fraction=([0-9.]+) "
                   r"sharded_dispatches=(\d+) placed=(\d+)", r.stdout)
     assert m, r.stdout[-2000:]

@@ -17,10 +17,9 @@ Four layers:
    spanning agent edge -> broker -> scheduler stages -> window verify
    -> raft apply -> store upsert (the ISSUE acceptance bar).
 
-Plus the tier-1 tracing-overhead assertion: the bench asserts <=5% on
-the config-4 stream; this suite asserts a generous structural bound on
-a small stream so a hot-path instrumentation regression fails tier-1,
-not just the nightly bench.
+Plus the tier-1 tracing-overhead assertion: a generous structural
+bound on a small stream, so a hot-path instrumentation regression
+fails tier-1.
 """
 from __future__ import annotations
 
@@ -312,47 +311,74 @@ class TestFlightRecorder:
         assert not any(th.name == "flight-stall-watchdog"
                        for th in threading.enumerate())
 
-    def test_stall_guard_extra_fn_names_the_slow_component(self,
-                                                           tmp_path):
-        """ISSUE 13 satellite: the applier.window stall guard's
-        incident dump carries the component executor's per-component
-        attribution — a wedged window names WHAT it was verifying, not
-        just that it wedged."""
-        from nomad_tpu.server.plan_apply import ComponentExecutor
+    def test_wedged_applier_window_incident_names_its_evals(
+            self, tmp_path, monkeypatch):
+        """The applier.window stall guard's incident dump says WHAT the
+        applier was verifying when the window wedged — the window's
+        eval ids — not just that it wedged."""
+        import nomad_tpu.mock as mock
+        import nomad_tpu.ops.plan_conflict as plan_conflict
+        from nomad_tpu.server.eval_broker import EvalBroker
+        from nomad_tpu.server.fsm import NomadFSM
+        from nomad_tpu.server.plan_apply import PlanApplier
+        from nomad_tpu.server.plan_queue import PlanQueue
+        from nomad_tpu.server.raft import InmemRaft
+        from nomad_tpu.structs import (Allocation, Evaluation, Plan,
+                                       Resources, codec, generate_uuid)
 
-        executor = ComponentExecutor(workers=1)
+        broker = EvalBroker()
+        broker.set_enabled(True)
+        fsm = NomadFSM(eval_broker=broker)
+        raft = InmemRaft(fsm)
+        queue = PlanQueue()
+        queue.set_enabled(True)
+        applier = PlanApplier(queue, broker, raft, lambda: fsm.state)
+        applier.WINDOW_STALL_S = 0.05
+        node = mock.node()
+        raft.apply(codec.encode(codec.NODE_REGISTER_REQUEST,
+                                {"node": node.to_dict()})).wait(5.0)
+        ev = Evaluation(id=generate_uuid(), priority=50, type="service",
+                        job_id=generate_uuid(), status="pending",
+                        triggered_by="job-register")
+        raft.apply(codec.encode(codec.EVAL_UPDATE_REQUEST,
+                                {"evals": [ev.to_dict()]})).wait(5.0)
+        _got, token = broker.dequeue(["service"], timeout=2.0)
+        plan = Plan(eval_id=ev.id, eval_token=token, priority=50)
+        plan.append_alloc(Allocation(
+            id=generate_uuid(), node_id=node.id, job_id=ev.job_id,
+            task_group="web", resources=Resources(cpu=100, memory_mb=64),
+            desired_status="run", client_status="pending"))
+
+        # Wedge the verify itself: the window stays open until released.
         started = threading.Event()
         release = threading.Event()
+        verify = plan_conflict.evaluate_window
 
-        def slow():
+        def wedged(snap, plans):
             started.set()
             release.wait(10.0)
-            return []
+            return verify(snap, plans)
 
-        runner = threading.Thread(
-            target=lambda: executor.run_components(
-                [slow, lambda: []],
-                descs=[{"component": 0, "plans": 7,
-                        "eval_ids": ["ev-stuck"]}, None]))
+        monkeypatch.setattr(plan_conflict, "evaluate_window", wedged)
         with flight.installed(str(tmp_path)) as rec:
-            runner.start()
+            future = queue.enqueue(plan)
+            applier.start()
             try:
                 assert started.wait(5.0)
-                with flight.guard("applier.window", timeout=0.05,
-                                  extra_fn=executor.active):
-                    wait_until(lambda: rec.incidents(), timeout=5.0)
+                wait_until(lambda: rec.incidents(), timeout=5.0)
             finally:
                 release.set()
-                runner.join(5.0)
-                executor.stop()
+                assert future.wait(5.0).alloc_index > 0
+                queue.set_enabled(False)
+                applier.shutdown(5.0)
+                broker.shutdown()
             names = rec.incidents()
             assert len(names) == 1 and "applier.window" in names[0]
             with open(os.path.join(str(tmp_path), names[0])) as fh:
                 doc = json.load(fh)
-            verifying = doc["extra"]["verifying"]
-            assert any("ev-stuck" in str(v.get("eval_ids"))
-                       for v in verifying), \
-                "the incident must name the slow component"
+            assert doc["extra"]["verifying"] == {
+                "plans": 1, "eval_ids": [ev.id]}, \
+                "the incident must name the wedged window's evals"
             assert "stalled_for_s" in doc["extra"]
 
     def test_breaker_open_trips(self, tmp_path):
@@ -869,7 +895,7 @@ class TestTracingOverhead:
         return best
 
     def test_tracing_on_overhead_bounded(self):
-        """The tier-1 tripwire behind bench.py's 5% assertion: on a
+        """The tier-1 tracing-overhead tripwire: on a
         small stream the tracing-ON best-of-5 must stay within 50% of
         OFF (generous — CI noise — but a hot path that started
         allocating per-span dicts with tracing OFF, or an O(n) tracer

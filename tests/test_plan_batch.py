@@ -93,15 +93,11 @@ def sequential_apply(store: StateStore, plans: list,
     return results
 
 
-def grouped_apply(store: StateStore, plans: list,
-                  base_index: int, executor=None,
-                  partition: bool = True) -> list:
-    """The group-commit path: one window verify (partitioned by
-    default; optionally concurrent via a ComponentExecutor, or the
-    flat ``partition=False`` walk), one batched upsert, same per-plan
-    index sequence."""
-    outcomes = evaluate_window(store, plans, executor=executor,
-                               partition=partition)
+def grouped_outcomes(store: StateStore, plans: list,
+                     base_index: int):
+    """The group-commit path: one window verify, one batched upsert,
+    same per-plan index sequence.  Returns the window's outcomes."""
+    outcomes = evaluate_window(store, plans)
     items = []
     for i, outcome in enumerate(outcomes):
         result = outcome.result
@@ -115,7 +111,12 @@ def grouped_apply(store: StateStore, plans: list,
             items.append((base_index + i, allocs))
     if items:
         store.upsert_allocs_batched(items)
-    return [o.result for o in outcomes]
+    return outcomes
+
+
+def grouped_apply(store: StateStore, plans: list,
+                  base_index: int) -> list:
+    return [o.result for o in grouped_outcomes(store, plans, base_index)]
 
 
 def result_key(result: PlanResult) -> tuple:
@@ -246,21 +247,6 @@ class TestWindowSemantics:
 # 2. sequential parity (the acceptance bar)
 # ---------------------------------------------------------------------------
 
-def _parity_modes():
-    """The grouped paths the rigs pin against sequential truth: the
-    default partitioned walk, the partitioned walk on a REAL concurrent
-    ComponentExecutor, and the flat pre-partition walk (the bench's
-    sequential-applier baseline)."""
-    from nomad_tpu.server.plan_apply import ComponentExecutor
-
-    executor = ComponentExecutor(workers=2)
-    return [
-        ("partitioned", None, True),
-        ("concurrent", executor, True),
-        ("flat", None, False),
-    ], executor
-
-
 def _stamp_adversarial_deadlines(plans) -> None:
     """Deadlines DESCENDING by window position, so the deadline-aware
     component scheduler verifies components in roughly REVERSE window
@@ -279,9 +265,9 @@ class TestSequentialParity:
         on a shared node, a window port collision, cross-plan
         over-commit, all_at_once whole-rejection, evict+refill, an
         in-place update, and failed allocs riding a rejected plan —
-        replayed through the partitioned, concurrent-executor and flat
-        grouped paths against one sequential truth, with adversarial
-        deadlines so component scheduling order != eval order."""
+        replayed through the window verify against the sequential
+        truth, with adversarial deadlines so component scheduling
+        order != eval order."""
         nodes = [mock.node(i) for i in range(6)]
 
         def setup(store):
@@ -325,17 +311,11 @@ class TestSequentialParity:
 
         s_seq = world()
         res_seq = sequential_apply(s_seq, plans, 2000)
-        modes, executor = _parity_modes()
-        try:
-            for name, ex, part in modes:
-                s_grp = world()
-                res_grp = grouped_apply(s_grp, plans, 2000,
-                                        executor=ex, partition=part)
-                assert [result_key(r) for r in res_seq] == \
-                    [result_key(r) for r in res_grp], name
-                assert store_image(s_seq) == store_image(s_grp), name
-        finally:
-            executor.stop()
+        s_grp = world()
+        res_grp = grouped_apply(s_grp, plans, 2000)
+        assert [result_key(r) for r in res_seq] == \
+            [result_key(r) for r in res_grp]
+        assert store_image(s_seq) == store_image(s_grp)
         # Sanity on the interesting verdicts.
         assert result_key(res_seq[2])[1] == {}      # port collision
         assert result_key(res_seq[4])[1] == {}      # over-commit
@@ -345,287 +325,190 @@ class TestSequentialParity:
     def test_recorded_contended_storm_stream_parity(self):
         """Record a REAL contended plan stream (fused storm through the
         verifying planner), then replay it onto fresh worlds through
-        every grouped path — partitioned, concurrent-executor, flat —
-        against one sequential truth."""
-        from nomad_tpu.scheduler import Harness
-        from nomad_tpu.scheduler.batch import BatchEvalRunner
-        from nomad_tpu.scheduler.harness import VerifyingPlanner
-        from nomad_tpu.structs import (EVAL_TRIGGER_JOB_REGISTER,
-                                       Task, TaskGroup)
-
-        nodes = [mock.node(i) for i in range(8)]
-        h = Harness()
-        for n in nodes:
-            h.state.upsert_node(h.next_index(), n.copy())
-        jobs = []
-        for j in range(6):
-            job = mock.job()
-            job.task_groups = [
-                TaskGroup(name=f"tg-{g}", count=2,
-                          tasks=[Task(name="web", driver="exec",
-                                      resources=Resources(
-                                          cpu=600, memory_mb=256,
-                                          networks=[NetworkResource(
-                                              mbits=5,
-                                              dynamic_ports=["http"])]))])
-                for g in range(4)]
-            h.state.upsert_job(h.next_index(), job)
-            jobs.append(job)
-        h.planner = VerifyingPlanner(h)
-        evals = [Evaluation(id=generate_uuid(), priority=50,
-                            type=j.type,
-                            triggered_by=EVAL_TRIGGER_JOB_REGISTER,
-                            job_id=j.id) for j in jobs]
-        BatchEvalRunner(h.state.snapshot(), h,
-                        state_refresh=h.snapshot).process(evals)
-        plans = h.plans
-        assert plans, "storm recorded no plans"
-        _stamp_adversarial_deadlines(plans)
-
-        def world():
-            store = StateStore()
-            for i, n in enumerate(nodes):
-                store.upsert_node(1000 + i, n.copy())
-            return store
-
+        the window verify against the sequential truth."""
+        world, plans = _recorded_storm()
         s_seq = world()
         res_seq = sequential_apply(s_seq, plans, 5000)
-        modes, executor = _parity_modes()
-        try:
-            for name, ex, part in modes:
-                s_grp = world()
-                res_grp = grouped_apply(s_grp, plans, 5000,
-                                        executor=ex, partition=part)
-                assert [result_key(r) for r in res_seq] == \
-                    [result_key(r) for r in res_grp], name
-                assert store_image(s_seq) == store_image(s_grp), name
-        finally:
-            executor.stop()
-
-
-# ---------------------------------------------------------------------------
-# 2b. host vs device verify-engine parity (NOMAD_TPU_VERIFY)
-# ---------------------------------------------------------------------------
-
-def device_grouped_apply(store: StateStore, plans: list,
-                         base_index: int) -> list:
-    """grouped_apply through the DEVICE verify engine, with the
-    cold-start warm-up (the first window after a mirror rebuild always
-    falls back — the window-lease rule) and a hard assertion that the
-    replayed window actually dispatched: a silent fallback would test
-    host against host and prove nothing."""
-    from nomad_tpu.ops.verify_policy import verify_override
-
-    with verify_override("device"):
-        evaluate_window(store, plans)          # warm the lease
-        probe = evaluate_window(store, plans)  # store untouched
-        dev = probe.info["device"] if probe.info else None
-        assert dev is not None and dev["dispatched"], \
-            f"device verify did not dispatch: {dev}"
-        return grouped_apply(store, plans, base_index)
-
-
-class TestDeviceVerifyParity:
-    """The device engine's acceptance bar: verdict stream, alloc set
-    and store fingerprint byte-identical to the host engine (and to
-    sequential truth) on every rig, with the dispatch PROVEN."""
-
-    def test_recorded_storm_host_vs_device(self):
-        """The recorded contended storm (same recipe as the grouped
-        parity rig) replayed through the host engine and through a
-        dispatched device window, byte-compared."""
-        from nomad_tpu.ops.verify_policy import verify_override
-        from nomad_tpu.scheduler import Harness
-        from nomad_tpu.scheduler.batch import BatchEvalRunner
-        from nomad_tpu.scheduler.harness import VerifyingPlanner
-        from nomad_tpu.structs import (EVAL_TRIGGER_JOB_REGISTER,
-                                       Task, TaskGroup)
-
-        nodes = [mock.node(i) for i in range(8)]
-        h = Harness()
-        for n in nodes:
-            h.state.upsert_node(h.next_index(), n.copy())
-        jobs = []
-        for j in range(6):
-            job = mock.job()
-            job.task_groups = [
-                TaskGroup(name=f"tg-{g}", count=2,
-                          tasks=[Task(name="web", driver="exec",
-                                      resources=Resources(
-                                          cpu=600, memory_mb=256,
-                                          networks=[NetworkResource(
-                                              mbits=5,
-                                              dynamic_ports=["http"])]))])
-                for g in range(4)]
-            h.state.upsert_job(h.next_index(), job)
-            jobs.append(job)
-        h.planner = VerifyingPlanner(h)
-        evals = [Evaluation(id=generate_uuid(), priority=50,
-                            type=j.type,
-                            triggered_by=EVAL_TRIGGER_JOB_REGISTER,
-                            job_id=j.id) for j in jobs]
-        BatchEvalRunner(h.state.snapshot(), h,
-                        state_refresh=h.snapshot).process(evals)
-        plans = h.plans
-        assert plans, "storm recorded no plans"
-        _stamp_adversarial_deadlines(plans)
-
-        def world():
-            store = StateStore()
-            for i, n in enumerate(nodes):
-                store.upsert_node(1000 + i, n.copy())
-            return store
-
-        s_seq = world()
-        res_seq = sequential_apply(s_seq, plans, 5000)
-        s_host = world()
-        with verify_override("host"):
-            res_host = grouped_apply(s_host, plans, 5000)
-        s_dev = world()
-        res_dev = device_grouped_apply(s_dev, plans, 5000)
+        s_grp = world()
+        res_grp = grouped_apply(s_grp, plans, 5000)
         assert [result_key(r) for r in res_seq] == \
-            [result_key(r) for r in res_host] == \
-            [result_key(r) for r in res_dev]
-        assert store_image(s_seq) == store_image(s_host) \
-            == store_image(s_dev)
+            [result_key(r) for r in res_grp]
+        assert store_image(s_seq) == store_image(s_grp)
 
-    @pytest.mark.parametrize("n_nodes", [8, 24, 64])
-    def test_seeded_random_windows_across_fleet_sizes(self, n_nodes):
-        """Seeded random contended windows at three fleet sizes —
-        including evict-frees-capacity and port-collision shapes — each
-        replayed sequentially, through the host engine, and through a
-        dispatched device window; all three byte-compared."""
-        import random
 
-        from nomad_tpu.ops.verify_policy import verify_override
+def _recorded_storm() -> tuple:
+    """(world, plans): the plan stream of a real contended storm — six
+    jobs of 4 groups x 2 copies fused over 8 nodes through the
+    verifying planner — with adversarial deadlines, and a factory of
+    fresh worlds to replay it onto."""
+    from nomad_tpu.scheduler import Harness
+    from nomad_tpu.scheduler.batch import BatchEvalRunner
+    from nomad_tpu.scheduler.harness import VerifyingPlanner
+    from nomad_tpu.structs import (EVAL_TRIGGER_JOB_REGISTER,
+                                   Task, TaskGroup)
 
-        rng = random.Random(171_000 + n_nodes)
-        nodes = [mock.node(i) for i in range(n_nodes)]
-        # Standing allocs: every third node starts near-full so random
-        # refills contend, and their evictions free real capacity.
-        existing = [make_alloc(nodes[i], cpu=FREE_CPU - 500)
-                    for i in range(0, n_nodes, 3)]
+    nodes = [mock.node(i) for i in range(8)]
+    h = Harness()
+    for n in nodes:
+        h.state.upsert_node(h.next_index(), n.copy())
+    jobs = []
+    for j in range(6):
+        job = mock.job()
+        job.task_groups = [
+            TaskGroup(name=f"tg-{g}", count=2,
+                      tasks=[Task(name="web", driver="exec",
+                                  resources=Resources(
+                                      cpu=600, memory_mb=256,
+                                      networks=[NetworkResource(
+                                          mbits=5,
+                                          dynamic_ports=["http"])]))])
+            for g in range(4)]
+        h.state.upsert_job(h.next_index(), job)
+        jobs.append(job)
+    h.planner = VerifyingPlanner(h)
+    evals = [Evaluation(id=generate_uuid(), priority=50,
+                        type=j.type,
+                        triggered_by=EVAL_TRIGGER_JOB_REGISTER,
+                        job_id=j.id) for j in jobs]
+    BatchEvalRunner(h.state.snapshot(), h,
+                    state_refresh=h.snapshot).process(evals)
+    plans = h.plans
+    assert plans, "storm recorded no plans"
+    _stamp_adversarial_deadlines(plans)
 
-        def world():
-            store = StateStore()
-            for i, n in enumerate(nodes):
-                store.upsert_node(1000 + i, n)
-            store.upsert_allocs(1500, existing)
-            return store
+    def world():
+        store = StateStore()
+        for i, n in enumerate(nodes):
+            store.upsert_node(1000 + i, n.copy())
+        return store
 
-        plans = []
-        hot = nodes[:max(2, n_nodes // 4)]  # contention focus
-        for _ in range(24):
-            kind = rng.random()
-            if kind < 0.25:
-                # Evict-frees-capacity: stop a standing alloc, refill
-                # the node to the brim in a LATER plan.
-                victim = rng.choice(existing)
-                evict = Plan(eval_id=generate_uuid())
-                evict.append_update(victim,
-                                    ALLOC_DESIRED_STATUS_STOP, "churn")
-                plans.append(evict)
-                node = next(n for n in nodes if n.id == victim.node_id)
-                plans.append(place_plan(make_alloc(node, cpu=FREE_CPU)))
-            elif kind < 0.45:
-                # Port collision: two claims on one hot node, one
-                # shared static port — the later one must reject.
-                node = rng.choice(hot)
-                port = 8000 + rng.randrange(4)
-                plans.append(place_plan(net_alloc(node, ports=[port])))
-                plans.append(place_plan(net_alloc(node, ports=[port])))
-            elif kind < 0.7:
-                # Over-commit pressure on a hot node.
-                node = rng.choice(hot)
-                plans.append(place_plan(make_alloc(
-                    node, cpu=rng.choice((500, 1500, FREE_CPU)))))
-            else:
-                # Clean placement on a random node.
-                node = rng.choice(nodes)
-                plans.append(place_plan(make_alloc(
-                    node, cpu=rng.choice((100, 400, 900)))))
-        _stamp_adversarial_deadlines(plans)
+    return world, plans
 
-        s_seq = world()
-        res_seq = sequential_apply(s_seq, plans, 5000)
-        s_host = world()
-        with verify_override("host"):
-            res_host = grouped_apply(s_host, plans, 5000)
-        s_dev = world()
-        res_dev = device_grouped_apply(s_dev, plans, 5000)
-        assert [result_key(r) for r in res_seq] == \
-            [result_key(r) for r in res_host] == \
-            [result_key(r) for r in res_dev]
-        assert store_image(s_seq) == store_image(s_host) \
-            == store_image(s_dev)
 
-    def test_overlay_fold_is_plain_float32_adds(self):
-        """The window kernel's verdicts are byte-identical to the host
-        walk only while every sum is a plain float32 add.  A matmul
-        runs as a bf16 pass on a TPU at default precision — it would
-        read an ask of 1299 MHz as 1296 — and the CPU cannot show that
-        rounding, so the structure is pinned: no dot in the traced
-        kernel, and asks no bf16 pass could carry fold exactly."""
-        import jax
-        import numpy as np
+def _seeded_random_window(n_nodes: int) -> tuple:
+    """(world, plans): a seeded random contended window over
+    ``n_nodes`` — evict-frees-capacity, port-collision, over-commit and
+    clean shapes mixed."""
+    import random
 
-        from nomad_tpu.parallel.mesh import _window_verify_jit
+    rng = random.Random(171_000 + n_nodes)
+    nodes = [mock.node(i) for i in range(n_nodes)]
+    # Standing allocs: every third node starts near-full so random
+    # refills contend, and their evictions free real capacity.
+    existing = [make_alloc(nodes[i], cpu=FREE_CPU - 500)
+                for i in range(0, n_nodes, 3)]
 
-        n, bucket = 8, 8
-        capacity = np.zeros((n, 6), dtype=np.float32)
-        capacity[:, :4] = 3900
-        zeros = np.zeros((n, 6), dtype=np.float32)
-        # Node 0: 1299 + 1299 + 1303 = 3901 (the third must not fit);
-        # node 1: 1301 + 1301 + 1298 = 3900 (the third fits exactly).
-        asks = [(0, 1299), (1, 1301), (0, 1299), (1, 1301),
-                (0, 1303), (1, 1298)]
-        pair_ni = np.zeros(bucket, dtype=np.int32)
-        row_vec = np.zeros((bucket, 4), dtype=np.float32)
-        for k, (ni, cpu) in enumerate(asks):
-            pair_ni[k] = ni
-            row_vec[k, 0] = cpu
-        order = np.arange(bucket, dtype=np.int32)
-        comp = np.zeros(bucket, dtype=np.int32)
-        seq_ni = pair_ni.copy()
-        seq_ni[len(asks):] = -1
-        args = (capacity, zeros, zeros, pair_ni, order, row_vec, seq_ni,
-                row_vec, order, comp, order, comp,
-                np.zeros((bucket, 4), dtype=np.float32))
-        assert "dot_general" not in str(
-            jax.make_jaxpr(_window_verify_jit)(*args))
-        used, _caps, fits = _window_verify_jit(*args)
-        assert np.asarray(used)[:6, 0].tolist() == \
-            [float(cpu) for _ni, cpu in asks]
-        assert np.asarray(fits)[:6].tolist() == \
-            [True, True, True, True, False, True]
-
-    def test_device_info_and_fallback_taxonomy(self):
-        """The window info record: host policy reports no device entry,
-        a cold device window reports the lease-miss fallback, a warmed
-        one reports the dispatch with its counted transfers."""
-        from nomad_tpu.ops.verify_policy import verify_override
-
-        nodes = [mock.node(i) for i in range(8)]
+    def world():
         store = StateStore()
         for i, n in enumerate(nodes):
             store.upsert_node(1000 + i, n)
-        plans = [place_plan(make_alloc(n, cpu=100)) for n in nodes]
+        store.upsert_allocs(1500, existing)
+        return store
 
-        with verify_override("host"):
-            out = evaluate_window(store, plans)
-            assert out.info["device"] is None
+    plans = []
+    hot = nodes[:max(2, n_nodes // 4)]  # contention focus
+    for _ in range(24):
+        kind = rng.random()
+        if kind < 0.25:
+            # Evict-frees-capacity: stop a standing alloc, refill
+            # the node to the brim in a LATER plan.
+            victim = rng.choice(existing)
+            evict = Plan(eval_id=generate_uuid())
+            evict.append_update(victim,
+                                ALLOC_DESIRED_STATUS_STOP, "churn")
+            plans.append(evict)
+            node = next(n for n in nodes if n.id == victim.node_id)
+            plans.append(place_plan(make_alloc(node, cpu=FREE_CPU)))
+        elif kind < 0.45:
+            # Port collision: two claims on one hot node, one
+            # shared static port — the later one must reject.
+            node = rng.choice(hot)
+            port = 8000 + rng.randrange(4)
+            plans.append(place_plan(net_alloc(node, ports=[port])))
+            plans.append(place_plan(net_alloc(node, ports=[port])))
+        elif kind < 0.7:
+            # Over-commit pressure on a hot node.
+            node = rng.choice(hot)
+            plans.append(place_plan(make_alloc(
+                node, cpu=rng.choice((500, 1500, FREE_CPU)))))
+        else:
+            # Clean placement on a random node.
+            node = rng.choice(nodes)
+            plans.append(place_plan(make_alloc(
+                node, cpu=rng.choice((100, 400, 900)))))
+    _stamp_adversarial_deadlines(plans)
+    return world, plans
 
-        with verify_override("device"):
-            cold = evaluate_window(store, plans)
-            dev = cold.info["device"]
-            if not dev["dispatched"]:  # twins may be resident already
-                assert dev["fallback"] in ("lease-miss", "capres-miss")
-            warm = evaluate_window(store, plans)
-            dev = warm.info["device"]
-            assert dev["dispatched"] and dev["fallback"] is None
-            assert dev["pairs"] == len(plans)
-            assert dev["d2h"] == 3  # used/caps/fits through fetch_host
-            assert dev["bucket"] >= dev["pairs"]
+
+# ---------------------------------------------------------------------------
+# 2b. the window verify's two engines: the array pass and the all-walk path
+# ---------------------------------------------------------------------------
+
+class TestWindowEngineParity:
+    """The same windows through the array pass (the size gate lifted),
+    through the walk of every claim (the small windows they are) and
+    through sequential ``evaluate_plan`` + commit: verdict stream,
+    alloc set and store image equal."""
+
+    @pytest.mark.parametrize("window", [
+        _recorded_storm,
+        lambda: _seeded_random_window(8),
+        lambda: _seeded_random_window(24),
+        lambda: _seeded_random_window(64),
+    ], ids=["recorded-storm", "seeded-8", "seeded-24", "seeded-64"])
+    def test_pass_walk_and_sequential_agree(self, window, monkeypatch):
+        import nomad_tpu.ops.plan_conflict as plan_conflict
+
+        world, plans = window()
+        s_seq = world()
+        res_seq = sequential_apply(s_seq, plans, 5000)
+
+        s_walk = world()
+        out_walk = grouped_outcomes(s_walk, plans, 5000)
+        assert all(o.walked == o.claims for o in out_walk), \
+            "a window under the gate walks every claim"
+
+        monkeypatch.setattr(plan_conflict, "ARRAY_PASS_MIN_CLAIMS", 0)
+        s_pass = world()
+        out_pass = grouped_outcomes(s_pass, plans, 5000)
+        assert sum(o.walked for o in out_pass) < \
+            sum(o.claims for o in out_pass), \
+            "the array pass decided nothing: walk against walk"
+
+        assert [result_key(r) for r in res_seq] == \
+            [result_key(o.result) for o in out_walk] == \
+            [result_key(o.result) for o in out_pass]
+        assert store_image(s_seq) == store_image(s_walk) \
+            == store_image(s_pass)
+
+    def test_prefix_sums_are_exact_where_a_bf16_pass_is_not(
+            self, monkeypatch):
+        """Free cpu per node is 3900 MHz.  Node X takes 1301 + 1301 +
+        1298 = 3900: the third fits exactly.  Node Y takes 1299 + 1299
+        + 1303 = 3901: the third must be rejected.  A sum carried in
+        fewer bits (bf16 reads 1301 as 1304, 1299 as 1296) gets both
+        wrong; the pass's float64 prefix sums and the walk's float adds
+        get both right."""
+        import nomad_tpu.ops.plan_conflict as plan_conflict
+
+        nodes = [mock.node(i) for i in range(32)]
+        asks = []
+        for cpus in ((1301, 1299), (1301, 1299), (1298, 1303)):
+            for pair in range(0, 32, 2):
+                asks += [(nodes[pair], cpus[0]), (nodes[pair + 1], cpus[1])]
+        plans = [place_plan(make_alloc(node, cpu=cpu, mem=517))
+                 for node, cpu in asks]
+        want = [True] * 64 + [True, False] * 16
+        for min_claims in (0, plan_conflict.ARRAY_PASS_MIN_CLAIMS):
+            monkeypatch.setattr(plan_conflict, "ARRAY_PASS_MIN_CLAIMS",
+                                min_claims)
+            outcomes = evaluate_window(_store(nodes), plans)
+            assert [bool(o.result.node_allocation)
+                    for o in outcomes] == want, min_claims
+            if min_claims == 0:
+                # The exact fits stayed with the pass; only the nodes
+                # with a rejection walked.
+                assert sum(o.walked for o in outcomes) == 16 * 3
 
 
 # ---------------------------------------------------------------------------
@@ -674,7 +557,7 @@ class TestApplierWindow:
                    for _ in range(4)]
         window = [queue.dequeue(0)] + queue.drain_pending(63)
         assert len(window) == 4
-        applier._apply_window(window, None, None)
+        applier._apply_window(window, None)
 
         results = [f.wait(5.0) for f in futures]
         # ONE raft apply carried the whole window...
@@ -705,7 +588,7 @@ class TestApplierWindow:
         f2 = queue.enqueue(_outstanding_plan(broker, fsm, raft, node,
                                              cpu=1000))
         window = [queue.dequeue(0)] + queue.drain_pending(63)
-        applier._apply_window(window, None, None)
+        applier._apply_window(window, None)
         r1 = f1.wait(5.0)
         r2 = f2.wait(5.0)
         assert r1.node_allocation and r1.alloc_index > 0
@@ -723,7 +606,7 @@ class TestApplierWindow:
         applied.clear()
         f = queue.enqueue(_outstanding_plan(broker, fsm, raft, node))
         window = [queue.dequeue(0)] + queue.drain_pending(63)
-        applier._apply_window(window, None, None)
+        applier._apply_window(window, None)
         assert f.wait(5.0).alloc_index > 0
         plan_applies = [t for t in applied
                         if t in (codec.ALLOC_UPDATE_REQUEST,
@@ -741,7 +624,7 @@ class TestApplierWindow:
         f_bad = queue.enqueue(bad)
         f_good = queue.enqueue(good)
         window = [queue.dequeue(0)] + queue.drain_pending(63)
-        applier._apply_window(window, None, None)
+        applier._apply_window(window, None)
         with pytest.raises(RuntimeError, match="not outstanding"):
             f_bad.wait(5.0)
         assert f_good.wait(5.0).alloc_index > 0
@@ -764,7 +647,7 @@ class TestApplierWindow:
         with faultinject.injected(fplan):
             futures = [queue.enqueue(p) for p in plans]
             window = [queue.dequeue(0)] + queue.drain_pending(63)
-            applier._apply_window(window, None, None)
+            applier._apply_window(window, None)
             errs = 0
             for f in futures:
                 with pytest.raises(Exception):
@@ -778,7 +661,7 @@ class TestApplierWindow:
             # window commits exactly once — no double placement.
             futures = [queue.enqueue(p) for p in plans]
             window = [queue.dequeue(0)] + queue.drain_pending(63)
-            applier._apply_window(window, None, None)
+            applier._apply_window(window, None)
             for f in futures:
                 assert f.wait(5.0).alloc_index > 0
         assert len(fsm.state.allocs_by_node(node.id)) == 3
@@ -840,7 +723,7 @@ class TestDrainPending:
     def test_deadline_promotion_pulls_near_deadline_plan_forward(self):
         """A LOW-priority plan whose deadline falls inside the drain
         horizon jumps the high-priority stream — without promotion it
-        would sit past the window cut until _fence expires it."""
+        would sit past the window cut until the fence expires it."""
         import time as _time
 
         q = PlanQueue()
@@ -1011,43 +894,55 @@ class TestPartitioner:
         assert outcomes.info["sizes"] == [1, 1, 1, 1]
         assert {o.component for o in outcomes} == {0, 1, 2, 3}
 
-    def test_big_component_rides_the_executor(self):
-        """A window with a real conflict cluster (>= the concurrency
-        threshold) dispatches to the ComponentExecutor, and verdicts
-        stay byte-identical to sequential application."""
-        from nomad_tpu.ops.plan_conflict import MIN_CONCURRENT_COMPONENT
-        from nomad_tpu.server.plan_apply import ComponentExecutor
+    def test_near_deadline_component_walks_first(self):
+        """A window of several components, the LAST of them holding a
+        plan with a near deadline: that component walks first, and the
+        verdicts are sequential application's all the same."""
+        import time as _time
 
         shared = mock.node()
         others = [mock.node(i + 1) for i in range(4)]
+        urgent = mock.node(9)
 
         def world():
             store = StateStore()
-            store.upsert_node(1000, shared)
-            for i, n in enumerate(others):
-                store.upsert_node(1001 + i, n)
+            for i, n in enumerate([shared, *others, urgent]):
+                store.upsert_node(1000 + i, n)
             return store
 
-        plans = [place_plan(make_alloc(shared, cpu=300))
-                 for _ in range(MIN_CONCURRENT_COMPONENT)]
+        # Component 0: a conflict cluster over-committing one node;
+        # components 1-4: lone plans; component 5: two plans on the
+        # urgent node, the second of which must be rejected.
+        plans = [place_plan(make_alloc(shared, cpu=600))
+                 for _ in range(8)]
         plans += [place_plan(make_alloc(n)) for n in others]
+        plans += [place_plan(make_alloc(urgent, cpu=FREE_CPU)),
+                  place_plan(make_alloc(urgent, cpu=500))]
+        now = _time.monotonic()
+        for plan in plans:
+            plan.deadline = now + 100.0
+        plans[-1].deadline = now + 1.0
 
         s_seq = world()
         res_seq = sequential_apply(s_seq, plans, 3000)
-        executor = ComponentExecutor(workers=2)
-        try:
-            s_grp = world()
-            res_grp = grouped_apply(s_grp, plans, 3000,
-                                    executor=executor)
-            assert [result_key(r) for r in res_seq] == \
-                [result_key(r) for r in res_grp]
-            assert store_image(s_seq) == store_image(s_grp)
-            stats = executor.stats()
-            assert stats["batches"] >= 1, \
-                "a >= threshold component must ride the executor"
-            assert stats["components_run"] >= 5
-        finally:
-            executor.stop()
+        s_grp = world()
+        outcomes = grouped_outcomes(s_grp, plans, 3000)
+        assert [result_key(r) for r in res_seq] == \
+            [result_key(o.result) for o in outcomes]
+        assert store_image(s_seq) == store_image(s_grp)
+        info = outcomes.info
+        assert info["components"] == 6
+        assert info["sizes"] == [8, 1, 1, 1, 1, 2]
+        assert info["order"] == [5, 0, 1, 2, 3, 4]
+        assert [o.component for o in outcomes] == \
+            [1] * 8 + [2, 3, 4, 5] + [0, 0]
+        # The walks ran in that order, one after another.
+        t0s = info["comp_t0s"]
+        assert t0s == sorted(t0s)
+        assert outcomes[-2].result.node_allocation
+        assert outcomes[-1].result.node_allocation == {}
+        assert sum(1 for o in outcomes[:8]
+                   if o.result.node_allocation) == 6
 
 
 # ---------------------------------------------------------------------------
@@ -1075,7 +970,7 @@ class TestDeadlineFence:
         f_dead = queue.enqueue(dead)
         window = [queue.dequeue(0)] + queue.drain_pending(63)
         try:
-            applier._apply_window(window, None, None)
+            applier._apply_window(window, None)
             with pytest.raises(ErrDeadlineExceeded):
                 f_dead.wait(5.0)
             assert f_live.wait(5.0).alloc_index > 0
@@ -1089,8 +984,8 @@ class TestDeadlineFence:
 class TestDispatchFailureOverlay:
     def test_dispatch_failure_drops_phantom_overlay_folds(self):
         """A window whose raft DISPATCH fails has already folded its
-        allocs into the applier's optimistic overlay (the partitioned
-        path folds before the committer hand-off): the next window
+        allocs into the applier's optimistic overlay (the verify folds
+        before the committer hand-off): the next window
         must verify against a fresh snapshot, not the phantoms — a
         later plan that fits only if the failed window never happened
         must be ACCEPTED."""
@@ -1110,8 +1005,7 @@ class TestDispatchFailureOverlay:
             with faultinject.injected(fplan):
                 f_a = queue.enqueue(full_a)
                 window = [queue.dequeue(0)] + queue.drain_pending(63)
-                wait_future, snap = applier._apply_window(
-                    window, None, None)
+                snap = applier._apply_window(window, None)
                 with pytest.raises(Exception):
                     f_a.wait(5.0)  # dispatch failed; flag raised
 
@@ -1120,7 +1014,7 @@ class TestDispatchFailureOverlay:
                 # RETURNED overlay state through, like run() does.
                 f_b = queue.enqueue(full_b)
                 window = [queue.dequeue(0)] + queue.drain_pending(63)
-                applier._apply_window(window, wait_future, snap)
+                applier._apply_window(window, snap)
                 assert f_b.wait(5.0).alloc_index > 0, \
                     "phantom folds from a failed dispatch must not " \
                     "reject later plans"
@@ -1170,11 +1064,10 @@ class TestDispatchFailureOverlay:
             with faultinject.injected(fplan):
                 f_a = queue.enqueue(plan_a)
                 window = [queue.dequeue(0)] + queue.drain_pending(63)
-                wait_future, snap = applier._apply_window(
-                    window, None, None)
+                snap = applier._apply_window(window, None)
                 f_b = queue.enqueue(plan_b)
                 window = [queue.dequeue(0)] + queue.drain_pending(63)
-                applier._apply_window(window, wait_future, snap)
+                applier._apply_window(window, snap)
                 gate.set()
                 with pytest.raises(Exception):
                     f_a.wait(5.0)   # A: dispatch error
@@ -1191,7 +1084,7 @@ class TestDispatchFailureOverlay:
             # so the plan is rejected with a refresh (not placed).
             f_b2 = queue.enqueue(plan_b)
             window = [queue.dequeue(0)] + queue.drain_pending(63)
-            applier._apply_window(window, None, None)
+            applier._apply_window(window, None)
             result = f_b2.wait(5.0)
             assert result.node_allocation == {}
             assert result.refresh_index > 0
@@ -1201,56 +1094,68 @@ class TestDispatchFailureOverlay:
 
 
 class TestApplierServiceThreads:
-    def test_component_executor_active_attribution(self):
-        """The executor's active() snapshot names what is verifying
-        RIGHT NOW — the flight recorder's per-component stall
-        attribution rides it."""
-        import threading
+    def test_window_guard_names_every_eval_of_the_window(self,
+                                                         monkeypatch):
+        """The applier thread arms the ``applier.window`` stall guard
+        with an attribution that names the evals of THAT window, the
+        fenced-out ones too — what an incident dump of a wedged window
+        carries."""
+        import contextlib
 
-        from nomad_tpu.server.plan_apply import ComponentExecutor
+        import nomad_tpu.server.plan_apply as plan_apply
 
-        executor = ComponentExecutor(workers=1)
-        started = threading.Event()
-        release = threading.Event()
+        armed = []
 
-        def slow():
-            started.set()
-            release.wait(5.0)
-            return "done"
+        @contextlib.contextmanager
+        def recording_guard(name, timeout, extra_fn=None):
+            armed.append((name, timeout, extra_fn()))
+            yield
 
-        tasks = [slow] + [lambda: "fast"] * 3
-        descs = [{"component": 0, "eval_ids": ["ev-slow"]},
-                 None, None, None]
-        out = []
-        runner = threading.Thread(
-            target=lambda: out.append(
-                executor.run_components(tasks, descs)))
-        runner.start()
+        monkeypatch.setattr(plan_apply.flight_mod, "guard",
+                            recording_guard)
+        broker, fsm, raft, queue, applier = _rig()
+        node = mock.node()
+        raft.apply(codec.encode(codec.NODE_REGISTER_REQUEST,
+                                {"node": node.to_dict()})).wait(5.0)
+        plans = [_outstanding_plan(broker, fsm, raft, node, cpu=100)
+                 for _ in range(3)]
+        stray = place_plan(make_alloc(node))  # never outstanding
+        futures = [queue.enqueue(p) for p in plans + [stray]]
+        applier.start()
         try:
-            assert started.wait(5.0)
-            active = executor.active()
-            assert active["verifying"], "a walk is live"
-            blob = str(active)
-            assert "ev-slow" in blob, \
-                "the stall attribution must name the slow component"
+            for f in futures[:3]:
+                assert f.wait(5.0).alloc_index > 0
+            with pytest.raises(RuntimeError, match="not outstanding"):
+                futures[3].wait(5.0)
         finally:
-            release.set()
-            runner.join(5.0)
-            executor.stop()
-        assert out and [r for chunk in out for r in [chunk]] is not None
+            queue.set_enabled(False)
+            applier.shutdown(5.0)
+            broker.shutdown()
+        assert {name for name, _t, _x in armed} == {"applier.window"}
+        assert all(t == applier.WINDOW_STALL_S for _n, t, _x in armed)
+        named = [eid for _n, _t, extra in armed
+                 for eid in extra["verifying"]["eval_ids"]]
+        assert sorted(named) == sorted(p.eval_id for p in plans + [stray])
+        assert sum(extra["verifying"]["plans"]
+                   for _n, _t, extra in armed) == 4
 
-    def test_executor_stop_reaps_workers(self):
-        import threading
-
-        from nomad_tpu.server.plan_apply import ComponentExecutor
-
-        executor = ComponentExecutor(workers=2, name="test-comps")
-        executor.run_components([lambda: 1, lambda: 2, lambda: 3])
-        assert any(t.name.startswith("test-comps")
-                   for t in threading.enumerate())
-        executor.stop()
-        assert not any(t.name.startswith("test-comps") and t.is_alive()
-                       for t in threading.enumerate())
+    def test_shutdown_reaps_the_applier_and_the_committer(self):
+        """The applier's two service threads — its own and the
+        committer's — are gone after the queue is disabled and
+        ``shutdown`` returns."""
+        broker, fsm, raft, queue, applier = _rig()
+        node = mock.node()
+        raft.apply(codec.encode(codec.NODE_REGISTER_REQUEST,
+                                {"node": node.to_dict()})).wait(5.0)
+        applier.start()
+        f = queue.enqueue(_outstanding_plan(broker, fsm, raft, node))
+        assert f.wait(5.0).alloc_index > 0
+        threads = [applier._thread, applier._committer._thread]
+        assert all(t is not None and t.is_alive() for t in threads)
+        queue.set_enabled(False)
+        applier.shutdown(5.0)
+        broker.shutdown()
+        assert not any(t.is_alive() for t in threads)
 
     def test_committer_survives_and_keeps_order(self):
         """FIFO commit order: jobs resolve in submission order even
@@ -1564,3 +1469,37 @@ class TestColumnarWindowParity:
                     for o in outcomes]
         assert rejected == [sum(1 for n in held if n + lane >= 203)
                             for lane in range(n_plans)]
+
+    def test_verify_is_host_code(self):
+        """On the suite's multi-device host a window big enough for
+        the array pass (>= 512 claims), with claims that walk in it,
+        moves nothing across the host/device seam and compiles
+        nothing."""
+        import jax
+        import jax.monitoring as monitoring
+
+        import nomad_tpu.ops.plan_conflict as plan_conflict
+        from nomad_tpu.parallel.devices import transfer_counts
+
+        assert len(jax.devices()) > 1
+        compiles = []  # listeners cannot be taken off again: keep it cheap
+        monitoring.register_event_duration_secs_listener(
+            lambda event, _secs, **_kw: compiles.append(event)
+            if event.endswith("backend_compile_duration") else None)
+
+        nodes = [mock.node(i) for i in range(300)]
+        store = _store(nodes)
+        store.upsert_allocs(1500, slab_allocs(
+            [(nodes[0], 20000 + k) for k in range(202)]))
+        plans = [place_plan(*slab_allocs(
+            [(n, 30000 + lane) for n in nodes])) for lane in range(3)]
+        assert sum(len(p.node_allocation) for p in plans) >= \
+            plan_conflict.ARRAY_PASS_MIN_CLAIMS
+        before = transfer_counts()
+        n_compiles = len(compiles)
+        outcomes = assert_columnar_parity(store, plans)
+        assert transfer_counts() == before
+        assert len(compiles) == n_compiles
+        # 203 of the ask fit a node: node 0 rejects its second and
+        # third claim, so its three claims walk; the pass took the rest.
+        assert _claims_walked(outcomes) == [(300, 1)] * 3
