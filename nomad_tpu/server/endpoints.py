@@ -220,9 +220,18 @@ class Endpoints:
     def _state(self):
         return self.server.fsm.state
 
-    def _blocking(self, args: dict, table: str, run) -> dict:
-        """Blocking-query wrapper: wait until the table index passes
-        min_query_index or the (jittered, capped) wait expires.
+    def _blocking(self, args: dict, table: str, run, key=None,
+                  index_of=None) -> dict:
+        """Blocking-query wrapper: wait until the index of what ``run``
+        reads passes min_query_index or the (jittered, capped) wait
+        expires.  A list / table reader watches the table: it parks
+        under ``(table,)``, compares the table's index and answers with
+        it.  A reader of ONE row hands in what it reads instead: the
+        watch ``key`` its writers notify, ``index_of()`` the row's
+        current index, and its ``run()`` sets ``index`` itself, from the
+        object it answers — an index read apart from the answer could
+        cover a write the answer lacks, and the caller would park past
+        it.
 
         On the event-driven serving plane the wait is not a parked
         thread: the handler raises ``mux.Parked`` carrying a watch-fan-
@@ -240,13 +249,19 @@ class Endpoints:
         wake, tagged ``table`` and ``fired``) when tracing is on."""
         min_index = int(args.get("min_query_index") or 0)
         state = self._state()
+        if key is None:
+            key = (table,)
+        if index_of is None:
+            def index_of() -> int:
+                return self._state().get_index(table)
         fired = args.pop("_watch_fired", None)
         tracer = trace_mod.tracer() if trace_mod.ENABLED else None
 
         def respond(why: str) -> dict:
             _fired.why = why
             out = run()
-            out["index"] = self._state().get_index(table)
+            if "index" not in out:
+                out["index"] = index_of()
             out["known_leader"] = self.server.has_leader()
             return out
 
@@ -261,7 +276,7 @@ class Endpoints:
                               parent_ctx=tracer.ctx(), table=table,
                               fired=why)
             return respond(why)
-        if min_index <= 0 or state.get_index(table) > min_index:
+        if min_index <= 0 or index_of() > min_index:
             return respond("immediate")
         wait = _jittered(float(args.get("max_query_time") or
                                MAX_BLOCKING_WAIT))
@@ -276,11 +291,11 @@ class Endpoints:
 
             def _subscribe(resume):
                 token = state.watch.subscribe(
-                    (table,), resume, min_index=min_index, ttl=wait)
+                    key, resume, min_index=min_index, ttl=wait)
                 return lambda: state.watch.unsubscribe(token)
             raise mux.Parked(_subscribe)
         woke = threading.Event()
-        token = state.watch.subscribe((table,),
+        token = state.watch.subscribe(key,
                                       lambda timed_out: woke.set(),
                                       min_index=min_index)
         try:
@@ -452,10 +467,22 @@ class Endpoints:
 
     # -- Eval -------------------------------------------------------------
     def eval_get_eval(self, args: dict) -> dict:
+        """One evaluation, watched by itself (reference Eval.GetEval,
+        Nomad 0.2+: ``watch.Item{Eval: id}``, ``reply.Index =
+        out.ModifyIndex``): the read parks under ``("eval", id)``, which
+        only that evaluation's writes and its reap notify, and its index
+        is the answered row's ``modify_index`` (the table's index when
+        there is no such evaluation)."""
+        eval_id = args["eval_id"]
+
         def run() -> dict:
-            ev = self._state().eval_by_id(args["eval_id"])
-            return {"eval": ev.to_dict() if ev else None}
-        return self._blocking(args, "evals", run)
+            ev, index = self._state().eval_index(eval_id)
+            return {"eval": ev.to_dict() if ev else None, "index": index}
+
+        def index_of() -> int:
+            return self._state().eval_index(eval_id)[1]
+        return self._blocking(args, "evals", run, key=("eval", eval_id),
+                              index_of=index_of)
 
     def eval_dequeue(self, args: dict) -> dict:
         fwd = self._forward("Eval.Dequeue", args)

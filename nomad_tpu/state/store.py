@@ -54,14 +54,15 @@ class StateWatch:
     """Shared watch fan-out keyed by (key, min_index).
 
     Parity role: nomad/state/notify.go NotifyGroup — blocking queries
-    register on keys like ("allocs",) or ("alloc-node", node_id) and are
-    woken when a write touches the key.
+    register on keys like ("allocs",), ("alloc-node", node_id) or
+    ("eval", eval_id) and are woken when a write touches the key.
 
     Beyond the reference (the event-driven serving plane): waiters are
     *callbacks* in ONE shared registry instead of one parked
     Event-holding thread each.  ``subscribe(key, deliver, min_index,
     ttl)`` parks a callback that the single notifier drains when the
-    key's table index advances past ``min_index``; timeouts ride one
+    key's index (its table's; the row's own for ``("eval", id)``)
+    advances past ``min_index``; timeouts ride one
     shared TTL wheel (server/ttlwheel.py) instead of per-waiter timers;
     and every exit path — wakeup, timeout, unsubscribe, conn death —
     removes the waiter, so an abandoned long-poll can never leak a
@@ -343,6 +344,17 @@ class _ReadMixin:
     def eval_by_id(self, eval_id: str) -> Optional[Evaluation]:
         return self._t.tables["evals"].get(eval_id)
 
+    def eval_index(self, eval_id: str) -> tuple:
+        """``(eval, index)`` for a read of ONE evaluation: the row and
+        its own ``modify_index``, both from the same object, or ``(None,
+        the table's index)`` when there is no such evaluation (reference
+        Eval.GetEval, Nomad 0.2+).  The table's index is read BEFORE the
+        row: a write landing between the two then leaves the caller an
+        index older than the write, never one that already covers it."""
+        floor = self.get_index("evals")
+        ev = self.eval_by_id(eval_id)
+        return ev, (ev.modify_index if ev is not None else floor)
+
     def evals(self) -> Iterable[Evaluation]:
         return list(self._t.tables["evals"].values())
 
@@ -462,13 +474,17 @@ class StateStore(_ReadMixin):
         self.watch = StateWatch(index_of=_index_of)
 
     def _watch_index(self, key) -> int:
-        """Current table index behind a watch key (the fan-out's
-        lost-wakeup recheck).  Unkeyed/odd keys report the latest index
-        so a recheck can only over-deliver, never under-deliver."""
+        """Current index behind a watch key (the fan-out's lost-wakeup
+        recheck): the ROW's ``modify_index`` for ``("eval", id)``, whose
+        waiters compare against the row, the table's index for the other
+        keys.  Unkeyed/odd keys report the latest index so a recheck can
+        only over-deliver, never under-deliver."""
         kind = key[0] if isinstance(key, tuple) and key else key
         if kind in TABLES:
             return self.get_index(kind)
-        table = {"node": "nodes", "job": "jobs", "eval": "evals",
+        if kind == "eval":
+            return self.eval_index(key[1])[1]
+        table = {"node": "nodes", "job": "jobs",
                  "alloc-node": "allocs"}.get(kind)
         if table is not None:
             return self.get_index(table)
@@ -689,7 +705,8 @@ class StateStore(_ReadMixin):
                 table[new.id] = new
                 self._index_add(by_job, new.job_id, new.id)
             self._bump("evals", index)
-        self.watch.notify(("evals",), index=index)
+        self.watch.notify(("evals",), *[("eval", ev.id) for ev in evals],
+                          index=index)
 
     def delete_eval(self, index: int, eval_ids: list,
                     alloc_ids: list) -> None:
@@ -723,6 +740,7 @@ class StateStore(_ReadMixin):
         # the notify key order escapes to watch subscribers — replicas
         # must fan out identically for the same log entry.
         keys = [("evals",), ("allocs",)]
+        keys += [("eval", eid) for eid in eval_ids]
         keys += [("alloc-node", n) for n in sorted(set(touched_nodes))]
         self.watch.notify(*keys, index=index)
 

@@ -961,6 +961,14 @@ def _tags(span: dict) -> dict:
     return span.get("tags") or {}
 
 
+def _pending_eval(job_id: str):
+    from nomad_tpu.structs import Evaluation, generate_uuid
+
+    return Evaluation(id=generate_uuid(), priority=50, type="service",
+                      triggered_by="job-register", job_id=job_id,
+                      status="pending")
+
+
 def _http_agent():
     from nomad_tpu.agent import Agent, AgentConfig
     from nomad_tpu.api import APIClient
@@ -1126,12 +1134,12 @@ class TestWholePathSpans:
         lag = chain.reduce({"what": "wake_lag_ms"}, ctx)
         assert lag is not None and lag < 1000.0
 
-    def test_unrelated_eval_write_wakes_the_query_unchanged(self):
-        """The table-level watch: a write to ANOTHER eval wakes a
-        client blocked on its own eval; the span says fired=index,
-        changed=0, and the registry counts the wake-up."""
+    def test_unrelated_eval_write_leaves_the_query_parked(self):
+        """The per-evaluation watch: a write to ANOTHER eval leaves a
+        client blocked on its own eval parked; its own eval's write
+        wakes it, the span says fired=index, changed=1, and the
+        registry counts the wake-up as a useful one."""
         from nomad_tpu.api.client import QueryOptions
-        from nomad_tpu.structs import Evaluation, generate_uuid
 
         agent, api = _http_agent()
         srv = agent.server
@@ -1139,38 +1147,42 @@ class TestWholePathSpans:
             for w in srv.workers:
                 w.set_pause(True)   # evals stay pending
             time.sleep(0.6)  # sleep-ok: workers leave their dequeue
-
-            def pending(job_id):
-                return Evaluation(
-                    id=generate_uuid(), priority=50, type="service",
-                    triggered_by="job-register", job_id=job_id,
-                    status="pending")
-            mine, other = pending("job-a"), pending("job-b")
+            mine, other = _pending_eval("job-a"), _pending_eval("job-b")
             srv.apply_eval_update([mine])
             index = srv.fsm.state.get_index("evals")
             got = {}
             with trace.tracing(seed=27) as tracer:
                 reader = threading.Thread(target=lambda: got.update(
-                    ev=api.eval_info(mine.id, QueryOptions(
-                        wait_index=index, wait_time=10.0))[0]))
+                    zip(("ev", "meta"), api.eval_info(
+                        mine.id, QueryOptions(wait_index=index,
+                                              wait_time=10.0)))))
                 reader.start()
                 wait_until(lambda: len(srv.fsm.state.watch._waiters)
                            >= 1, timeout=5.0)
                 srv.apply_eval_update([other])
+                reader.join(0.3)
+                assert reader.is_alive(), \
+                    "another eval's write woke the read of this one"
+                assert srv.fsm.state.watch.live_waiters() == 1
+                done = mine.copy()
+                done.status = "complete"
+                srv.apply_eval_update([done])
                 reader.join(10.0)
                 assert not reader.is_alive()
                 wait_until(lambda: any(
                     s["name"] == "http.serve.eval_get"
                     for s in tracer.snapshot()), timeout=5.0)
                 spans = tracer.snapshot()
-            assert got["ev"].status == "pending"
+            assert got["ev"].status == "complete"
+            assert got["meta"].last_index == got["ev"].modify_index \
+                == srv.fsm.state.get_index("evals")
             read = next(s for s in spans
                         if s["name"] == "http.serve.eval_get")
             assert _tags(read)["fired"] == "index"
-            assert _tags(read)["changed"] == 0
+            assert _tags(read)["changed"] == 1
             assert _tags(read)["blocking"] == 1
             assert _tags(read)["eval_id"] == mine.id
-            assert _tags(read)["eval_status"] == "pending"
+            assert _tags(read)["eval_status"] == "complete"
             blocked = next(s for s in spans
                            if s["name"] == "query.blocked")
             assert _tags(blocked)["fired"] == "index"
@@ -1178,14 +1190,73 @@ class TestWholePathSpans:
                 blocked["t0"] + blocked["dur"] <= read["t0"] + read["dur"]
             stats = agent.http.stats()
             assert stats["blocking_wakes"] == 1
-            assert stats["blocking_wakes_changed"] == 0
+            assert stats["blocking_wakes_changed"] == 1
             metrics = agent.metrics_payload()["providers"]
             assert metrics["nomad.http.blocking_wakes"] == 1
-            assert metrics["nomad.http.blocking_wakes_changed"] == 0
+            assert metrics["nomad.http.blocking_wakes_changed"] == 1
             assert metrics["nomad.workers.batches"] >= 0
             assert metrics["nomad.workers.batch_busy_s"] >= 0.0
             assert metrics["nomad.finish.node_inits"] >= \
                 metrics["nomad.finish.node_walks"] >= 0
+        finally:
+            for w in srv.workers:
+                w.set_pause(False)
+            agent.shutdown()
+
+    def test_every_wake_of_eight_waiters_is_its_own_write(self):
+        """8 clients blocked on 8 evals over HTTP, 50 writes to other
+        evals: nobody wakes; then each eval's own write wakes its one
+        reader, so every counted wake is a useful one, and the fan-out
+        registry is empty again (no entry leaked under the per-eval
+        key)."""
+        from nomad_tpu.api.client import QueryOptions
+
+        agent, api = _http_agent()
+        srv = agent.server
+        watch = srv.fsm.state.watch
+        try:
+            for w in srv.workers:
+                w.set_pause(True)   # evals stay pending
+            time.sleep(0.6)  # sleep-ok: workers leave their dequeue
+            mine = [_pending_eval(f"job-{i}") for i in range(8)]
+            srv.apply_eval_update(mine)
+            index = srv.fsm.state.get_index("evals")
+            got = {}
+
+            def read(ev):
+                got[ev.id] = api.eval_info(ev.id, QueryOptions(
+                    wait_index=index, wait_time=30.0))
+            readers = [threading.Thread(target=read, args=(ev,))
+                       for ev in mine]
+            for t in readers:
+                t.start()
+            wait_until(lambda: watch.live_waiters() == 8, timeout=10.0)
+            for i in range(50):
+                srv.apply_eval_update([_pending_eval(f"other-{i}")])
+            assert watch.stats()["live_waiters"] == 8
+            assert agent.http.stats()["blocking_wakes"] == 0
+            for n, ev in enumerate(mine, 1):
+                done = ev.copy()
+                done.status = "complete"
+                srv.apply_eval_update([done])
+                wait_until(lambda: agent.http.stats()["blocking_wakes"]
+                           == n, timeout=10.0)
+                stats = agent.http.stats()
+                assert stats["blocking_wakes_changed"] == \
+                    stats["blocking_wakes"] == n
+                assert watch.stats()["live_waiters"] == 8 - n
+            for t in readers:
+                t.join(10.0)
+                assert not t.is_alive()
+            for ev in mine:
+                answered, meta = got[ev.id]
+                assert answered.status == "complete"
+                assert meta.last_index == answered.modify_index > index
+            metrics = agent.metrics_payload()["providers"]
+            assert metrics["nomad.http.blocking_wakes_changed"] == \
+                metrics["nomad.http.blocking_wakes"] == 8
+            assert watch.stats()["live_waiters"] == 0
+            assert watch.stats()["timeouts"] == 0
         finally:
             for w in srv.workers:
                 w.set_pause(False)
@@ -1196,18 +1267,11 @@ class TestWholePathSpans:
     def test_parked_query_records_its_wait(self, wait, fired):
         """The RPC plane parks a blocking query instead of holding a
         thread: the span still runs subscribe -> wake."""
-        from nomad_tpu.structs import Evaluation, generate_uuid
-
         srv = Server(ServerConfig(num_schedulers=0, enable_rpc=True))
         srv.establish_leadership()
         pool = ConnPool()
         try:
-            def pending(job_id):
-                return Evaluation(
-                    id=generate_uuid(), priority=50, type="service",
-                    triggered_by="job-register", job_id=job_id,
-                    status="pending")
-            mine = pending("job-a")
+            mine = _pending_eval("job-a")
             srv.apply_eval_update([mine])
             index = srv.fsm.state.get_index("evals")
             with trace.tracing(seed=28) as tracer:
@@ -1219,7 +1283,11 @@ class TestWholePathSpans:
                 if fired == "index":
                     wait_until(lambda: len(
                         srv.fsm.state.watch._waiters) >= 1, timeout=5.0)
-                    srv.apply_eval_update([pending("job-b")])
+                    # Another eval's write leaves it parked; its own
+                    # wakes it.
+                    srv.apply_eval_update([_pending_eval("job-b")])
+                    assert srv.fsm.state.watch.live_waiters() == 1
+                    srv.apply_eval_update([mine])
                 reader.join(15.0)
                 assert not reader.is_alive()
                 blocked = [s for s in tracer.snapshot()
