@@ -14,11 +14,13 @@ This is the same engineering trade XLA itself makes with host callbacks:
 don't ship work to an accelerator that costs more to reach than to run.
 Semantics are kernel-for-kernel the same math, and on the CPU backend
 the same choices (parity-tested in tests/test_jax_binpack.py; scores
-agree to ~2e-6).  On a TPU 10^x rounds a few 1e-6 relative off numpy,
-so two nodes whose scores are closer than that may be ordered
-differently by the two engines — measured on a v5e, a handful of
-near-tie swaps per 1,000 placements on a used fleet (PERF.md,
-bring-up).  Either order is a valid plan; the contract between the
+agree to ~2e-6).  On a TPU 10^x rounds a few 1e-6 RELATIVE off numpy
+and a BestFit term reaches 10, so a score sits 3-6e-5 off numpy's
+(PERF.md, bring-up; 4.1e-5 off the float64 reference over the picks
+sampled in fleet131k.storm, PR 33) and two nodes whose scores are
+closer than that may be ordered differently by the two engines —
+measured on a v5e, a handful of near-tie swaps per 1,000 placements on
+a used fleet.  Either order is a valid plan; the contract between the
 engines is check_sequence_host / check_rounds_host below.
 Reference math AllocsFit/ScoreFit
 (/root/reference/nomad/structs/funcs.go:48-124), anti-affinity
@@ -181,12 +183,13 @@ def place_rounds_host(capacity, reserved, usage0, jc0, feasible, asks,
 # -- checking another engine's choices -----------------------------------
 # On the CPU backend the XLA kernels and these twins make the same
 # choices.  On a TPU they do not have to: 10^x rounds a few 1e-6
-# relative off numpy there, so two nodes whose scores are closer than
-# that may be ranked differently, and once one choice differs the two
-# engines walk different (equally valid) usage trajectories.  So the question one
-# engine can soundly ask of the other is not "same nodes?" but "would I
-# have ranked each of your picks best, within ``atol``, at the step you
-# made it?" — answered by scoring along the OTHER engine's trajectory.
+# relative off numpy there (3-6e-5 on a score), so two nodes whose
+# scores are closer than that may be ranked differently, and once one
+# choice differs the two engines walk different (equally valid) usage
+# trajectories.  So the question one engine can soundly ask of the
+# other is not "same nodes?" but "would I have ranked each of your
+# picks best, within ``atol``, at the step you made it?" — answered by
+# scoring along the OTHER engine's trajectory.
 
 def _check_setup(capacity, reserved, usage0, jc0, feasible, asks,
                  n_real: int) -> tuple:

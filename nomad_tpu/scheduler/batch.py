@@ -68,7 +68,8 @@ def _lane_spans(name: str, scheds, t0: float, t1: float,
                           else None, eval_id=ev.id, **tags)
 
 
-def dispatch_tags(rounds_mode: bool, rounds: int, engine: str) -> dict:
+def dispatch_tags(rounds_mode: bool, rounds: int, engine: str,
+                  cost: int, lanes: int) -> dict:
     """What a lane's ``sched.dispatch`` span says of the kernel it rode
     (nothing while tracing is off).  ``mode`` is the kernel the
     dispatch ran — ``rounds`` (one scoring pass per slot and top-k
@@ -77,11 +78,15 @@ def dispatch_tags(rounds_mode: bool, rounds: int, engine: str) -> dict:
     fused window runs its widest lane's; 0 on the sequence kernel);
     ``engine`` is who ran it: ``host`` (the numpy twin), ``device``
     (the XLA kernel on one chip) or ``sharded`` (over a mesh), as
-    ``scheduler/executor.py`` chose."""
+    ``scheduler/executor.py`` chose; ``cost`` is the estimate the
+    choice was made on (``JaxBinPackScheduler.host_wins``) and
+    ``lanes`` how many lanes shared that choice (a fused window's, 1
+    for a lone eval)."""
     if not trace_mod.ENABLED:
         return {}
     return {"mode": "rounds" if rounds_mode else "sequence",
-            "rounds": rounds if rounds_mode else 0, "engine": engine}
+            "rounds": rounds if rounds_mode else 0, "engine": engine,
+            "cost": cost, "lanes": lanes}
 
 
 class BatchEvalRunner:
@@ -115,6 +120,10 @@ class BatchEvalRunner:
         self.device_dispatches = 0
         self.sharded_dispatches = 0
         self.fused_batches = 0   # fused windows planned, either executor
+        # The same mix by the work: lanes (evals) the device engine
+        # placed; the twin runs one call a lane, so its lanes ARE
+        # ``host_dispatches``.
+        self.device_lanes = 0
         # Finish: per-node network states built, and how many of those
         # walked the node's allocations because the usage mirror's
         # occupancy could not serve them (nomad.finish.*).
@@ -132,6 +141,7 @@ class BatchEvalRunner:
         self.host_dispatches += calls["host"]
         self.device_dispatches += calls["device"]
         self.sharded_dispatches += calls["sharded"]
+        self.device_lanes += calls["device"]
         sched.kernel_calls = dict.fromkeys(calls, 0)
 
     def _note_finish(self, scheds: list) -> dict:
@@ -152,6 +162,8 @@ class BatchEvalRunner:
             "device_dispatches": self.device_dispatches,
             "sharded_dispatches": self.sharded_dispatches,
             "fused_batches": self.fused_batches,
+            "host_lanes": self.host_dispatches,
+            "device_lanes": self.device_lanes,
         }
 
     def finish_stats(self) -> dict:
@@ -262,12 +274,17 @@ class BatchEvalRunner:
                                     batch=(ev.type == "batch"))
         t0 = _tnow()
         retry.process(ev)
+        # The kernel calls of this re-plan by engine (each plans the
+        # eval once more: a lane of its own), before they are folded.
+        calls = {"host_calls": retry.kernel_calls["host"],
+                 "device_calls": retry.kernel_calls["device"]} \
+            if trace_mod.ENABLED else {}
         self._note_dispatch(retry)
         self._note_finish([retry])
         # One span over the whole re-plan.  Its status write is a
         # sibling ``sched.status`` under the eval's anchor, not a child:
         # the re-plan's own time stays a leaf of the eval's tree.
-        _lane_spans("sched.retry", [retry], t0, _tnow())
+        _lane_spans("sched.retry", [retry], t0, _tnow(), **calls)
 
     def _process(self, evals: list[Evaluation],
                  retries: Optional[list] = None) -> None:
@@ -309,24 +326,19 @@ class BatchEvalRunner:
         k_cap = max(a.k_cap for _, _, a in pending)
         rounds = max(a.rounds for _, _, a in pending)
 
-        # Executor policy (same trade as JaxBinPackScheduler.
-        # choose_host_executor, and the same NOMAD_TPU_EXECUTOR
-        # override): a fused dispatch pays one device round trip + a
-        # [B, G, N] upload; below this op-count the numpy kernels
-        # finish before the request would even reach the device.  The
-        # host path reads each lane's arrays directly — no stacking.
-        from .executor import (EXECUTOR_DEVICE, EXECUTOR_HOST,
-                               executor_policy)
-
-        policy = executor_policy()
+        # Executor policy (JaxBinPackScheduler.host_executor: the one
+        # comparison, and the same NOMAD_TPU_EXECUTOR override): a
+        # fused dispatch pays one device round trip + a [B, G, N]
+        # upload; below the break-even the numpy kernels finish before
+        # the request would even reach the device.  The fused kernel
+        # scans the padded slot axis, so the estimate counts g_max.
+        # The host path reads each lane's arrays directly — no stacking.
         steps = rounds * g_max if rounds_ok else p_max
         fused_cost = B * steps * statics.n_real
         self.fused_batches += 1
-        if policy == EXECUTOR_HOST or (
-                policy != EXECUTOR_DEVICE and
-                fused_cost <= JaxBinPackScheduler.HOST_SINGLE_SHOT_COST):
+        if JaxBinPackScheduler.host_executor(fused_cost):
             self._finish_fused_host(pending, rounds_ok, k_cap, rounds,
-                                    retries)
+                                    fused_cost, retries)
             if leftovers:
                 self._process_leftovers(leftovers)
             return
@@ -360,10 +372,12 @@ class BatchEvalRunner:
 
         mesh = dispatch_mesh(B_pad, statics.n_pad)
         self.device_dispatches += 1
+        self.device_lanes += B
         if mesh is not None:
             self.sharded_dispatches += 1
         kernel_tags = dispatch_tags(rounds_ok, rounds,
-                                    "device" if mesh is None else "sharded")
+                                    "device" if mesh is None else "sharded",
+                                    fused_cost, B)
         # All fused lanes share the same snapshot base usage (fast-path
         # contract above); use the resident device copies when available
         # (single-device mirror copy, or on a mesh the sharded statics +
@@ -415,7 +429,9 @@ class BatchEvalRunner:
                     place_rounds_batch as program
             with (device_dispatch(program, lanes=B, b_pad=B_pad,
                                   g_pad=g_max, k_cap=k_cap, rounds=rounds,
-                                  n_pad=statics.n_pad)
+                                  n_pad=statics.n_pad,
+                                  slots=sum(a.n_groups
+                                            for _, _, a in pending))
                   if trace_mod.ENABLED else NO_DISPATCH):
                 if mesh is not None:
                     chosen_s, score_s, _u = place_rounds_batch_sharded(
@@ -469,7 +485,7 @@ class BatchEvalRunner:
             self._process_leftovers(leftovers)
 
     def _finish_fused_host(self, pending, rounds_ok, k_cap,
-                           rounds, retries=None) -> None:
+                           rounds, fused_cost, retries=None) -> None:
         """Host-executor twin of the fused dispatch: every lane plans
         against the same snapshot base usage via the numpy kernels, one
         lane at a time (each lane's kernel is vectorized over nodes),
@@ -481,7 +497,8 @@ class BatchEvalRunner:
         statics = pending[0][2].statics
         base_usage = pending[0][2].view.usage  # host array
         n_real = statics.n_real
-        kernel_tags = dispatch_tags(rounds_ok, rounds, "host")
+        kernel_tags = dispatch_tags(rounds_ok, rounds, "host", fused_cost,
+                                    len(pending))
         done = []
         for sched, place, args in pending:
             t_disp = _tnow()
@@ -527,7 +544,8 @@ class BatchEvalRunner:
         _lane_spans("sched.dispatch", [sched], t0, t1, **dispatch_tags(
             args.rounds_eligible, args.rounds,
             "host" if sched.dispatched_host else
-            "sharded" if sched.dispatched_sharded else "device"))
+            "sharded" if sched.dispatched_sharded else "device",
+            sched.dispatch_cost(args), 1))
         sched.finish_deferred(place, args, chosen, scores)
         self._note_dispatch(sched)
         _lane_spans("sched.finish", [sched], t1, _tnow(),

@@ -1,7 +1,8 @@
 """Executor policy: which engine runs the placement kernels.
 
 The jax-binpack scheduler picks between two executors per dispatch
-(scheduler/jax_binpack.py choose_host_executor):
+(scheduler/jax_binpack.py ``JaxBinPackScheduler.host_executor``, the one
+comparison both the lone-eval and the fused site read):
 
   host    numpy twin kernels (ops/binpack_host.py) — zero dispatch
           latency, wins whenever the workload is smaller than a device
@@ -31,9 +32,28 @@ said host, or under ``auto`` lanes x steps x nodes stayed within
 ``HOST_SINGLE_SHOT_COST``, steps being slots x rounds under top-k
 rounds and placements on the sequence kernel), ``device`` (the XLA
 kernel on one chip) or ``sharded`` (the same over a mesh), beside
-``mode`` and ``rounds``;
+``mode``, ``rounds``, the estimate itself (``cost``) and the ``lanes``
+that shared the choice;
 ``nomad.batch_runner.{host,device,sharded}_dispatches`` count the same
-choice always.
+choice always, and ``nomad.batch_runner.{host,device}_lanes`` the lanes
+each engine placed (a fused device window is one dispatch of many
+lanes).
+
+Where the break-even falls.  A fused window scans its PADDED slot axis
+(``g_pad``, at least 8), so a window of single-group lanes costs lanes x
+8 x nodes: it crosses ``HOST_SINGLE_SHOT_COST`` = 2^25 above 32 lanes
+at 131,072 nodes and above 419 at 10,000 (the runner fuses at most 64).
+A lone eval counts its real slots: one group on 131,072 nodes is 2^17,
+inside ``HOST_ALWAYS_COST``.  Measured on a v5e at 131,072 nodes
+(PERF.md section 6, PR 33): the twin takes 8.4-10.2 ms a lane (539 ms
+for 64 lanes); a fused window on the kernel takes 165 ms at 64 lanes
+(61 ms from enqueue to results, 50 ms of it device time, 101 MB
+uploaded), 52 ms at 32 lanes, 14 ms at 16 and 7.7 ms at one (the twin:
+10.2); a fenced round trip of a tiny kernel is 0.99 ms.  At that width
+the kernel wins at every lane count, and ``auto`` still keeps a window
+of 32 lanes or fewer on the twin (275 ms against 52): the thresholds
+predate these numbers, and moving them is ROADMAP D2's, judged on
+``fleet131k.storm`` and ``baseline4-10k.small``.
 
 The override only selects the executor; plan semantics are identical on
 both sides (tests/test_executor_parity.py gates this on every run).

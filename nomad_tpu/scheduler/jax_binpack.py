@@ -606,13 +606,36 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
     # when the caller isn't pipelining dispatches (a pipeline hides the
     # round trip behind host work, a single-shot eval eats it whole).
     # The two thresholds were not derived from a round trip measured on
-    # the chip; PERF.md records that round trip and ROADMAP D2 re-derives
-    # them from it.
-    HOST_ALWAYS_COST = 1 << 18       # ~sub-ms of numpy
-    HOST_SINGLE_SHOT_COST = 1 << 25  # ~tens of ms of numpy
+    # the chip (PERF.md section 6, PR 33, has the chip's numbers for
+    # both engines; ROADMAP D2 re-derives the thresholds from them).
+    # cost = lanes x steps x nodes, steps = slots x rounds (top-k rounds)
+    # or placements (sequence kernel).  A lone eval counts its REAL
+    # slots; a fused window counts the PADDED slot axis its kernel scans
+    # (g_pad, at least 8), so a window of single-group lanes reaches
+    # 2^25 at lanes x nodes = 2^22: 33 lanes of 131,072 nodes, 420 of
+    # 10,000.  Measured on a v5e's host (PR 33): numpy takes 65-80 ns a
+    # real slot and node (0.69 ms a lane of 10,000 nodes, 8.4-10.2 ms of
+    # 131,072).
+    # One lane x one slot x 262,144 nodes: ~20 ms of numpy.
+    HOST_ALWAYS_COST = 1 << 18
+    # 32 lanes x 8 padded slots x 131,072 nodes: 275 ms of numpy, where
+    # the kernel's fused window takes 52 ms (165 ms at 64 lanes).
+    HOST_SINGLE_SHOT_COST = 1 << 25
 
-    def choose_host_executor(self, args: "DeviceArgs",
-                             pipelined: bool) -> bool:
+    @classmethod
+    def host_wins(cls, cost: int, pipelined: bool = False) -> bool:
+        """THE comparison behind ``auto``: does the numpy twin take a
+        dispatch of estimated ``cost``?  Read by the lone-eval site
+        (``choose_host_executor``) and the fused one
+        (``BatchEvalRunner._process``)."""
+        if cost <= cls.HOST_ALWAYS_COST:
+            return True
+        return not pipelined and cost <= cls.HOST_SINGLE_SHOT_COST
+
+    @classmethod
+    def host_executor(cls, cost: int, pipelined: bool = False) -> bool:
+        """``host_wins`` under the executor policy: a forced policy
+        decides alone."""
         from .executor import (EXECUTOR_DEVICE, EXECUTOR_HOST,
                                executor_policy)
 
@@ -621,12 +644,18 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
             return True
         if policy == EXECUTOR_DEVICE:
             return False
+        return cls.host_wins(cost, pipelined)
+
+    @staticmethod
+    def dispatch_cost(args: "DeviceArgs") -> int:
+        """A lone eval's estimate: steps x nodes."""
         steps = args.rounds * args.n_groups if args.rounds_eligible \
             else args.n_place
-        cost = steps * args.statics.n_real
-        if cost <= self.HOST_ALWAYS_COST:
-            return True
-        return not pipelined and cost <= self.HOST_SINGLE_SHOT_COST
+        return steps * args.statics.n_real
+
+    def choose_host_executor(self, args: "DeviceArgs",
+                             pipelined: bool) -> bool:
+        return self.host_executor(self.dispatch_cost(args), pipelined)
 
     # Which executor the last dispatch_device call actually used: True
     # host, False device, None when no dispatch ran yet.  The pipelined

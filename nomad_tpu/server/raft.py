@@ -242,8 +242,19 @@ class FileLogStore:
         return size
 
     def append(self, index: int, entry) -> None:
-        record = msgpack.packb((index, entry), use_bin_type=True)
-        framed = self._frame(record)
+        self.append_many([(index, entry)])
+
+    def append_many(self, records: list) -> None:
+        """Append ``records`` [(index, entry), ...] as ONE write and ONE
+        fsync (group commit: concurrent appliers share the flush that
+        makes them durable).  All or nothing for the caller: on any
+        failure the file is truncated back to where the batch began.
+        A simulated power cut tears the batch like one long record, so
+        a prefix of whole records may survive unacknowledged — what a
+        lone record's whole-landed case already is."""
+        framed = b"".join(
+            self._frame(msgpack.packb(rec, use_bin_type=True))
+            for rec in records)
         crash = None
         if faultinject.ACTIVE:
             # Consulted OUTSIDE the lock (a delay/hang action must not
@@ -648,6 +659,11 @@ class InmemRaft:
         self._lock = threading.Lock()
         self._applied = 0
         self._entries_since_snap = 0
+        # Group commit (``apply``): entries waiting for the commit
+        # section, and whether a caller is inside it.
+        self._group = threading.Condition()
+        self._queued: list = []
+        self._committing = False
 
         # Boot: restore newest snapshot, then replay the tail of the log.
         # Snapshot files wrap (term, fsm_blob) — shared format with NetRaft
@@ -689,46 +705,82 @@ class InmemRaft:
             return self._applied
 
     def apply(self, entry: bytes) -> ApplyFuture:
+        """Commit ``entry``; the future is resolved on return.
+
+        Group commit: appliers that arrive while another is inside the
+        commit section queue up, and the next one through takes the
+        whole queue as ONE batch — one log write and one fsync for all
+        of it, then each entry applied to the FSM in log order and its
+        caller answered.  Every caller still returns only after the
+        fsync that covers ITS entry (durable before applied, applied
+        before answered); what concurrent callers no longer pay is a
+        flush each.  A caller commits at most one batch, its own entry
+        in it, so no thread is held to serve the others."""
         if faultinject.ACTIVE:
             # Before any state moves: an injected failure here is an
             # entry that never entered the log (callers retry/raise).
             faultinject.fire("raft.apply")
         future = ApplyFuture()
+        with self._group:
+            self._queued.append((entry, future))
+            while self._committing and not future.done():
+                # Bounded slices (the committer always notifies on its
+                # way out; a slice that times out only re-checks).
+                self._group.wait(1.0)
+            if future.done():
+                return future   # rode another caller's batch
+            self._committing = True
+            batch, self._queued = self._queued, []
+        try:
+            self._commit(batch)
+        finally:
+            with self._group:
+                self._committing = False
+                self._group.notify_all()
+        try:
+            self._maybe_snapshot()
+        except Exception:
+            # A compaction failure (disk death, injected crash) must
+            # not fail an apply that already committed; the log keeps
+            # the entries a snapshot would have covered.
+            logger.exception("snapshot compaction failed")
+        return future
+
+    def _commit(self, batch: list) -> None:
+        """Persist ``batch`` [(entry, future), ...] with one append,
+        apply it in order, answer every future."""
         with self._lock:
-            index = self._applied + 1
+            first = self._applied + 1
             # Persist BEFORE applying (raft discipline, reference
-            # raft-boltdb ordering): a disk failure rejects the entry with
-            # no state moved, so the in-memory FSM can never run ahead of
-            # the durable log.  An entry whose apply then fails stays on
-            # disk but is harmless — boot replay tolerates unreplayable
-            # entries (see replay try/except above), mirroring that the
-            # write it carried failed when first applied.
+            # raft-boltdb ordering): a disk failure rejects the batch
+            # with no state moved, so the in-memory FSM can never run
+            # ahead of the durable log.  An entry whose apply then fails
+            # stays on disk but is harmless — boot replay tolerates
+            # unreplayable entries (see replay try/except above),
+            # mirroring that the write it carried failed when first
+            # applied.
             if self.log_store is not None:
                 try:
-                    self.log_store.append(index, entry)
+                    self.log_store.append_many(
+                        [(first + i, entry)
+                         for i, (entry, _f) in enumerate(batch)])
                 except Exception as e:
-                    logger.exception("raft log append failed at index %d",
-                                     index)
-                    future.respond(index, None, e)
-                    return future
-            apply_error = None
-            response = None
-            try:
-                response = self.fsm.apply(index, entry)
-            except Exception as e:  # surface apply errors to the caller
-                apply_error = e
-            self._applied = index
-            self._entries_since_snap += 1
-        future.respond(index, response, apply_error)
-        if apply_error is None:
-            try:
-                self._maybe_snapshot()
-            except Exception:
-                # A compaction failure (disk death, injected crash)
-                # must not fail an apply that already committed; the
-                # log keeps the entries a snapshot would have covered.
-                logger.exception("snapshot compaction failed")
-        return future
+                    logger.exception(
+                        "raft log append failed at index %d (%d entries)",
+                        first, len(batch))
+                    for i, (_entry, future) in enumerate(batch):
+                        future.respond(first + i, None, e)
+                    return
+            for index, (entry, future) in enumerate(batch, first):
+                apply_error = None
+                response = None
+                try:
+                    response = self.fsm.apply(index, entry)
+                except Exception as e:  # surface apply errors to the caller
+                    apply_error = e
+                self._applied = index
+                self._entries_since_snap += 1
+                future.respond(index, response, apply_error)
 
     def barrier(self) -> int:
         """All prior applies are visible once this returns (trivially true

@@ -176,6 +176,7 @@ def test_fused_dispatch_rides_the_mesh_on_multi_device(monkeypatch):
     from nomad_tpu.scheduler.jax_binpack import JaxBinPackScheduler
 
     monkeypatch.setattr(JaxBinPackScheduler, "HOST_SINGLE_SHOT_COST", 0)
+    monkeypatch.setattr(JaxBinPackScheduler, "HOST_ALWAYS_COST", 0)
     used = []
     orig = mesh_mod.dispatch_mesh
 

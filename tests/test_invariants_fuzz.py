@@ -192,6 +192,7 @@ def test_fuzz_invariants_fused_mesh_storm(seed, monkeypatch):
     from nomad_tpu.scheduler.jax_binpack import JaxBinPackScheduler
 
     monkeypatch.setattr(JaxBinPackScheduler, "HOST_SINGLE_SHOT_COST", 0)
+    monkeypatch.setattr(JaxBinPackScheduler, "HOST_ALWAYS_COST", 0)
     rng = np.random.default_rng(seed)
     h = Harness()
     h.planner = VerifyingPlanner(h)

@@ -1198,6 +1198,10 @@ class TestWholePathSpans:
             assert metrics["nomad.workers.batch_busy_s"] >= 0.0
             assert metrics["nomad.finish.node_inits"] >= \
                 metrics["nomad.finish.node_walks"] >= 0
+            assert metrics["nomad.batch_runner.host_lanes"] == \
+                metrics["nomad.batch_runner.host_dispatches"] >= 0
+            assert metrics["nomad.batch_runner.device_lanes"] >= \
+                metrics["nomad.batch_runner.device_dispatches"] >= 0
         finally:
             for w in srv.workers:
                 w.set_pause(False)
@@ -1331,10 +1335,19 @@ class TestWholePathSpans:
                                 np.ones((3, 6), np.float32))
             # The single-device twin: the suite's 8 virtual devices
             # would otherwise shard the lanes (another program's name).
+            runner = BatchEvalRunner(h.state.snapshot(), h)
             with executor_override("device"), mesh_override("off"):
-                BatchEvalRunner(h.state.snapshot(), h).process(evals)
+                runner.process(evals)
+            lanes = [_tags(s) for s in tracer.snapshot()
+                     if s["name"] == "sched.dispatch"]
             spans = [s for s in tracer.snapshot()
                      if s["name"] == "device.dispatch"]
+        # The lane spans say what the choice was made on, and the
+        # always-on pair counts the lanes each engine placed.
+        assert {(t["engine"], t["lanes"], t["cost"]) for t in lanes} == \
+            {("device", 3, 3 * 8 * 16)}
+        assert runner.stats()["device_lanes"] == 3
+        assert runner.stats()["host_lanes"] == 0
         scatter = next(s for s in spans if _tags(s)["program"] ==
                        fleet._scatter_jit_impl.__name__)
         assert _tags(scatter)["async"] == 1
@@ -1345,6 +1358,7 @@ class TestWholePathSpans:
         assert _tags(fused)["program"] == "_place_rounds_batched"
         assert "async" not in _tags(fused)
         assert _tags(fused)["lanes"] == 3 and _tags(fused)["b_pad"] == 4
+        assert _tags(fused)["slots"] == 3     # one real slot a lane
         for key in ("g_pad", "k_cap", "rounds", "n_pad"):
             assert _tags(fused)[key] >= 1, key
         assert _tags(fused)["h2d_bytes"] > 0

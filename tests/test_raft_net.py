@@ -385,10 +385,10 @@ def test_inmem_raft_disk_failure_rejects_before_apply(tmp_path):
     class FlakyLog(FileLogStore):
         fail = False
 
-        def append(self, index, entry):
+        def append_many(self, records):
             if self.fail:
                 raise OSError("disk full")
-            super().append(index, entry)
+            super().append_many(records)
 
     fsm = _RecordingFSM()
     log = FlakyLog(str(tmp_path / "log.bin"))
