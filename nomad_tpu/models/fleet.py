@@ -1064,10 +1064,18 @@ class UsageMirror:
         hold, so a concurrent worker cannot advance the mirror between
         the sync and the view (the view must reflect exactly this eval's
         snapshot).  Returns None when the snapshot is older than the
-        mirror — the caller falls back to a from-scratch build.  The
-        view's device-usage attachment resolves after the lock releases
-        (_attach_device) so the first-use upload never serializes other
-        workers' syncs."""
+        mirror — the caller falls back to a from-scratch build
+        (``build_usage`` over every allocation in the store, counted
+        as ``usage_walks``).  One caller can still see None: a
+        scheduler whose snapshot another worker's sync has passed (a
+        plain ``Worker``'s system eval beside the fused runner, or the
+        reverse).  The fused runner never does that to itself: each of
+        its fused rounds and each of its one-by-one re-plans starts
+        from a snapshot taken after its own last commit
+        (``BatchEvalRunner.process``).  The view's device-usage
+        attachment resolves after the lock releases (_attach_device)
+        so the first-use upload never serializes other workers'
+        syncs."""
         t = state._t
         with self._lock:
             if not self._sync_locked(t):

@@ -99,11 +99,19 @@ class BatchEvalRunner:
     (``state_refresh``) so they see the earlier round's commits — without a
     refresh hook the leftovers would double-place, so they are then failed
     rather than silently over-scheduled.
+
+    Retries (``process``): with a refresh hook, lanes whose plans came
+    back partial re-plan together in up to ``FUSED_RETRY_ROUNDS`` fused
+    rounds, each on a snapshot taken after the round before it; the
+    stragglers then re-plan one by one, each on a snapshot taken right
+    before it, so it sees every earlier straggler's commits and plans
+    once.  Without a hook there are no rounds: a partial plan re-plans
+    at once on the state its scheduler holds by then (the snapshot its
+    own ``submit_plan`` handed back).
     """
 
-    # Fused retry rounds before the per-eval sequential fallback: 1 =
-    # collect-then-serial (each retry sees every earlier retry's
-    # commits).
+    # Fused rounds a batch may run (the first included) before its
+    # stragglers re-plan one by one, each on the store as it is then.
     FUSED_RETRY_ROUNDS = 2
 
     def __init__(self, state, planner,
@@ -124,6 +132,16 @@ class BatchEvalRunner:
         # placed; the twin runs one call a lane, so its lanes ARE
         # ``host_dispatches``.
         self.device_lanes = 0
+        # One-by-one re-plans (``_retry_sequential``) and the attempts
+        # they took: equal while every re-plan starts from a snapshot
+        # that holds the re-plans before it.
+        self.replans = 0
+        self.replan_attempts = 0
+        # Its schedulers' ``usage_walks`` (scheduler/jax_binpack.py):
+        # views built from every allocation in the store.  A snapshot
+        # older than the usage mirror is another worker's doing, never
+        # this runner's own.
+        self.usage_walks = 0
         # Finish: per-node network states built, and how many of those
         # walked the node's allocations because the usage mirror's
         # occupancy could not serve them (nomad.finish.*).
@@ -136,13 +154,16 @@ class BatchEvalRunner:
 
     def _note_dispatch(self, sched) -> None:
         """Fold one scheduler's own kernel-call counts (its single-eval
-        dispatches and finish-loop host re-plans) into the mix."""
+        dispatches and finish-loop host re-plans) and its whole-store
+        usage walks into the mix."""
         calls = sched.kernel_calls
         self.host_dispatches += calls["host"]
         self.device_dispatches += calls["device"]
         self.sharded_dispatches += calls["sharded"]
         self.device_lanes += calls["device"]
         sched.kernel_calls = dict.fromkeys(calls, 0)
+        self.usage_walks += sched.usage_walks
+        sched.usage_walks = 0
 
     def _note_finish(self, scheds: list) -> dict:
         """Fold the schedulers' node-init counts into nomad.finish.*;
@@ -164,6 +185,9 @@ class BatchEvalRunner:
             "fused_batches": self.fused_batches,
             "host_lanes": self.host_dispatches,
             "device_lanes": self.device_lanes,
+            "replans": self.replans,
+            "replan_attempts": self.replan_attempts,
+            "usage_walks": self.usage_walks,
         }
 
     def finish_stats(self) -> dict:
@@ -252,10 +276,13 @@ class BatchEvalRunner:
             # rejected re-plan TOGETHER against a refreshed snapshot —
             # under contention the applier's serialized conflicts, not
             # planning, dominate, and one fused round retries them all
-            # for one dispatch.  Without a refresh hook (or for the
-            # stragglers after the round cap) the exact per-eval
-            # sequential retry gives the same terminal guarantee as the
-            # single-eval worker path.
+            # for one dispatch.  Without a refresh hook ``_process``
+            # retries each such lane itself, at once; with one, the
+            # stragglers after the round cap take the same exact
+            # per-eval retry (the single-eval worker path's terminal
+            # guarantee), each from the store as it is by then: the
+            # stragglers before it have committed, and a snapshot that
+            # lacks them would send it to the nodes they just filled.
             rounds = self.FUSED_RETRY_ROUNDS \
                 if self.state_refresh is not None else 1
             for _ in range(rounds):
@@ -266,6 +293,7 @@ class BatchEvalRunner:
                 pending = retries
                 self.state = self.state_refresh()
             for ev in pending:
+                self.state = self.state_refresh()
                 self._retry_sequential(self.state, ev)
 
     def _retry_sequential(self, state, ev: Evaluation) -> None:
@@ -274,10 +302,16 @@ class BatchEvalRunner:
                                     batch=(ev.type == "batch"))
         t0 = _tnow()
         retry.process(ev)
+        self.replans += 1
+        self.replan_attempts += retry.attempts
         # The kernel calls of this re-plan by engine (each plans the
-        # eval once more: a lane of its own), before they are folded.
+        # eval once more: a lane of its own), how often ``retry_max``
+        # ran it and the views it built by walking the whole store,
+        # before they are folded.
         calls = {"host_calls": retry.kernel_calls["host"],
-                 "device_calls": retry.kernel_calls["device"]} \
+                 "device_calls": retry.kernel_calls["device"],
+                 "attempts": retry.attempts,
+                 "usage_walks": retry.usage_walks} \
             if trace_mod.ENABLED else {}
         self._note_dispatch(retry)
         self._note_finish([retry])

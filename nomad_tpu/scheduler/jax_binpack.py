@@ -585,6 +585,17 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         # a mesh; the finish loop's exact re-plan counts as "host").
         # BatchEvalRunner folds these into its dispatch mix.
         self.kernel_calls = {"host": 0, "device": 0, "sharded": 0}
+        # Times ``process`` ran ``_process`` (``retry_max``'s attempts).
+        self.attempts = 0
+        # Usage views built by walking every allocation in the store
+        # (``build_usage``) where the usage mirror could not serve
+        # them: the snapshot was older than the mirror, or the finish
+        # loop re-planned the rest of a diverged plan.
+        self.usage_walks = 0
+
+    def _process(self) -> bool:
+        self.attempts += 1
+        return super()._process()
 
     def _compute_placements(self, place: list) -> None:
         args = self._prepare_device(place)
@@ -927,6 +938,7 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         view = mirror_for(statics).view_at(self.state, self.plan,
                                            self.job.id)
         if view is None:
+            self.usage_walks += 1
             view = build_usage(statics, self._proposed_allocs_all(),
                                job_id=self.job.id)
 
@@ -1425,6 +1437,7 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         from nomad_tpu.ops.binpack_host import place_sequence_host
 
         self.kernel_calls["host"] += 1
+        self.usage_walks += 1
         statics = args.statics
         view = build_usage(statics, self._proposed_allocs_all(),
                            job_id=self.job.id)

@@ -1,9 +1,15 @@
 """Batched optimistic scheduling: many evals fused into one dispatch."""
 from __future__ import annotations
 
+import pytest
+
 import nomad_tpu.mock as mock
-from nomad_tpu.scheduler import Harness
+from nomad_tpu.models import fleet
+from nomad_tpu.obs import trace
+from nomad_tpu.scheduler import Harness, jax_binpack
 from nomad_tpu.scheduler.batch import BatchEvalRunner
+from nomad_tpu.scheduler.harness import VerifyingPlanner
+from nomad_tpu.scheduler.jax_binpack import JaxBinPackScheduler
 from nomad_tpu.structs import (
     EVAL_TRIGGER_JOB_REGISTER,
     JOB_TYPE_SERVICE,
@@ -205,3 +211,167 @@ def test_fused_dispatch_rides_the_mesh_on_multi_device(monkeypatch):
                      for p in h2.plans]
     assert mesh_counts == single_counts == [4, 4, 4, 4]
     assert all(e.status == "complete" for e in h.evals)
+
+
+# ---------------------------------------------------------------------------
+# One-by-one re-plans: each starts from the store as it is by then.
+# ---------------------------------------------------------------------------
+
+def _contended_storm(n_jobs: int = 8, n_nodes: int = 12):
+    """A real store behind the applier's own verify
+    (``VerifyingPlanner``: partial accept, refresh index, a fresh
+    snapshot with every partial result) and jobs of 2 copies of
+    1,500 MHz on ``mock.node``s that hold two such copies each: every
+    lane of a fused round bin-packs the same nodes, two plans a node
+    commit, and the rest come back partial."""
+    h = Harness()
+    h.planner = VerifyingPlanner(h)
+    nodes = [mock.node(i) for i in range(n_nodes)]
+    for n in nodes:
+        h.state.upsert_node(h.next_index(), n)
+    jobs = []
+    for _ in range(n_jobs):
+        j = mock.job()
+        j.task_groups[0].count = 2
+        j.task_groups[0].tasks[0].resources.cpu = 1500
+        h.state.upsert_job(h.next_index(), j)
+        jobs.append(j)
+    return h, nodes, jobs
+
+
+def _assert_placed_exactly(h, nodes, jobs) -> None:
+    """Every eval complete, every job's count placed, every node's
+    committed allocations fit it."""
+    assert [e.status for e in h.evals] == ["complete"] * len(jobs)
+    for j in jobs:
+        live = [a for a in h.state.allocs_by_job(j.id)
+                if a.node_id and not a.terminal_status()]
+        assert len(live) == j.task_groups[0].count, j.id
+    for n in nodes:
+        live = [a for a in h.state.allocs_by_node(n.id)
+                if not a.terminal_status()]
+        fit, dim, _ = allocs_fit(n, live)
+        assert fit, (n.id, dim)
+
+
+def _no_usage_walk(monkeypatch) -> None:
+    def boom(*_a, **_kw):
+        raise AssertionError("build_usage walked the whole store")
+    monkeypatch.setattr(fleet, "build_usage", boom)
+    monkeypatch.setattr(jax_binpack, "build_usage", boom)
+
+
+def test_stragglers_plan_once_from_the_store_as_it_is(monkeypatch):
+    """With a refresh hook the evals still partial after the fused
+    rounds re-plan one by one, each on a snapshot that holds the
+    re-plans before it: one attempt each, the usage mirror serves every
+    view, and nobody walks the store."""
+    _no_usage_walk(monkeypatch)
+    h, nodes, jobs = _contended_storm()
+    with trace.tracing(seed=34) as tracer:
+        runner = BatchEvalRunner(h.state.snapshot(), h,
+                                 state_refresh=h.snapshot)
+        runner.process([make_eval(j) for j in jobs])
+        retries = [s["tags"] for s in tracer.snapshot()
+                   if s["name"] == "sched.retry"]
+    assert len(retries) >= 3, "the storm left too few stragglers"
+    assert {t["attempts"] for t in retries} == {1}
+    assert {t["usage_walks"] for t in retries} == {0}
+    assert {t["host_calls"] + t["device_calls"] for t in retries} == {1}
+    stats = runner.stats()
+    assert stats["replans"] == stats["replan_attempts"] == len(retries)
+    assert stats["usage_walks"] == 0
+    assert stats["fused_batches"] == BatchEvalRunner.FUSED_RETRY_ROUNDS
+    _assert_placed_exactly(h, nodes, jobs)
+
+
+def test_storm_without_a_refresh_hook_ends_as_before():
+    """No hook (the harness, the graft entry's dry run): one fused
+    round, then every partial lane re-plans at once on the snapshot
+    its own submit handed back, through upstream's attempt limit, to
+    the same end."""
+    h, nodes, jobs = _contended_storm()
+    runner = BatchEvalRunner(h.state.snapshot(), h)
+    runner.process([make_eval(j) for j in jobs])
+    stats = runner.stats()
+    assert stats["fused_batches"] == 1
+    assert stats["replan_attempts"] >= stats["replans"] >= 3
+    _assert_placed_exactly(h, nodes, jobs)
+
+
+def test_refresh_hook_is_taken_once_a_round_and_once_a_straggler():
+    h, nodes, jobs = _contended_storm()
+    taken = []
+
+    def refresh():
+        taken.append(h.state.latest_index())
+        return h.state.snapshot()
+
+    runner = BatchEvalRunner(h.state.snapshot(), h, state_refresh=refresh)
+    runner.process([make_eval(j) for j in jobs])
+    stragglers = runner.stats()["replans"]
+    assert stragglers >= 3
+    assert len(taken) == BatchEvalRunner.FUSED_RETRY_ROUNDS + stragglers
+    # Each straggler's snapshot holds the commit of the one before it.
+    per_straggler = taken[BatchEvalRunner.FUSED_RETRY_ROUNDS:]
+    assert per_straggler == sorted(set(per_straggler))
+    _assert_placed_exactly(h, nodes, jobs)
+
+
+def test_a_quiet_batch_takes_no_refresh_and_no_replan():
+    """Nothing partial: the hook is never called, no counter moves."""
+    h = Harness()
+    h.planner = VerifyingPlanner(h)
+    nodes = [mock.node(i) for i in range(8)]
+    for n in nodes:
+        h.state.upsert_node(h.next_index(), n)
+    job = mock.job()
+    job.task_groups[0].count = 2
+    h.state.upsert_job(h.next_index(), job)
+    taken = []
+    runner = BatchEvalRunner(
+        h.state.snapshot(), h,
+        state_refresh=lambda: taken.append(1) or h.state.snapshot())
+    runner.process([make_eval(job)])
+    assert taken == []
+    stats = runner.stats()
+    assert (stats["replans"], stats["replan_attempts"],
+            stats["usage_walks"], stats["fused_batches"]) == (0, 0, 0, 1)
+    _assert_placed_exactly(h, nodes, [job])
+
+
+@pytest.mark.parametrize("through_runner", [False, True],
+                         ids=["scheduler", "runner"])
+def test_snapshot_older_than_the_mirror_walks_the_store(through_runner):
+    """The walk is still there for whom it is meant: a scheduler whose
+    snapshot another worker's sync has passed builds its view from
+    every allocation of ITS snapshot (one ``usage_walks``), plans what
+    fits there, and ends placed once the applier has refreshed it."""
+    h, nodes, jobs = _contended_storm(n_jobs=4)
+    first, second, third, late = jobs
+    h.process("jax-binpack", make_eval(first))
+    old = h.state.snapshot()
+    # The mirror syncs to the snapshot a plan is MADE on: the third
+    # job's holds the second's commit, which ``old`` lacks.
+    h.process("jax-binpack", make_eval(second))
+    h.process("jax-binpack", make_eval(third))
+    by_id = {n.id: n for n in nodes}
+    n_plans = len(h.plans)
+    if through_runner:
+        runner = BatchEvalRunner(old, h)
+        runner.process([make_eval(late)])
+        assert runner.stats()["usage_walks"] == 1
+    else:
+        sched = JaxBinPackScheduler(old, h, batch=False)
+        sched.process(make_eval(late))
+        assert sched.usage_walks == 1
+        assert sched.attempts == len(h.plans) - n_plans
+    # The plan made on the old snapshot fits the old snapshot.
+    plan = h.plans[n_plans]
+    assert sum(len(v) for v in plan.node_allocation.values()) == 2
+    for node_id, placed in plan.node_allocation.items():
+        held = [a for a in old.allocs_by_node(node_id)
+                if not a.terminal_status()]
+        fit, dim, _ = allocs_fit(by_id[node_id], held + placed)
+        assert fit, (node_id, dim)
+    _assert_placed_exactly(h, nodes, jobs)

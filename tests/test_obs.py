@@ -1122,6 +1122,60 @@ class TestWholePathSpans:
         assert after[0] - before[0] == sum(t["claims"] for t in windows)
         assert after[1] - before[1] == sum(t["walked"] for t in windows)
 
+    def test_replan_counters_add_up_to_the_retry_spans(self):
+        """A batch whose lanes bin-pack the same nodes leaves
+        stragglers: ``nomad.batch_runner.replans``, ``.replan_attempts``
+        and ``.usage_walks`` under /v1/agent/metrics move by what the
+        ``sched.retry`` spans' ``attempts`` / ``usage_walks`` tags say,
+        and with one runner every re-plan takes one attempt."""
+        agent, api = _http_agent()
+        srv = agent.server
+
+        def counters() -> dict:
+            code, body = _http_get(
+                api, "/v1/agent/metrics?filter=batch_runner")
+            assert code == 200
+            return {k: body["providers"][f"nomad.batch_runner.{k}"]
+                    for k in ("replans", "replan_attempts",
+                              "usage_walks", "host_dispatches")}
+
+        try:
+            warm = api.job_register(_job(1))["eval_id"]
+            assert _await_eval(api, warm).status == "complete"
+            before = counters()
+            assert before["replans"] == before["replan_attempts"] == 0
+            for w in srv.workers:
+                w.set_pause(True)   # the evals gather into ONE batch
+            time.sleep(0.6)  # sleep-ok: workers leave their dequeue
+            with trace.tracing(seed=34) as tracer:
+                eval_ids = []
+                for _ in range(6):
+                    # Two copies a job, two such copies a node: two
+                    # plans a fused round commit whole.
+                    job = _job(1, count=2)
+                    job.task_groups[0].tasks[0].resources.cpu = 1500
+                    eval_ids.append(api.job_register(job)["eval_id"])
+                for w in srv.workers:
+                    w.set_pause(False)
+                assert {_await_eval(api, e).status
+                        for e in eval_ids} == {"complete"}
+                after = counters()
+                retries = [_tags(s) for s in tracer.snapshot()
+                           if s["name"] == "sched.retry"]
+        finally:
+            for w in srv.workers:
+                w.set_pause(False)
+            agent.shutdown()
+        assert retries, "the storm left no straggler"
+        assert after["replans"] - before["replans"] == len(retries)
+        assert after["replan_attempts"] - before["replan_attempts"] == \
+            sum(t["attempts"] for t in retries) == len(retries)
+        assert after["usage_walks"] == before["usage_walks"] == \
+            sum(t["usage_walks"] for t in retries) == 0
+        assert after["host_dispatches"] - before["host_dispatches"] >= \
+            6 + len(retries)
+        assert len(srv.fsm.state.allocs()) == 1 + 6 * 2
+
     def test_leaf_spans_cover_the_interval(self):
         """The chain is contiguous: at most a tenth of socket-readable
         -> answer-written lies under no leaf span (the benchmark's
