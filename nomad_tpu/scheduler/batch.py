@@ -69,7 +69,7 @@ def _lane_spans(name: str, scheds, t0: float, t1: float,
 
 
 def dispatch_tags(rounds_mode: bool, rounds: int, engine: str,
-                  cost: int, lanes: int) -> dict:
+                  cost: int, lanes: int, slots: int) -> dict:
     """What a lane's ``sched.dispatch`` span says of the kernel it rode
     (nothing while tracing is off).  ``mode`` is the kernel the
     dispatch ran — ``rounds`` (one scoring pass per slot and top-k
@@ -81,12 +81,14 @@ def dispatch_tags(rounds_mode: bool, rounds: int, engine: str,
     ``scheduler/executor.py`` chose; ``cost`` is the estimate the
     choice was made on (``JaxBinPackScheduler.host_wins``) and
     ``lanes`` how many lanes shared that choice (a fused window's, 1
-    for a lone eval)."""
+    for a lone eval); ``slots`` is THIS lane's real slot count
+    (``DeviceArgs.n_groups``: its task groups after those of one ask
+    have deduped), where ``cost`` counts the padded axis."""
     if not trace_mod.ENABLED:
         return {}
     return {"mode": "rounds" if rounds_mode else "sequence",
             "rounds": rounds if rounds_mode else 0, "engine": engine,
-            "cost": cost, "lanes": lanes}
+            "cost": cost, "lanes": lanes, "slots": slots}
 
 
 class BatchEvalRunner:
@@ -132,6 +134,12 @@ class BatchEvalRunner:
         # placed; the twin runs one call a lane, so its lanes ARE
         # ``host_dispatches``.
         self.device_lanes = 0
+        # The slot axis of every kernel call, either engine: the real
+        # slots its lanes carried, and the slots of the padded axes it
+        # was shaped to (``g_pad`` a lane; a fused device window scans
+        # ``b_pad`` x ``g_pad``, the twin skips the padding).
+        self.slots = 0
+        self.padded_slots = 0
         # One-by-one re-plans (``_retry_sequential``) and the attempts
         # they took: equal while every re-plan starts from a snapshot
         # that holds the re-plans before it.
@@ -162,6 +170,9 @@ class BatchEvalRunner:
         self.sharded_dispatches += calls["sharded"]
         self.device_lanes += calls["device"]
         sched.kernel_calls = dict.fromkeys(calls, 0)
+        self.slots += sched.kernel_slots["real"]
+        self.padded_slots += sched.kernel_slots["padded"]
+        sched.kernel_slots = dict.fromkeys(sched.kernel_slots, 0)
         self.usage_walks += sched.usage_walks
         sched.usage_walks = 0
 
@@ -185,6 +196,8 @@ class BatchEvalRunner:
             "fused_batches": self.fused_batches,
             "host_lanes": self.host_dispatches,
             "device_lanes": self.device_lanes,
+            "slots": self.slots,
+            "padded_slots": self.padded_slots,
             "replans": self.replans,
             "replan_attempts": self.replan_attempts,
             "usage_walks": self.usage_walks,
@@ -234,13 +247,20 @@ class BatchEvalRunner:
                                          eval_type=ev.type)
             t0 = tracer.now()
             sid = self.stage_span[ev.id] = tracer.new_id()
+            sched = None
             try:
-                return self._begin_eval_inner(ev, finish_noop)
+                sched = self._begin_eval_inner(ev, finish_noop)
+                return sched
             finally:
                 del self.stage_span[ev.id]
+                # ``slots``: the real slots this lane takes to a kernel
+                # (none where the eval needs no placement).
+                tags = {"slots": sched.deferred[1].n_groups} \
+                    if sched is not None and sched.deferred is not None \
+                    else {}
                 tracer.record("sched.begin", t0, tracer.now() - t0,
                               parent_ctx=ev.trace, span_id=sid,
-                              eval_id=ev.id)
+                              eval_id=ev.id, **tags)
         return self._begin_eval_inner(ev, finish_noop)
 
     def _begin_eval_inner(self, ev: Evaluation, finish_noop: bool = True):
@@ -306,12 +326,16 @@ class BatchEvalRunner:
         self.replan_attempts += retry.attempts
         # The kernel calls of this re-plan by engine (each plans the
         # eval once more: a lane of its own), how often ``retry_max``
-        # ran it and the views it built by walking the whole store,
-        # before they are folded.
+        # ran it, the views it built by walking the whole store, and
+        # the seconds its ``dispatch_host`` calls spent in the numpy
+        # twin with the real slots they carried (a re-plan has no
+        # ``sched.dispatch`` span of its own), before they are folded.
         calls = {"host_calls": retry.kernel_calls["host"],
                  "device_calls": retry.kernel_calls["device"],
                  "attempts": retry.attempts,
-                 "usage_walks": retry.usage_walks} \
+                 "usage_walks": retry.usage_walks,
+                 "twin_s": retry.twin_s,
+                 "twin_slots": retry.twin_slots} \
             if trace_mod.ENABLED else {}
         self._note_dispatch(retry)
         self._note_finish([retry])
@@ -409,9 +433,21 @@ class BatchEvalRunner:
         self.device_lanes += B
         if mesh is not None:
             self.sharded_dispatches += 1
-        kernel_tags = dispatch_tags(rounds_ok, rounds,
-                                    "device" if mesh is None else "sharded",
-                                    fused_cost, B)
+        self.slots += sum(a.n_groups for _, _, a in pending)
+        self.padded_slots += B_pad * g_max
+        engine = "device" if mesh is None else "sharded"
+
+        def dispatch_spans() -> None:
+            """One ``sched.dispatch`` a lane over the window's one
+            interval; the lanes differ in ``slots`` alone."""
+            if not trace_mod.ENABLED:
+                return
+            t1 = _tnow()
+            for sched, _p, a in pending:
+                _lane_spans("sched.dispatch", [sched], t_disp, t1,
+                            fused=B, **dispatch_tags(
+                                rounds_ok, rounds, engine, fused_cost, B,
+                                a.n_groups))
         # All fused lanes share the same snapshot base usage (fast-path
         # contract above); use the resident device copies when available
         # (single-device mirror copy, or on a mesh the sharded statics +
@@ -478,8 +514,7 @@ class BatchEvalRunner:
                         feasible, asks, distinct, counts, penalty,
                         k_cap=k_cap, rounds=rounds)
                 chosen_s, score_s = fetch_results(chosen_s, score_s)
-            _lane_spans("sched.dispatch", [s for s, _p, _a in pending],
-                        t_disp, _tnow(), fused=B, **kernel_tags)
+            dispatch_spans()
             done = []
             for b, (sched, place, args) in enumerate(pending):
                 chosen, scores = rounds_to_placements(
@@ -508,8 +543,7 @@ class BatchEvalRunner:
                         feasible, asks, distinct, group_idx, valid,
                         penalty)
                 chosen, scores = fetch_results(chosen, scores)
-            _lane_spans("sched.dispatch", [s for s, _p, _a in pending],
-                        t_disp, _tnow(), fused=B, **kernel_tags)
+            dispatch_spans()
             self._finish_window(
                 [(sched, place, args, chosen[b], scores[b])
                  for b, (sched, place, args) in enumerate(pending)],
@@ -531,8 +565,6 @@ class BatchEvalRunner:
         statics = pending[0][2].statics
         base_usage = pending[0][2].view.usage  # host array
         n_real = statics.n_real
-        kernel_tags = dispatch_tags(rounds_ok, rounds, "host", fused_cost,
-                                    len(pending))
         done = []
         for sched, place, args in pending:
             t_disp = _tnow()
@@ -551,8 +583,12 @@ class BatchEvalRunner:
                     args.distinct, args.group_idx, args.valid,
                     float(args.penalty), n_real=n_real)
             _lane_spans("sched.dispatch", [sched], t_disp, _tnow(),
-                        host=True, **kernel_tags)
+                        host=True, **dispatch_tags(
+                            rounds_ok, rounds, "host", fused_cost,
+                            len(pending), args.n_groups))
             self.host_dispatches += 1
+            self.slots += args.n_groups
+            self.padded_slots += args.g_pad
             done.append((sched, place, args, chosen, scores))
         self._finish_window(done, retries)
 
@@ -579,7 +615,7 @@ class BatchEvalRunner:
             args.rounds_eligible, args.rounds,
             "host" if sched.dispatched_host else
             "sharded" if sched.dispatched_sharded else "device",
-            sched.dispatch_cost(args), 1))
+            sched.dispatch_cost(args), 1, args.n_groups))
         sched.finish_deferred(place, args, chosen, scores)
         self._note_dispatch(sched)
         _lane_spans("sched.finish", [sched], t1, _tnow(),

@@ -32,19 +32,27 @@ said host, or under ``auto`` lanes x steps x nodes stayed within
 ``HOST_SINGLE_SHOT_COST``, steps being slots x rounds under top-k
 rounds and placements on the sequence kernel), ``device`` (the XLA
 kernel on one chip) or ``sharded`` (the same over a mesh), beside
-``mode``, ``rounds``, the estimate itself (``cost``) and the ``lanes``
-that shared the choice;
+``mode``, ``rounds``, the estimate itself (``cost``), the ``lanes``
+that shared the choice and the lane's own REAL slot count (``slots``,
+also on its ``sched.begin``; a one-by-one re-plan has no
+``sched.dispatch`` and states ``twin_s`` / ``twin_slots`` on its
+``sched.retry``);
 ``nomad.batch_runner.{host,device,sharded}_dispatches`` count the same
-choice always, and ``nomad.batch_runner.{host,device}_lanes`` the lanes
+choice always, ``nomad.batch_runner.{host,device}_lanes`` the lanes
 each engine placed (a fused device window is one dispatch of many
-lanes).
+lanes), and ``nomad.batch_runner.slots`` / ``.padded_slots`` the real
+slots of every kernel call beside the padded slot axis it was shaped
+to.
 
 Where the break-even falls.  A fused window scans its PADDED slot axis
 (``g_pad``, at least 8), so a window of single-group lanes costs lanes x
 8 x nodes: it crosses ``HOST_SINGLE_SHOT_COST`` = 2^25 above 32 lanes
 at 131,072 nodes and above 419 at 10,000 (the runner fuses at most 64).
-A lone eval counts its real slots: one group on 131,072 nodes is 2^17,
-inside ``HOST_ALWAYS_COST``.  Measured on a v5e at 131,072 nodes
+At the 100,000 nodes of ``fleet100k.stacks`` a window leaves the twin
+at 42 lanes, whether its lanes carry one real slot or that cell's
+three.  A lone eval counts its real slots: one group on 131,072 nodes
+is 2^17, inside ``HOST_ALWAYS_COST``; a three-slot stack on 100,000 is
+300,000, inside ``HOST_SINGLE_SHOT_COST``.  Measured on a v5e at 131,072 nodes
 (PERF.md section 6, PR 33): the twin takes 8.4-10.2 ms a lane (539 ms
 for 64 lanes); a fused window on the kernel takes 165 ms at 64 lanes
 (61 ms from enqueue to results, 50 ms of it device time, 101 MB

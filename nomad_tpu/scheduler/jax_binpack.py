@@ -585,6 +585,14 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         # a mesh; the finish loop's exact re-plan counts as "host").
         # BatchEvalRunner folds these into its dispatch mix.
         self.kernel_calls = {"host": 0, "device": 0, "sharded": 0}
+        # The slot axis of those calls: the real slots they carried
+        # (``n_groups``) and the padded axis they were shaped to.
+        self.kernel_slots = {"real": 0, "padded": 0}
+        # Tracing only: seconds ``dispatch_host`` spent in the numpy
+        # twin and the real slots of those calls (the ``sched.retry``
+        # span's ``twin_s`` / ``twin_slots``).
+        self.twin_s = 0.0
+        self.twin_slots = 0
         # Times ``process`` ran ``_process`` (``retry_max``'s attempts).
         self.attempts = 0
         # Usage views built by walking every allocation in the store
@@ -596,6 +604,12 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
     def _process(self) -> bool:
         self.attempts += 1
         return super()._process()
+
+    def _count_call(self, engine: str, args: "DeviceArgs") -> None:
+        """One placement-kernel call of this scheduler's own."""
+        self.kernel_calls[engine] += 1
+        self.kernel_slots["real"] += args.n_groups
+        self.kernel_slots["padded"] += args.g_pad
 
     def _compute_placements(self, place: list) -> None:
         args = self._prepare_device(place)
@@ -714,8 +728,10 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
                                                 place_sequence_host)
 
         self.dispatched_sharded = False
-        self.kernel_calls["host"] += 1
+        self._count_call("host", args)
         statics = args.statics
+        traced = trace_mod.ENABLED
+        t0 = time.perf_counter() if traced else 0.0
         if args.rounds_eligible:
             chosen, scores, _ = place_rounds_host(
                 statics.capacity, statics.reserved, args.view.usage,
@@ -729,6 +745,9 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
                 args.view.job_counts, args.feasible_h, args.asks,
                 args.distinct, args.group_idx, args.valid, args.penalty,
                 n_real=statics.n_real)
+        if traced:
+            self.twin_s += time.perf_counter() - t0
+            self.twin_slots += args.n_groups
         return chosen, scores
 
     def dispatch_device(self, args: "DeviceArgs",
@@ -750,7 +769,7 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
             self.dispatched_host = True
             return self.dispatch_host(args)
         self.dispatched_host = False
-        self.kernel_calls["device"] += 1
+        self._count_call("device", args)
         from nomad_tpu.parallel.mesh import dispatch_mesh
 
         mesh = dispatch_mesh(1, args.statics.n_pad)
@@ -1436,7 +1455,7 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         device runs, so results splice straight into the finish loop)."""
         from nomad_tpu.ops.binpack_host import place_sequence_host
 
-        self.kernel_calls["host"] += 1
+        self._count_call("host", args)
         self.usage_walks += 1
         statics = args.statics
         view = build_usage(statics, self._proposed_allocs_all(),

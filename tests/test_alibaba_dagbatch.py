@@ -270,12 +270,13 @@ def test_a_slot_with_more_copies_than_nodes_takes_a_second_round():
                 runner.process([_eval(spec)])
                 lanes = [s["tags"] for s in tracer.snapshot()
                          if s["name"] == "sched.dispatch"]
-            # One lane, one deduped slot on a padded axis of 8, two
-            # rounds, 256 nodes: the estimate the choice was made on.
+            # One lane of 16 real slots (no two groups share an ask; a
+            # padded axis of 16), two rounds, 128 nodes: the estimate
+            # the choice was made on.
             assert lanes == [{
                 "eval_id": lanes[0]["eval_id"], "host": True,
                 "mode": "rounds", "rounds": 2, "engine": "host",
-                "cost": 1 * 2 * 8 * 256, "lanes": 1}]
+                "cost": 1 * 2 * 16 * N_NODES, "lanes": 1, "slots": 16}]
         else:
             h.process("batch", _eval(spec))
         _assert_every_node_fits(h)

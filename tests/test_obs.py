@@ -1172,9 +1172,57 @@ class TestWholePathSpans:
             sum(t["attempts"] for t in retries) == len(retries)
         assert after["usage_walks"] == before["usage_walks"] == \
             sum(t["usage_walks"] for t in retries) == 0
+        # The twin's seconds and real slots of each re-plan ride on its
+        # span: it has no ``sched.dispatch`` of its own.
+        assert all(t["twin_slots"] == t["host_calls"] == 1
+                   and 0.0 < t["twin_s"] for t in retries)
         assert after["host_dispatches"] - before["host_dispatches"] >= \
             6 + len(retries)
         assert len(srv.fsm.state.allocs()) == 1 + 6 * 2
+
+    def test_slot_tags_and_counters_say_how_many_slots_a_lane_carried(self):
+        """A job of three groups whose asks differ is a lane of three
+        REAL kernel slots; one whose two groups share an ask dedupes to
+        one.  ``sched.begin`` and ``sched.dispatch`` say so per lane
+        (``slots``), and ``nomad.batch_runner.slots`` / ``.padded_slots``
+        under /v1/agent/metrics move by the real slots of every kernel
+        call and by the padded axis (8) each was shaped to."""
+        agent, api = _http_agent()
+
+        def counters() -> dict:
+            code, body = _http_get(
+                api, "/v1/agent/metrics?filter=batch_runner")
+            assert code == 200
+            return {k: body["providers"][f"nomad.batch_runner.{k}"]
+                    for k in ("slots", "padded_slots", "host_dispatches",
+                              "device_dispatches")}
+
+        try:
+            warm = api.job_register(_job(1))["eval_id"]
+            assert _await_eval(api, warm).status == "complete"
+            with trace.tracing(seed=35) as tracer:
+                before = counters()
+                stack = _job(3, count=2)
+                for g, tg in enumerate(stack.task_groups):
+                    tg.tasks[0].resources.memory_mb = 32 * (g + 1)
+                wanted = {}
+                for job, slots in ((stack, 3), (_job(2, count=2), 1)):
+                    eval_id = api.job_register(job)["eval_id"]
+                    assert _await_eval(api, eval_id).status == "complete"
+                    wanted[eval_id] = slots
+                after = counters()
+                spans = tracer.snapshot()
+        finally:
+            agent.shutdown()
+        for name in ("sched.begin", "sched.dispatch"):
+            got = {_tags(s)["eval_id"]: _tags(s)["slots"] for s in spans
+                   if s["name"] == name}
+            assert got == wanted, name
+        calls = sum(after[k] - before[k]
+                    for k in ("host_dispatches", "device_dispatches"))
+        assert calls == 2
+        assert after["slots"] - before["slots"] == 3 + 1
+        assert after["padded_slots"] - before["padded_slots"] == 2 * 8
 
     def test_leaf_spans_cover_the_interval(self):
         """The chain is contiguous: at most a tenth of socket-readable
