@@ -33,6 +33,7 @@ import itertools
 import threading
 
 from dataclasses import dataclass, field
+from functools import cached_property
 from typing import Optional
 
 import numpy as np
@@ -222,6 +223,16 @@ class FleetStatics:
     gen: int = field(default_factory=lambda: next(_FLEET_GEN))
     # Lazily attached incremental usage mirror (see mirror_for()).
     mirror: Optional["UsageMirror"] = None
+
+    @cached_property
+    def min_available(self) -> tuple[float, float]:
+        """The least cpu and memory any real node has left once its
+        reserved share is taken: what the prep's gain bound divides an
+        ask by.  A constant of the fleet generation, read once."""
+        if not self.n_real:
+            return 1.0, 1.0
+        avail = self.capacity[:self.n_real] - self.reserved[:self.n_real]
+        return float(avail[:, 0].min()), float(avail[:, 1].min())
 
     def device_capacity_reserved(self):
         from nomad_tpu.parallel.devices import ensure_on_default, \

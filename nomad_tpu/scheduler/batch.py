@@ -150,6 +150,11 @@ class BatchEvalRunner:
         # older than the usage mirror is another worker's doing, never
         # this runner's own.
         self.usage_walks = 0
+        # Its schedulers' ``fit_rows`` / ``fit_rows_full``: rows the
+        # preps' fit walks (``_fit_rounds``) examined, and the rows
+        # they would have examined had none stopped early.
+        self.fit_rows = 0
+        self.fit_rows_full = 0
         # Finish: per-node network states built, and how many of those
         # walked the node's allocations because the usage mirror's
         # occupancy could not serve them (nomad.finish.*).
@@ -163,7 +168,7 @@ class BatchEvalRunner:
     def _note_dispatch(self, sched) -> None:
         """Fold one scheduler's own kernel-call counts (its single-eval
         dispatches and finish-loop host re-plans) and its whole-store
-        usage walks into the mix."""
+        usage walks and fit-walk rows into the mix."""
         calls = sched.kernel_calls
         self.host_dispatches += calls["host"]
         self.device_dispatches += calls["device"]
@@ -175,6 +180,9 @@ class BatchEvalRunner:
         sched.kernel_slots = dict.fromkeys(sched.kernel_slots, 0)
         self.usage_walks += sched.usage_walks
         sched.usage_walks = 0
+        self.fit_rows += sched.fit_rows
+        self.fit_rows_full += sched.fit_rows_full
+        sched.fit_rows = sched.fit_rows_full = 0
 
     def _note_finish(self, scheds: list) -> dict:
         """Fold the schedulers' node-init counts into nomad.finish.*;
@@ -201,6 +209,8 @@ class BatchEvalRunner:
             "replans": self.replans,
             "replan_attempts": self.replan_attempts,
             "usage_walks": self.usage_walks,
+            "fit_rows": self.fit_rows,
+            "fit_rows_full": self.fit_rows_full,
         }
 
     def finish_stats(self) -> dict:
@@ -253,9 +263,13 @@ class BatchEvalRunner:
                 return sched
             finally:
                 del self.stage_span[ev.id]
-                # ``slots``: the real slots this lane takes to a kernel
+                # ``slots``: the real slots this lane takes to a kernel;
+                # ``fit_rows`` of ``fit_rows_full``: the rows its prep's
+                # fit walk examined, of those a whole walk examines
                 # (none where the eval needs no placement).
-                tags = {"slots": sched.deferred[1].n_groups} \
+                tags = {"slots": sched.deferred[1].n_groups,
+                        "fit_rows": sched.fit_rows,
+                        "fit_rows_full": sched.fit_rows_full} \
                     if sched is not None and sched.deferred is not None \
                     else {}
                 tracer.record("sched.begin", t0, tracer.now() - t0,
@@ -326,16 +340,19 @@ class BatchEvalRunner:
         self.replan_attempts += retry.attempts
         # The kernel calls of this re-plan by engine (each plans the
         # eval once more: a lane of its own), how often ``retry_max``
-        # ran it, the views it built by walking the whole store, and
-        # the seconds its ``dispatch_host`` calls spent in the numpy
-        # twin with the real slots they carried (a re-plan has no
-        # ``sched.dispatch`` span of its own), before they are folded.
+        # ran it, the views it built by walking the whole store, the
+        # seconds its ``dispatch_host`` calls spent in the numpy twin
+        # with the real slots they carried (a re-plan has no
+        # ``sched.dispatch`` span of its own), and the rows its preps'
+        # fit walks examined, before they are folded.
         calls = {"host_calls": retry.kernel_calls["host"],
                  "device_calls": retry.kernel_calls["device"],
                  "attempts": retry.attempts,
                  "usage_walks": retry.usage_walks,
                  "twin_s": retry.twin_s,
-                 "twin_slots": retry.twin_slots} \
+                 "twin_slots": retry.twin_slots,
+                 "fit_rows": retry.fit_rows,
+                 "fit_rows_full": retry.fit_rows_full} \
             if trace_mod.ENABLED else {}
         self._note_dispatch(retry)
         self._note_finish([retry])

@@ -202,8 +202,15 @@ def fetch_results(*arrays) -> list:
     return [fetch_host(a) for a in arrays]
 
 
+# Rows of the fit walk's first block; each next block is twice the last,
+# so a fleet with little room walks every row once, in a handful of
+# blocks (five at 131,072 rows), and a fleet of at most one block runs
+# one pass.
+_FIT_BLOCK = 8192
+
+
 def _fit_rounds(statics, view, feasible_h, asks, slot_placements,
-                k_cap: int, rounds: int) -> tuple[int, bool]:
+                k_cap: int, rounds: int, tally) -> tuple[int, bool]:
     """Fit-aware rounds refresh, run on EVERY dispatch (the prep cache
     can't carry it — usage moves without the job/fleet generation
     moving).  One round places at most one copy per currently-fitting
@@ -214,6 +221,18 @@ def _fit_rounds(statics, view, feasible_h, asks, slot_placements,
     strand copies; the finish loop's sequential fallback rescues those
     exactly.  Returns (rounds, rounds_eligible); need > 16 rounds means
     the eval is scan-shaped and the sequence kernel takes it.
+
+    What it stops at: a slot's walk examines the rows in blocks from
+    row 0 and ends at the first block after which the fitting nodes
+    counted so far already give ``need <= min(rounds, 16)``.  ``need``
+    only falls as the count grows, so no further row could change what
+    is returned: the pair is the whole walk's for every input.  A slot
+    that never gets there has walked all ``n_real`` rows.
+
+    What it reports: ``tally`` (the scheduler) gains ``fit_rows``, the
+    rows examined summed over the slots, and ``fit_rows_full``,
+    ``n_real`` a slot walked: what the whole walk examines.  Both stay
+    as they were where the walk is skipped.
 
     What comes back is what the lane's ``sched.dispatch`` span reports
     (scheduler/batch.py ``dispatch_tags``): ``mode`` = ``rounds`` with
@@ -235,18 +254,32 @@ def _fit_rounds(statics, view, feasible_h, asks, slot_placements,
         # walk would cost O(slots x nodes x dims) numpy per eval for a
         # guaranteed no-op answer.
         return rounds, True
-    cap = statics.capacity[:n]
-    res = statics.reserved[:n]
-    usage = np.asarray(view.usage)[:n]
+    cap = statics.capacity
+    res = statics.reserved
+    usage = np.asarray(view.usage)
     for slot, ps in slot_placements.items():
-        fit = ((usage + res + asks[slot]) <= cap).all(axis=-1)
-        fit_count = int((fit & feasible_h[slot, :n]).sum())
+        ask = asks[slot]
+        feasible = feasible_h[slot]
+        settled = min(rounds, 16)
+        fit_count = need = lo = 0
+        block = _FIT_BLOCK
+        while lo < n:
+            hi = min(lo + block, n)
+            fit = ((usage[lo:hi] + res[lo:hi] + ask)
+                   <= cap[lo:hi]).all(axis=-1)
+            fit_count += int(np.count_nonzero(fit & feasible[lo:hi]))
+            lo, block = hi, 2 * block
+            if fit_count:
+                need = -(-len(ps) // min(fit_count, k_cap))  # ceil
+                if need <= settled:
+                    break
+        tally.fit_rows += lo
+        tally.fit_rows_full += n
         if fit_count == 0:
             # Nothing can place for this slot right now: one cheap
             # dispatch suffices — the finish fallback coalesces and
             # explains the failures.
             continue
-        need = -(-len(ps) // min(fit_count, k_cap))  # ceil
         if need > 16:
             # Scan-shaped (huge count on a tiny fitting set): the exact
             # sequence kernel takes it.
@@ -260,14 +293,14 @@ def _fit_rounds(statics, view, feasible_h, asks, slot_placements,
     return min(rounds, 16), True
 
 
-def _refresh_rounds(args: "DeviceArgs") -> "DeviceArgs":
+def _refresh_rounds(args: "DeviceArgs", tally) -> "DeviceArgs":
     """Per-dispatch rounds refinement applied to every DeviceArgs (both
     the prep-cache hit and the fresh build) — ONE call site per return
     so the policy cannot desynchronize."""
     if args.rounds_eligible:
         args.rounds, args.rounds_eligible = _fit_rounds(
             args.statics, args.view, args.feasible_h, args.asks,
-            args.slot_placements, args.k_cap, args.rounds)
+            args.slot_placements, args.k_cap, args.rounds, tally)
     return args
 
 
@@ -600,6 +633,11 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         # them: the snapshot was older than the mirror, or the finish
         # loop re-planned the rest of a diverged plan.
         self.usage_walks = 0
+        # Rows ``_fit_rounds`` examined for this scheduler's preps,
+        # summed over their slots, and ``n_real`` a slot walked: what
+        # the walk examines where no block settles its answer.
+        self.fit_rows = 0
+        self.fit_rows_full = 0
 
     def _process(self) -> bool:
         self.attempts += 1
@@ -981,7 +1019,7 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
             if feas is not None:
                 return _refresh_rounds(DeviceArgs(
                     statics=statics, view=view, start=start,
-                    feasible_d=feas, feasible_h=feas[0], **tmpl[5]))
+                    feasible_d=feas, feasible_h=feas[0], **tmpl[5]), self)
 
         # Dedupe task groups by *semantic* key (constraints + drivers + dc +
         # ask): count-expanded groups collapse to one mask row, keeping the
@@ -1075,10 +1113,7 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         counts = np.zeros(g_pad, dtype=np.int32)
         for slot, ps in slot_placements.items():
             counts[slot] = len(ps)
-        avail = statics.capacity[:statics.n_real] - \
-            statics.reserved[:statics.n_real]
-        min_cpu = float(avail[:, 0].min()) if statics.n_real else 1.0
-        min_mem = float(avail[:, 1].min()) if statics.n_real else 1.0
+        min_cpu, min_mem = statics.min_available
         eligible = statics.n_real > 0
         rounds = 1
         # top_k's k may not exceed the node axis: clamp and let extra
@@ -1117,7 +1152,7 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
                                        self.batch, feas_key, kw)
         return _refresh_rounds(DeviceArgs(
             statics=statics, view=view, start=start,
-            feasible_d=cached, feasible_h=feasible_h, **kw))
+            feasible_d=cached, feasible_h=feasible_h, **kw), self)
 
     def finish_deferred(self, place: list, args: DeviceArgs,
                         chosen: np.ndarray, scores: np.ndarray,

@@ -375,3 +375,70 @@ def test_snapshot_older_than_the_mirror_walks_the_store(through_runner):
         fit, dim, _ = allocs_fit(by_id[node_id], held + placed)
         assert fit, (node_id, dim)
     _assert_placed_exactly(h, nodes, jobs)
+
+
+# ---------------------------------------------------------------------------
+# The prep's fit walk: rows examined, as the spans and the runner say.
+# ---------------------------------------------------------------------------
+
+def test_fit_walk_rows_on_the_prep_spans_and_in_the_stats(monkeypatch):
+    """``sched.begin`` (a fused round's lane) and ``sched.retry`` (a
+    one-by-one re-plan) carry ``fit_rows`` of ``fit_rows_full``, and
+    the runner's stats hold their sum.  With the walk's first block cut
+    to four rows, a lane of the first round, on an empty fleet of
+    twelve, stops after that block; once the fleet fills a walk goes
+    further, never past the fleet, and the plans end as ever."""
+    monkeypatch.setattr(jax_binpack, "_FIT_BLOCK", 4)
+    h, nodes, jobs = _contended_storm()
+    with trace.tracing(seed=36) as tracer:
+        runner = BatchEvalRunner(h.state.snapshot(), h,
+                                 state_refresh=h.snapshot)
+        runner.process([make_eval(j) for j in jobs])
+        spans = tracer.snapshot()
+    begins = [s["tags"] for s in spans if s["name"] == "sched.begin"]
+    retries = [s["tags"] for s in spans if s["name"] == "sched.retry"]
+    assert len(begins) > len(jobs) and len(retries) >= 3
+    assert [t["fit_rows"] for t in begins[:len(jobs)]] == [4] * len(jobs)
+    for t in begins + retries:
+        assert t["fit_rows_full"] == len(nodes) * t.get("attempts", 1)
+        assert 4 <= t["fit_rows"] <= t["fit_rows_full"]
+    assert any(t["fit_rows"] > 4 for t in begins[len(jobs):] + retries)
+    stats = runner.stats()
+    for name in ("fit_rows", "fit_rows_full"):
+        assert stats[name] == sum(t[name] for t in begins + retries)
+    _assert_placed_exactly(h, nodes, jobs)
+
+
+def test_fleet_minima_are_read_once_a_fleet_generation():
+    """The least available cpu and memory the prep's gain bound divides
+    by are a constant of the fleet generation: kept on its statics,
+    equal to the recomputed ones, and a new generation has its own."""
+    h = Harness()
+    nodes = [mock.node(i) for i in range(6)]
+    nodes[2].resources.cpu = 2500
+    nodes[4].reserved.memory_mb = 1024
+    for n in nodes:
+        h.state.upsert_node(h.next_index(), n)
+
+    def recomputed(statics) -> tuple:
+        avail = statics.capacity[:statics.n_real] - \
+            statics.reserved[:statics.n_real]
+        return float(avail[:, 0].min()), float(avail[:, 1].min())
+
+    snap = h.state.snapshot()
+    statics = fleet.fleet_cache.statics_for(snap)
+    assert statics.min_available == recomputed(statics) == (
+        2500.0 - nodes[2].reserved.cpu,
+        nodes[4].resources.memory_mb - 1024.0)
+    assert statics.min_available is statics.min_available   # kept
+    assert fleet.fleet_cache.statics_for(snap) is statics
+
+    small = mock.node(6)
+    small.resources.cpu, small.resources.memory_mb = 1000, 2048
+    h.state.upsert_node(h.next_index(), small)
+    grown = fleet.fleet_cache.statics_for(h.state.snapshot())
+    assert grown.gen != statics.gen
+    assert grown.min_available == recomputed(grown) == (
+        1000.0 - small.reserved.cpu, 2048.0 - small.reserved.memory_mb)
+    assert statics.min_available[0] == 2500.0 - nodes[2].reserved.cpu
+    assert fleet.build_fleet([]).min_available == (1.0, 1.0)

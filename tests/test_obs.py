@@ -1122,12 +1122,13 @@ class TestWholePathSpans:
         assert after[0] - before[0] == sum(t["claims"] for t in windows)
         assert after[1] - before[1] == sum(t["walked"] for t in windows)
 
-    def test_replan_counters_add_up_to_the_retry_spans(self):
-        """A batch whose lanes bin-pack the same nodes leaves
-        stragglers: ``nomad.batch_runner.replans``, ``.replan_attempts``
-        and ``.usage_walks`` under /v1/agent/metrics move by what the
-        ``sched.retry`` spans' ``attempts`` / ``usage_walks`` tags say,
-        and with one runner every re-plan takes one attempt."""
+    def _replan_storm(self, names: tuple) -> tuple:
+        """Six jobs gathered into ONE batch whose lanes bin-pack the
+        same nodes (two copies a job, two such copies a node: two plans
+        a fused round commit whole), so a second fused round and
+        one-by-one re-plans follow.  Returns the ``names`` of
+        ``nomad.batch_runner.*`` under /v1/agent/metrics before and
+        after, the spans, and the allocations the store ends with."""
         agent, api = _http_agent()
         srv = agent.server
 
@@ -1136,22 +1137,18 @@ class TestWholePathSpans:
                 api, "/v1/agent/metrics?filter=batch_runner")
             assert code == 200
             return {k: body["providers"][f"nomad.batch_runner.{k}"]
-                    for k in ("replans", "replan_attempts",
-                              "usage_walks", "host_dispatches")}
+                    for k in names}
 
         try:
             warm = api.job_register(_job(1))["eval_id"]
             assert _await_eval(api, warm).status == "complete"
             before = counters()
-            assert before["replans"] == before["replan_attempts"] == 0
             for w in srv.workers:
                 w.set_pause(True)   # the evals gather into ONE batch
             time.sleep(0.6)  # sleep-ok: workers leave their dequeue
             with trace.tracing(seed=34) as tracer:
                 eval_ids = []
                 for _ in range(6):
-                    # Two copies a job, two such copies a node: two
-                    # plans a fused round commit whole.
                     job = _job(1, count=2)
                     job.task_groups[0].tasks[0].resources.cpu = 1500
                     eval_ids.append(api.job_register(job)["eval_id"])
@@ -1160,12 +1157,25 @@ class TestWholePathSpans:
                 assert {_await_eval(api, e).status
                         for e in eval_ids} == {"complete"}
                 after = counters()
-                retries = [_tags(s) for s in tracer.snapshot()
-                           if s["name"] == "sched.retry"]
+                spans = tracer.snapshot()
+            n_allocs = len(srv.fsm.state.allocs())
         finally:
             for w in srv.workers:
                 w.set_pause(False)
             agent.shutdown()
+        return before, after, spans, n_allocs
+
+    def test_replan_counters_add_up_to_the_retry_spans(self):
+        """A batch whose lanes bin-pack the same nodes leaves
+        stragglers: ``nomad.batch_runner.replans``, ``.replan_attempts``
+        and ``.usage_walks`` under /v1/agent/metrics move by what the
+        ``sched.retry`` spans' ``attempts`` / ``usage_walks`` tags say,
+        and with one runner every re-plan takes one attempt."""
+        before, after, spans, n_allocs = self._replan_storm(
+            ("replans", "replan_attempts", "usage_walks",
+             "host_dispatches"))
+        assert before["replans"] == before["replan_attempts"] == 0
+        retries = [_tags(s) for s in spans if s["name"] == "sched.retry"]
         assert retries, "the storm left no straggler"
         assert after["replans"] - before["replans"] == len(retries)
         assert after["replan_attempts"] - before["replan_attempts"] == \
@@ -1178,7 +1188,30 @@ class TestWholePathSpans:
                    and 0.0 < t["twin_s"] for t in retries)
         assert after["host_dispatches"] - before["host_dispatches"] >= \
             6 + len(retries)
-        assert len(srv.fsm.state.allocs()) == 1 + 6 * 2
+        assert n_allocs == 1 + 6 * 2
+
+    def test_fit_walk_rows_ride_the_prep_spans_and_two_counters(self):
+        """Every prep says how many rows its fit walk examined
+        (``fit_rows``) of those a whole walk examines (``fit_rows_full``
+        = the fleet's real rows a slot): on ``sched.begin`` for a fused
+        round's lane, on ``sched.retry`` for a one-by-one re-plan, and
+        ``nomad.batch_runner.fit_rows`` / ``.fit_rows_full`` under
+        /v1/agent/metrics move by their sum.  Eight nodes are under one
+        block of the walk: it examines them all."""
+        before, after, spans, _n = self._replan_storm(
+            ("fit_rows", "fit_rows_full", "replans"))
+        begins = [_tags(s) for s in spans if s["name"] == "sched.begin"]
+        retries = [_tags(s) for s in spans if s["name"] == "sched.retry"]
+        # A first round of six lanes, a second of those left partial.
+        assert len(begins) > 6 and retries
+        assert after["replans"] - before["replans"] == len(retries)
+        for t in begins:
+            assert t["fit_rows"] == t["fit_rows_full"] == 8 * t["slots"]
+        for t in retries:
+            assert t["fit_rows"] == t["fit_rows_full"] == 8 * t["attempts"]
+        for name in ("fit_rows", "fit_rows_full"):
+            assert after[name] - before[name] == \
+                sum(t[name] for t in begins + retries)
 
     def test_slot_tags_and_counters_say_how_many_slots_a_lane_carried(self):
         """A job of three groups whose asks differ is a lane of three
