@@ -18,7 +18,8 @@ Design constraints, in order:
   ``faultinject.ACTIVE``); with tracing off the hot path pays a single
   global read: no clock, no ``thread_time``, no tag dict and no
   ``TraceAnnotation`` is built behind a false gate.  Tracing ON was
-  measured on the chip in both benchmark cells (PERF.md §6, PR 26):
+  measured on the chip (PERF.md §6: PR 26, and PR 37 with the re-plan
+  and stage spans in: 8-9% of ``baseline4-10k.small``'s jobs a window):
   jobs completed per window traced vs. untraced, span count, drops.
 - **Lock-cheap recording.**  Finished spans append to a per-thread
   buffer (plain ``list.append`` — owner-thread only, no lock) and drain
@@ -69,6 +70,39 @@ ack``) the fused runner's whole cycle, and ``device.dispatch``
 trace's module events can be laid on this clock.  README
 "Observability" has the table; PERF.md §3 names each span's reader.
 
+The runner's cycle, opened (ISSUE 37).  ``sched.retry`` (one one-by-one
+re-plan; name, extent and tags as before) is a parent: per attempt
+(``attempt`` tag) ``retry.begin`` (job lookup, reconcile, prep, up to
+the kernel call), ``retry.dispatch`` (the kernel call; ``engine``,
+``slots``, ``mode``, ``rounds`` as ``dispatch_tags`` gives them; on the
+host engine its duration is the span's ``twin_s``), ``retry.finish``
+(results -> plan; ``node_inits``, ``walked``) and ``retry.submit``
+(``planner.submit_plan``: enqueue -> result, a forced refresh
+included), recorded from a :class:`StageClock` the retrying scheduler
+keeps while tracing is on.  ``retry.refresh`` is the snapshot taken
+before it, a leaf under the eval's anchor; the terminal ``sched.status``
+stays the sibling it was.  A fused window on the kernel records, once a
+window, ``window.stack`` (the lanes' arrays copied into ``[b_pad, g_pad,
+n_pad]`` stacks; ``lanes``, ``b_pad``, ``g_pad``, ``n_pad``, ``bytes``)
+and ``window.upload`` (the counted ``put_counted`` block;
+``h2d_bytes``), and its ``device.dispatch`` says ``fetch_s`` (seconds
+inside ``fetch_results``).  ``plan.encode`` is the commit window's wire
+encode (``encode_alloc_update`` / ``encode_plan_batch`` +
+``codec.encode``; ``plans``, ``bytes``), per member plan as
+``raft.apply`` is.
+
+``cpu_s`` / ``blocked_s`` (ISSUE 37): every stage span of the fused
+runner's thread (``sched.begin/dispatch/finish/submit/status/retry``,
+``retry.*``, ``window.*``, ``worker.sync/snapshot/ack/batch``) carries
+the thread's CPU seconds over the span and the seconds it spent, off
+the CPU, inside waits it CHOSE (:func:`chosen_wait`: a plan's future, a
+raft index, a raft apply, the device fetch).  A fused stage's lane
+spans share the window's one interval and its one pair: count a window
+once.  ``dur - cpu_s - blocked_s`` is time the thread was runnable and
+did not run: the interpreter lock, the OS, a lock nobody declared.  Of
+GIL wait it is a LOWER bound: waking from a chosen wait queues for the
+lock too, and that is counted as blocked.
+
 Export is Chrome-trace JSON (``chrome://tracing`` / Perfetto "X"
 complete events), span tags riding in ``args``.
 """
@@ -78,7 +112,7 @@ import itertools
 import json
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Optional
 
 # Hot-path gate: every runtime instrumentation site checks this single
@@ -94,6 +128,72 @@ TRACE_KEY = "_trace"
 FLUSH_AT = 64
 
 DEFAULT_RING = 65536
+
+
+# Per-thread sum (attribute ``s``) of the seconds the thread spent, off
+# the CPU, inside waits it chose (``chosen_wait``); tracing only.
+_waited = threading.local()
+
+# What a parking place enters with tracing off:
+#   with (trace.chosen_wait() if trace.ENABLED else trace.NO_WAIT):
+NO_WAIT = nullcontext()
+
+
+class chosen_wait:
+    """Bracket a wait the calling thread CHOSE (a future, a raft index,
+    a device fetch): its wall seconds less the thread's own CPU seconds
+    inside it join the thread's sum, which every ``StageClock`` lap
+    reads as ``blocked_s``.  The sum is per thread: another caller's
+    wait in the same function never reaches the runner's spans."""
+
+    __slots__ = ("_t", "_cpu")
+
+    def __enter__(self) -> None:
+        self._t = time.perf_counter()
+        self._cpu = time.thread_time()
+
+    def __exit__(self, *_exc) -> None:
+        held = _waited.__dict__
+        held["s"] = held.get("s", 0.0) + (time.perf_counter() - self._t) \
+            - (time.thread_time() - self._cpu)
+
+
+class StageClock:
+    """One thread's stage clock (build and lap it on that thread, and
+    only behind ``ENABLED``): the span clock, ``time.thread_time()``
+    and the thread's sum of chosen waits, read together.  ``lap()``
+    closes the stage running since the last lap (or construction) and
+    starts the next: ``(t0, dur, {"cpu_s", "blocked_s"})``, ``t0`` on
+    ``tracer``'s clock.  ``dur - cpu_s - blocked_s`` is what the thread
+    stood runnable and did not run (the taxonomy above)."""
+
+    __slots__ = ("_epoch", "_t", "_cpu", "_blocked")
+
+    def __init__(self, tracer: "Tracer") -> None:
+        self._epoch = tracer._epoch
+        self._t = time.perf_counter()
+        self._cpu = time.thread_time()
+        self._blocked = _waited.__dict__.get("s", 0.0)
+
+    def lap(self, now: Optional[float] = None) -> tuple:
+        """``now``: a ``perf_counter`` reading the caller already took
+        for the stage's end (so two numbers made from one pair of
+        readings agree exactly)."""
+        t = time.perf_counter() if now is None else now
+        cpu = time.thread_time()
+        blocked = _waited.__dict__.get("s", 0.0)
+        out = (self._t - self._epoch, t - self._t,
+               {"cpu_s": cpu - self._cpu,
+                "blocked_s": blocked - self._blocked})
+        self._t, self._cpu, self._blocked = t, cpu, blocked
+        return out
+
+
+def stage_clock() -> Optional[StageClock]:
+    """A stage clock for the calling thread, for a site that has read
+    ``ENABLED``; None where a ``disable()`` raced that gate."""
+    tracer = _TRACER
+    return StageClock(tracer) if tracer is not None else None
 
 
 class _ThreadBuf:

@@ -106,6 +106,14 @@ def note_transfer(kind: str, n: int = 1, *moved) -> None:
             int(getattr(x, "nbytes", 0)) for x in moved)
 
 
+def moved_bytes(kind: str) -> int:
+    """Bytes of ``kind`` the calling thread moved through the counted
+    seams since its last ``device.dispatch`` span closed (tracing
+    only): a site reads it before and after a block of uploads to say
+    what that block moved, and takes nothing from the span's count."""
+    return _moved.__dict__.get(kind, 0)
+
+
 # -- device.dispatch spans (obs/trace.py) ----------------------------------
 # What a dispatch site enters with tracing off:
 #   with (device_dispatch(kernel, lanes=B, ...) if trace_mod.ENABLED
@@ -130,15 +138,18 @@ def device_dispatch(program, async_: bool = False, **tags):
     counted seams since its previous span.  A
     ``jax.profiler.TraceAnnotation`` of the same name is open for the
     block, so a profiler capture with the host tracer on shows the
-    dispatch beside the device ops.  Not to be confused with the
+    dispatch beside the device ops.  Yields the span's tag dict (None
+    where a ``disable()`` raced the gate, as ``NO_DISPATCH`` does): what
+    a site learns inside the block (``fetch_s``, the seconds of its
+    fetch) it adds there.  Not to be confused with the
     fault-injection site of the same name (scheduler/pipeline.py)."""
     tracer = trace_mod.tracer()
     if tracer is None:   # a disable() raced the site's gate
-        yield
+        yield None
         return
     t0 = tracer.now()
     with jax.profiler.TraceAnnotation("device.dispatch"):
-        yield
+        yield tags
     held = _moved.__dict__
     if async_:
         tags["async"] = 1

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from types import SimpleNamespace
 from typing import Callable, Optional
 
 from nomad_tpu.obs import trace as trace_mod
@@ -66,6 +67,18 @@ def _lane_spans(name: str, scheds, t0: float, t1: float,
             tracer.record(name, t0, t1 - t0, parent_ctx=ev.trace,
                           span_id=span_ids.get(ev.id) if span_ids
                           else None, eval_id=ev.id, **tags)
+
+
+def _stage_spans(name: str, scheds, clock, span_ids: Optional[dict] = None,
+                 **tags) -> None:
+    """``_lane_spans`` over the stage ``clock`` (a ``StageClock`` of the
+    calling thread, None while tracing is off) closes now: one span a
+    lane over the stage's one interval, each carrying its one
+    ``cpu_s`` / ``blocked_s`` pair (readers count a window once)."""
+    if clock is None:
+        return
+    t0, dur, pair = clock.lap()
+    _lane_spans(name, scheds, t0, t0 + dur, span_ids, **pair, **tags)
 
 
 def dispatch_tags(rounds_mode: bool, rounds: int, engine: str,
@@ -255,7 +268,7 @@ class BatchEvalRunner:
                 ev.trace = tracer.anchor("eval.created",
                                          eval_id=ev.id,
                                          eval_type=ev.type)
-            t0 = tracer.now()
+            clock = trace_mod.StageClock(tracer)
             sid = self.stage_span[ev.id] = tracer.new_id()
             sched = None
             try:
@@ -272,9 +285,10 @@ class BatchEvalRunner:
                         "fit_rows_full": sched.fit_rows_full} \
                     if sched is not None and sched.deferred is not None \
                     else {}
-                tracer.record("sched.begin", t0, tracer.now() - t0,
+                t0, dur, pair = clock.lap()
+                tracer.record("sched.begin", t0, dur,
                               parent_ctx=ev.trace, span_id=sid,
-                              eval_id=ev.id, **tags)
+                              eval_id=ev.id, **pair, **tags)
         return self._begin_eval_inner(ev, finish_noop)
 
     def _begin_eval_inner(self, ev: Evaluation, finish_noop: bool = True):
@@ -327,14 +341,20 @@ class BatchEvalRunner:
                 pending = retries
                 self.state = self.state_refresh()
             for ev in pending:
+                # ``retry.refresh``: the snapshot a straggler plans on,
+                # a leaf under its anchor just before its ``sched.retry``.
+                clock = trace_mod.stage_clock() if trace_mod.ENABLED \
+                    else None
                 self.state = self.state_refresh()
+                _stage_spans("retry.refresh", [SimpleNamespace(eval=ev)],
+                             clock)
                 self._retry_sequential(self.state, ev)
 
     def _retry_sequential(self, state, ev: Evaluation) -> None:
         """Exact per-eval retry (fresh scheduler, full process)."""
         retry = JaxBinPackScheduler(state, self.planner,
                                     batch=(ev.type == "batch"))
-        t0 = _tnow()
+        clock = trace_mod.stage_clock() if trace_mod.ENABLED else None
         retry.process(ev)
         self.replans += 1
         self.replan_attempts += retry.attempts
@@ -356,10 +376,25 @@ class BatchEvalRunner:
             if trace_mod.ENABLED else {}
         self._note_dispatch(retry)
         self._note_finish([retry])
-        # One span over the whole re-plan.  Its status write is a
-        # sibling ``sched.status`` under the eval's anchor, not a child:
-        # the re-plan's own time stays a leaf of the eval's tree.
-        _lane_spans("sched.retry", [retry], t0, _tnow(), **calls)
+        # One span over the whole re-plan, parent of its attempts'
+        # stages.  Its status write is a sibling ``sched.status`` under
+        # the eval's anchor, not a child.
+        tracer = trace_mod.tracer() if clock is not None else None
+        if tracer is None or not ev.trace:
+            return
+        r0, r_dur, r_pair = clock.lap()
+        sid = tracer.new_id()
+        under = {"trace_id": ev.trace.get("trace_id"), "span_id": sid}
+        for name, attempt, (t0, dur, pair), facts in retry.stage_log:
+            args = facts.pop("args", None)
+            if args is not None:    # ``retry.dispatch``: the kernel call
+                facts.update(dispatch_tags(
+                    args.rounds_eligible, args.rounds, facts["engine"],
+                    retry.dispatch_cost(args), 1, args.n_groups))
+            tracer.record(name, t0, dur, parent_ctx=under, eval_id=ev.id,
+                          attempt=attempt, **pair, **facts)
+        _lane_spans("sched.retry", [retry], r0, r0 + r_dur, {ev.id: sid},
+                    **r_pair, **calls)
 
     def _process(self, evals: list[Evaluation],
                  retries: Optional[list] = None) -> None:
@@ -418,7 +453,15 @@ class BatchEvalRunner:
                 self._process_leftovers(leftovers)
             return
 
-        t_disp = _tnow()
+        # The window's stages (tracing only): ``whole`` is the lanes'
+        # ``sched.dispatch``; ``part`` laps ``window.stack`` and
+        # ``window.upload``, once a window, in the trace its
+        # ``device.dispatch`` joins.
+        tracer = trace_mod.tracer() if trace_mod.ENABLED else None
+        whole = part = None
+        if tracer is not None:
+            whole = trace_mod.StageClock(tracer)
+            part = trace_mod.StageClock(tracer)
         # Harmonize pad shapes across lanes, stack, one dispatch.
         feasible = np.zeros((B_pad, g_max, statics.n_pad), dtype=bool)
         asks = np.zeros((B_pad, g_max, pending[0][2].asks.shape[1]),
@@ -439,6 +482,14 @@ class BatchEvalRunner:
 
         penalty = np.zeros(B_pad, dtype=np.float32)
         penalty[:B] = [a.penalty for _, _, a in pending]
+        if part is not None:
+            t0, dur, pair = part.lap()
+            tracer.record(
+                "window.stack", t0, dur, parent_ctx=tracer.ctx(), lanes=B,
+                b_pad=B_pad, g_pad=g_max, n_pad=statics.n_pad,
+                bytes=sum(x.nbytes for x in (
+                    feasible, asks, distinct, group_idx, valid,
+                    job_counts, counts, penalty)), **pair)
 
         # Mesh resolution rides the ONE authority (parallel/mesh.py):
         # multi-chip agents automatically get the 2-D (lanes, fleet)
@@ -456,15 +507,26 @@ class BatchEvalRunner:
 
         def dispatch_spans() -> None:
             """One ``sched.dispatch`` a lane over the window's one
-            interval; the lanes differ in ``slots`` alone."""
-            if not trace_mod.ENABLED:
+            interval and its one ``cpu_s`` / ``blocked_s`` pair; the
+            lanes differ in ``slots`` alone."""
+            if whole is None:
                 return
-            t1 = _tnow()
+            t0, dur, pair = whole.lap()
             for sched, _p, a in pending:
-                _lane_spans("sched.dispatch", [sched], t_disp, t1,
-                            fused=B, **dispatch_tags(
+                _lane_spans("sched.dispatch", [sched], t0, t0 + dur,
+                            fused=B, **pair, **dispatch_tags(
                                 rounds_ok, rounds, engine, fused_cost, B,
                                 a.n_groups))
+
+        def fetch(late, *arrays) -> list:
+            """``fetch_results``, its seconds on the window's
+            ``device.dispatch`` span (``late``: that span's tags)."""
+            if late is None:
+                return fetch_results(*arrays)
+            t0 = _tnow()
+            out = fetch_results(*arrays)
+            late["fetch_s"] = _tnow() - t0
+            return out
         # All fused lanes share the same snapshot base usage (fast-path
         # contract above); use the resident device copies when available
         # (single-device mirror copy, or on a mesh the sharded statics +
@@ -482,8 +544,11 @@ class BatchEvalRunner:
             if base_usage is None:
                 base_usage = view0.usage  # mirror moved on: host upload
         else:
-            from nomad_tpu.parallel.devices import put_counted
+            from nomad_tpu.parallel.devices import moved_bytes, put_counted
 
+            if part is not None:
+                part.lap()
+                moved = moved_bytes("h2d")
             capacity_d, reserved_d = statics.device_capacity_reserved()
             base_usage = put_counted(view0.dispatch_usage())
             # The per-dispatch lane stacks are fresh host arrays: place
@@ -500,6 +565,11 @@ class BatchEvalRunner:
             job_counts = put_counted(job_counts)
             counts = put_counted(counts)
             penalty = put_counted(penalty)
+            if part is not None:
+                t0, dur, pair = part.lap()
+                tracer.record("window.upload", t0, dur,
+                              parent_ctx=tracer.ctx(),
+                              h2d_bytes=moved_bytes("h2d") - moved, **pair)
         from nomad_tpu.parallel.devices import NO_DISPATCH, device_dispatch
 
         if rounds_ok:
@@ -519,7 +589,7 @@ class BatchEvalRunner:
                                   n_pad=statics.n_pad,
                                   slots=sum(a.n_groups
                                             for _, _, a in pending))
-                  if trace_mod.ENABLED else NO_DISPATCH):
+                  if trace_mod.ENABLED else NO_DISPATCH) as late:
                 if mesh is not None:
                     chosen_s, score_s, _u = place_rounds_batch_sharded(
                         mesh, capacity_d, reserved_d, base_usage,
@@ -530,7 +600,7 @@ class BatchEvalRunner:
                         capacity_d, reserved_d, base_usage, job_counts,
                         feasible, asks, distinct, counts, penalty,
                         k_cap=k_cap, rounds=rounds)
-                chosen_s, score_s = fetch_results(chosen_s, score_s)
+                chosen_s, score_s = fetch(late, chosen_s, score_s)
             dispatch_spans()
             done = []
             for b, (sched, place, args) in enumerate(pending):
@@ -548,7 +618,7 @@ class BatchEvalRunner:
             with (device_dispatch(program, lanes=B, b_pad=B_pad,
                                   g_pad=g_max, p_pad=p_max,
                                   n_pad=statics.n_pad)
-                  if trace_mod.ENABLED else NO_DISPATCH):
+                  if trace_mod.ENABLED else NO_DISPATCH) as late:
                 if mesh is not None:
                     chosen, scores, _usage = place_sequence_batch_sharded(
                         mesh, capacity_d, reserved_d, base_usage,
@@ -559,7 +629,7 @@ class BatchEvalRunner:
                         capacity_d, reserved_d, base_usage, job_counts,
                         feasible, asks, distinct, group_idx, valid,
                         penalty)
-                chosen, scores = fetch_results(chosen, scores)
+                chosen, scores = fetch(late, chosen, scores)
             dispatch_spans()
             self._finish_window(
                 [(sched, place, args, chosen[b], scores[b])
@@ -584,7 +654,7 @@ class BatchEvalRunner:
         n_real = statics.n_real
         done = []
         for sched, place, args in pending:
-            t_disp = _tnow()
+            clock = trace_mod.stage_clock() if trace_mod.ENABLED else None
             if rounds_ok:
                 chosen_s, score_s, _u = place_rounds_host(
                     statics.capacity, statics.reserved, base_usage,
@@ -599,10 +669,10 @@ class BatchEvalRunner:
                     args.view.job_counts, args.feasible_h, args.asks,
                     args.distinct, args.group_idx, args.valid,
                     float(args.penalty), n_real=n_real)
-            _lane_spans("sched.dispatch", [sched], t_disp, _tnow(),
-                        host=True, **dispatch_tags(
-                            rounds_ok, rounds, "host", fused_cost,
-                            len(pending), args.n_groups))
+            _stage_spans("sched.dispatch", [sched], clock, host=True,
+                         **dispatch_tags(
+                             rounds_ok, rounds, "host", fused_cost,
+                             len(pending), args.n_groups))
             self.host_dispatches += 1
             self.slots += args.n_groups
             self.padded_slots += args.g_pad
@@ -620,23 +690,22 @@ class BatchEvalRunner:
         self.process(leftovers)
 
     def _run_single(self, sched, place, args, retries=None) -> None:
-        t0 = _tnow()
+        clock = trace_mod.stage_clock() if trace_mod.ENABLED else None
         handles = sched.dispatch_device(args)
         # faultlint-ok(uninjectable-io): batch-lane device round-trip;
         # fault rehearsal (and the recovery path it needs) rides the
         # pipelined lane's device.dispatch/collect seam — a documented
         # gap, not an oversight.
         chosen, scores = sched.collect_device(args, handles)
-        t1 = _tnow()
-        _lane_spans("sched.dispatch", [sched], t0, t1, **dispatch_tags(
+        _stage_spans("sched.dispatch", [sched], clock, **dispatch_tags(
             args.rounds_eligible, args.rounds,
             "host" if sched.dispatched_host else
             "sharded" if sched.dispatched_sharded else "device",
             sched.dispatch_cost(args), 1, args.n_groups))
         sched.finish_deferred(place, args, chosen, scores)
         self._note_dispatch(sched)
-        _lane_spans("sched.finish", [sched], t1, _tnow(),
-                    **self._note_finish([sched]))
+        _stage_spans("sched.finish", [sched], clock,
+                     **self._note_finish([sched]))
         self._finish(sched, retries)
 
     @staticmethod
@@ -675,7 +744,7 @@ class BatchEvalRunner:
 
         from .jax_binpack import _native_bulk
 
-        t_fin = _tnow()
+        clock = trace_mod.stage_clock() if trace_mod.ENABLED else None
 
         uuid_slab = generate_uuids(
             sum(len(place) for _, place, *_ in lanes))
@@ -712,8 +781,8 @@ class BatchEvalRunner:
         for (sched, *_rest), fs in zip(lanes, states):
             sched._finish_python_tail(fs)
         scheds = [s for s, *_r in lanes]
-        _lane_spans("sched.finish", scheds, t_fin, _tnow(),
-                    window=len(lanes), **self._note_finish(scheds))
+        _stage_spans("sched.finish", scheds, clock,
+                     window=len(lanes), **self._note_finish(scheds))
 
     def _finish_window(self, done: list, retries=None) -> None:
         """Windowed finish + group submit for fused lanes
@@ -732,10 +801,10 @@ class BatchEvalRunner:
         order and per-lane status semantics (see ``_finish``).  Uses the
         planner's group path when it has one; per-plan submits
         otherwise."""
-        t_sub = _tnow()
         tracer = trace_mod.tracer() if trace_mod.ENABLED else None
-        sub_ids = None
+        sub_ids = clock = None
         if tracer is not None:
+            clock = trace_mod.StageClock(tracer)
             # Pin each lane's sched.submit span id up front: the status
             # writes inside the window are its children.
             sub_ids = {s.eval.id: tracer.new_id() for s in scheds
@@ -747,8 +816,8 @@ class BatchEvalRunner:
             if sub_ids is not None:
                 for eval_id in sub_ids:
                     self.stage_span.pop(eval_id, None)
-        _lane_spans("sched.submit", scheds, t_sub, _tnow(),
-                    span_ids=sub_ids, window=len(scheds))
+        _stage_spans("sched.submit", scheds, clock, sub_ids,
+                     window=len(scheds))
 
     def _submit_window_inner(self, scheds: list, retries=None) -> None:
         submitters = []

@@ -199,7 +199,10 @@ def fetch_results(*arrays) -> list:
             a.copy_to_host_async()
         except AttributeError:  # plain numpy already on host
             pass
-    return [fetch_host(a) for a in arrays]
+    # A wait the caller chose (obs/trace.py): device -> host.
+    with (trace_mod.chosen_wait() if trace_mod.ENABLED
+          else trace_mod.NO_WAIT):
+        return [fetch_host(a) for a in arrays]
 
 
 # Rows of the fit walk's first block; each next block is twice the last,
@@ -628,6 +631,13 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         self.twin_slots = 0
         # Times ``process`` ran ``_process`` (``retry_max``'s attempts).
         self.attempts = 0
+        # Tracing only: the stage clock of the attempt ``process`` is
+        # running (None on a deferred lane, whose stages the batch
+        # driver times) and the stages it closed, [(name, attempt,
+        # (t0, dur, {cpu_s, blocked_s}), facts)]: the ``retry.*``
+        # children of a one-by-one re-plan's ``sched.retry`` span.
+        self._clock = None
+        self.stage_log: list = []
         # Usage views built by walking every allocation in the store
         # (``build_usage``) where the usage mirror could not serve
         # them: the snapshot was older than the mirror, or the finish
@@ -641,7 +651,36 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
 
     def _process(self) -> bool:
         self.attempts += 1
-        return super()._process()
+        self._clock = trace_mod.stage_clock() if trace_mod.ENABLED \
+            else None
+        if self._clock is None:
+            return super()._process()
+        # One attempt, its stages closed as they end: ``retry.begin``
+        # up to the kernel call and ``retry.dispatch`` over it (laps in
+        # ``dispatch_host`` / ``dispatch_device`` + ``collect_device``),
+        # ``retry.finish`` until ``_begin`` returns, ``retry.submit``.
+        # An attempt with nothing to place has no kernel call: all of
+        # ``_begin`` is its ``retry.begin``.
+        closed = len(self.stage_log)
+        inits, walks = self.net_inits, self.net_walks
+        self._begin()
+        if len(self.stage_log) > closed:
+            self._stage("retry.finish", node_inits=self.net_inits - inits,
+                        walked=self.net_walks - walks)
+        else:
+            self._stage("retry.begin")
+        ok = self._submit()
+        self._stage("retry.submit")
+        return ok
+
+    def _stage(self, name: str, now: "float | None" = None,
+               **facts) -> None:
+        """Close the stage running on this attempt's clock as ``name``
+        (nothing without a clock: tracing off, or a deferred lane)."""
+        clock = self._clock
+        if clock is not None:
+            self.stage_log.append((name, self.attempts, clock.lap(now),
+                                   facts))
 
     def _count_call(self, engine: str, args: "DeviceArgs") -> None:
         """One placement-kernel call of this scheduler's own."""
@@ -770,6 +809,8 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         statics = args.statics
         traced = trace_mod.ENABLED
         t0 = time.perf_counter() if traced else 0.0
+        if traced:
+            self._stage("retry.begin", t0)
         if args.rounds_eligible:
             chosen, scores, _ = place_rounds_host(
                 statics.capacity, statics.reserved, args.view.usage,
@@ -784,8 +825,10 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
                 args.distinct, args.group_idx, args.valid, args.penalty,
                 n_real=statics.n_real)
         if traced:
-            self.twin_s += time.perf_counter() - t0
+            t1 = time.perf_counter()
+            self.twin_s += t1 - t0
             self.twin_slots += args.n_groups
+            self._stage("retry.dispatch", t1, args=args, engine="host")
         return chosen, scores
 
     def dispatch_device(self, args: "DeviceArgs",
@@ -807,6 +850,8 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
             self.dispatched_host = True
             return self.dispatch_host(args)
         self.dispatched_host = False
+        if trace_mod.ENABLED:
+            self._stage("retry.begin")
         self._count_call("device", args)
         from nomad_tpu.parallel.mesh import dispatch_mesh
 
@@ -926,7 +971,14 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         (devices.fetch_host) — this is a sanctioned collect seam."""
         from nomad_tpu.parallel.devices import fetch_host
 
-        chosen, scores = (fetch_host(h) for h in handles)
+        with (trace_mod.chosen_wait() if trace_mod.ENABLED
+              else trace_mod.NO_WAIT):
+            chosen, scores = [fetch_host(h) for h in handles]
+        if trace_mod.ENABLED and not self.dispatched_host:
+            # The twin closed its stage in ``dispatch_host``.
+            self._stage("retry.dispatch", args=args,
+                        engine="sharded" if self.dispatched_sharded
+                        else "device")
         if args.rounds_eligible:
             chosen, scores = rounds_to_placements(args, chosen, scores)
         return chosen, scores

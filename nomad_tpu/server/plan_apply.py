@@ -785,6 +785,7 @@ class PlanApplier:
             encode_plan_batch,
         )
 
+        t_enc = tracer.now() if tracer is not None else 0.0
         if len(committers) == 1:
             msg_type, payload = (codec.ALLOC_UPDATE_REQUEST,
                                  encode_alloc_update(alloc_lists[0]))
@@ -805,6 +806,17 @@ class PlanApplier:
                 payload["_trace"] = env
             t_apply = tracer.now()
         entry = codec.encode(msg_type, payload)
+        if tracer is not None:
+            # ``plan.encode``: accepted portions -> log entry (either
+            # wire format), one span per member plan over the window's
+            # one interval, as ``raft.apply`` is.
+            dur = tracer.now() - t_enc
+            for pend, _result in committers:
+                if pend.plan.trace:
+                    tracer.record("plan.encode", t_enc, dur,
+                                  parent_ctx=pend.plan.trace,
+                                  eval_id=pend.plan.eval_id,
+                                  plans=len(committers), bytes=len(entry))
         try:
             future = self.raft.apply(entry)
         except Exception as e:

@@ -691,7 +691,12 @@ class Server:
         tracer = obs_trace.tracer() if obs_trace.ENABLED else None
         t0 = tracer.now() if tracer is not None else 0.0
         entry = codec.encode(msg_type, payload)
-        index, _ = self.raft.apply(entry).wait(30.0)
+        # A wait the calling thread chose (obs/trace.py): the log's
+        # group commit and flush, or a quorum; its FSM apply, where it
+        # runs on this thread, stays CPU time.
+        with (obs_trace.chosen_wait() if tracer is not None
+              else obs_trace.NO_WAIT):
+            index, _ = self.raft.apply(entry).wait(30.0)
         if tracer is not None:
             # Encode -> committed index back, under whatever is ambient
             # (the serving RPC's span, or a lane's sched.status).  The

@@ -162,12 +162,17 @@ class Worker:
     def _wait_for_index(self, index: int, timeout: float) -> None:
         """Block until the local FSM has applied at least `index`
         (worker.go:209-230)."""
+        if self.server.raft.applied_index() >= index:
+            return
         deadline = time.monotonic() + timeout
-        while self.server.raft.applied_index() < index:
-            if time.monotonic() >= deadline:
-                raise TimeoutError(
-                    f"timed out waiting for raft index {index}")
-            time.sleep(0.005)
+        # A wait this thread chose (obs/trace.py ``chosen_wait``).
+        with (trace_mod.chosen_wait() if trace_mod.ENABLED
+              else trace_mod.NO_WAIT):
+            while self.server.raft.applied_index() < index:
+                if time.monotonic() >= deadline:
+                    raise TimeoutError(
+                        f"timed out waiting for raft index {index}")
+                time.sleep(0.005)
 
     def _invoke_scheduler(self, ev: Evaluation) -> None:
         # tracer() re-checked for None behind the gate: a concurrent
@@ -202,23 +207,27 @@ class Worker:
         responds while the leader is alive, but leadership loss (or a
         test teardown) can orphan an already-submitted plan — a worker
         blocked forever here pins its whole dispatch (including the
-        gc_pause the fused path runs under) for the process lifetime."""
-        while True:
-            try:
-                return future.wait(PLAN_WAIT_POLL)
-            except TimeoutError:
-                # The future may have been responded since (or DURING)
-                # the poll: re-read it rather than trusting this
-                # TimeoutError, which is ambiguous between our poll
-                # expiring, a respond() racing the poll's expiry, and a
-                # RESPONDED result whose stored error is itself a
-                # TimeoutError (re-raised instantly — treating that as
-                # the poll would zero-sleep spin here forever).
-                if future.done():
-                    return future.wait(0)
-                if not self.server.plan_queue.enabled():
-                    raise RuntimeError(
-                        "plan queue closed while awaiting plan result")
+        gc_pause the fused path runs under) for the process lifetime.
+        A wait this thread chose (obs/trace.py ``chosen_wait``)."""
+        with (trace_mod.chosen_wait() if trace_mod.ENABLED
+              else trace_mod.NO_WAIT):
+            while True:
+                try:
+                    return future.wait(PLAN_WAIT_POLL)
+                except TimeoutError:
+                    # The future may have been responded since (or
+                    # DURING) the poll: re-read it rather than trusting
+                    # this TimeoutError, which is ambiguous between our
+                    # poll expiring, a respond() racing the poll's
+                    # expiry, and a RESPONDED result whose stored error
+                    # is itself a TimeoutError (re-raised instantly —
+                    # treating that as the poll would zero-sleep spin
+                    # here forever).
+                    if future.done():
+                        return future.wait(0)
+                    if not self.server.plan_queue.enabled():
+                        raise RuntimeError(
+                            "plan queue closed while awaiting plan result")
 
     # -- Planner seam ------------------------------------------------------
     def submit_plan(self, plan: Plan) -> tuple[PlanResult, Optional[object]]:
@@ -304,22 +313,24 @@ class BatchWorker(Worker):
         cycle, dequeue to last ack, a trace of its own (a batch belongs
         to no one eval) — with ``worker.dequeue``, ``worker.sync``,
         ``worker.snapshot`` and ``worker.ack`` as children.  ``cpu_s``
-        is this thread's CPU time over the batch: the rest of the
-        span's duration after ``worker.dequeue`` is time the runner
-        waited (plan results, the GIL)."""
+        is this thread's CPU time over the batch and ``blocked_s`` what
+        it spent, off the CPU, inside waits it chose (plan results,
+        raft, the device fetch): the rest of the span's duration after
+        ``worker.dequeue`` is time the runner stood runnable and did
+        not run (the GIL, the OS; obs/trace.py)."""
         ctx = {"trace_id": tracer.new_id(), "span_id": tracer.new_id()}
+        clock = trace_mod.StageClock(tracer)
         t0 = tracer.now()
         tracer.record("worker.dequeue", t_deq, t0 - t_deq, parent_ctx=ctx,
                       lanes=len(batch))
-        cpu0 = time.thread_time()
         try:
             self._run_batch(batch, tracer, ctx)
         finally:
+            _t0, _dur, pair = clock.lap()
             tracer.record(
                 "worker.batch", t_deq, tracer.now() - t_deq,
                 ctx={"trace_id": ctx["trace_id"], "parent_id": None},
-                span_id=ctx["span_id"], lanes=len(batch),
-                cpu_s=time.thread_time() - cpu0)
+                span_id=ctx["span_id"], lanes=len(batch), **pair)
 
     def _run_batch(self, batch: list, tracer=None, ctx=None) -> None:
         """Sync to the batch's raft index, snapshot, run the fused
@@ -327,12 +338,15 @@ class BatchWorker(Worker):
         With a ``tracer``, each step is a span under ``ctx``."""
         broker = self.server.eval_broker
 
-        def now() -> float:
-            return tracer.now() if tracer is not None else 0.0
+        def stage():
+            """A stage clock (obs/trace.py), None untraced."""
+            return trace_mod.StageClock(tracer) if tracer is not None \
+                else None
 
-        def step(name: str, t0: float) -> None:
-            if tracer is not None:
-                tracer.record(name, t0, tracer.now() - t0, parent_ctx=ctx)
+        def step(name: str, clock) -> None:
+            if clock is not None:
+                t0, dur, pair = clock.lap()
+                tracer.record(name, t0, dur, parent_ctx=ctx, **pair)
 
         def nack_all() -> None:
             for ev, token in batch:
@@ -343,7 +357,7 @@ class BatchWorker(Worker):
 
         self._delivery_deadline = time.monotonic() + broker.nack_timeout
         max_index = max(ev.modify_index for ev, _ in batch)
-        t0 = now()
+        clock = stage()
         try:
             self._wait_for_index(max_index, RAFT_SYNC_LIMIT)
             # ErrDeadlineExceeded is a TimeoutError: an expired
@@ -354,12 +368,12 @@ class BatchWorker(Worker):
             nack_all()
             return
         finally:
-            step("worker.sync", t0)
+            step("worker.sync", clock)
 
         self._tokens = {ev.id: token for ev, token in batch}
-        t0 = now()
+        clock = stage()
         self.runner.state = self.server.fsm.state.snapshot()
-        step("worker.snapshot", t0)
+        step("worker.snapshot", clock)
         try:
             self.runner.process([ev for ev, _ in batch])
         except Exception:
@@ -369,13 +383,13 @@ class BatchWorker(Worker):
             return
         finally:
             self.runner.state = None  # don't pin a store generation idle
-        t0 = now()
+        clock = stage()
         for ev, token in batch:
             try:
                 broker.ack(ev.id, token)
             except ValueError:
                 pass
-        step("worker.ack", t0)
+        step("worker.ack", clock)
 
 
 class _BatchPlanner:
@@ -454,10 +468,18 @@ class _BatchPlanner:
         parent = {"trace_id": ev.trace.get("trace_id"),
                   "span_id": self.worker.runner.stage_span.get(ev.id)
                   or ev.trace.get("span_id")}
-        with tracer.span("sched.status", ctx=parent, eval_id=ev.id,
-                         status=ev.status):
-            self.worker.server.apply_eval_update(
-                [ev], self.worker._tokens.get(ev.id, ""))
+        sid = tracer.new_id()
+        clock = trace_mod.StageClock(tracer)
+        try:
+            with tracer.attach({"trace_id": parent["trace_id"],
+                                "span_id": sid}):
+                self.worker.server.apply_eval_update(
+                    [ev], self.worker._tokens.get(ev.id, ""))
+        finally:
+            t0, dur, pair = clock.lap()
+            tracer.record("sched.status", t0, dur, parent_ctx=parent,
+                          span_id=sid, eval_id=ev.id, status=ev.status,
+                          **pair)
 
     def create_eval(self, ev: Evaluation) -> None:
         self.worker.server.apply_eval_update(

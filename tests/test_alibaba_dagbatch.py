@@ -272,7 +272,10 @@ def test_a_slot_with_more_copies_than_nodes_takes_a_second_round():
                          if s["name"] == "sched.dispatch"]
             # One lane of 16 real slots (no two groups share an ask; a
             # padded axis of 16), two rounds, 128 nodes: the estimate
-            # the choice was made on.
+            # the choice was made on; beside it what the runner's thread
+            # computed and chose to wait over the stage.
+            assert all(lane.pop("cpu_s") >= 0.0 and
+                       lane.pop("blocked_s") >= 0.0 for lane in lanes)
             assert lanes == [{
                 "eval_id": lanes[0]["eval_id"], "host": True,
                 "mode": "rounds", "rounds": 2, "engine": "host",

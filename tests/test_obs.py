@@ -1190,6 +1190,92 @@ class TestWholePathSpans:
             6 + len(retries)
         assert n_allocs == 1 + 6 * 2
 
+    def test_plan_encode_once_a_commit_window_on_both_wire_formats(self):
+        """``plan.encode`` (accepted portions -> log entry) is recorded
+        once a commit window, one span per member plan over the
+        window's one interval as ``raft.apply`` is, on the committer's
+        thread, whichever wire format the window took: a lone plan's
+        ``ALLOC_UPDATE_REQUEST`` (``plans`` 1: every one-by-one
+        re-plan) and a window's ``PLAN_BATCH_APPLY_REQUEST``."""
+        _before, _after, spans, _n = self._replan_storm(("replans",))
+
+        def windows(name: str) -> dict:
+            out = {}
+            for s in spans:
+                if s["name"] == name:
+                    out.setdefault((s["t0"], s["dur"]), []).append(s)
+            return dict(sorted(out.items()))
+
+        encodes, applies = windows("plan.encode"), windows("raft.apply")
+        assert len(encodes) == len(applies) >= 3
+        for (enc, members), (app, committed) in zip(encodes.items(),
+                                                    applies.items()):
+            # Encoded before it was dispatched, one span a member.
+            assert enc[0] <= app[0] and enc[0] + enc[1] <= app[0] + app[1]
+            assert {_tags(s)["eval_id"] for s in members} == \
+                {_tags(s)["eval_id"] for s in committed}
+            assert {_tags(s)["plans"] for s in members} == \
+                {_tags(s)["window"] for s in committed} == {len(members)}
+            assert all(_tags(s)["bytes"] > 0 for s in members)
+            assert {s["thread"] for s in members} == \
+                {s["thread"] for s in committed}
+            parents = {s["span_id"]: s for s in spans}
+            assert all(parents[s["parent_id"]]["name"] == "eval.created"
+                       for s in members)
+        sizes = {len(members) for members in encodes.values()}
+        assert 1 in sizes and max(sizes) > 1, sizes
+
+    def test_the_runner_says_what_it_computed_chose_to_wait_and_stood(self):
+        """On a served batch every stage span of the runner's thread
+        carries ``cpu_s`` and ``blocked_s``; the status write stays the
+        parent of its raft apply; and the benchmark's own readers make
+        100 of the batch: on a CPU, in waits it chose, runnable and not
+        running (``runner_on_cpu_share`` + ``runner_blocked_share`` +
+        ``runner_stalled_share``), with the re-plans' share of the
+        cycle and the stage table beside them."""
+        _before, _after, spans, _n = self._replan_storm(("replans",))
+        staged = ("worker.batch", "worker.sync", "worker.snapshot",
+                  "worker.ack", "sched.begin", "sched.dispatch",
+                  "sched.finish", "sched.submit", "sched.status",
+                  "sched.retry", "retry.refresh", "retry.begin",
+                  "retry.dispatch", "retry.finish", "retry.submit")
+        seen = set()
+        for s in spans:
+            if s["name"] in staged:
+                seen.add(s["name"])
+                assert _tags(s)["cpu_s"] >= 0.0, s
+                # Wall less CPU inside the waits, from two clocks read
+                # one after the other: a few microseconds either way.
+                assert _tags(s)["blocked_s"] >= -1e-4, s
+                assert s["thread"] == "scheduler-worker"
+        assert seen == set(staged)
+        by_id = {s["span_id"]: s for s in spans}
+        applies = [s for s in spans
+                   if s["name"] == "server.apply.eval_update"
+                   and by_id.get(s["parent_id"], {}).get("name")
+                   == "sched.status"]
+        assert len(applies) == sum(s["name"] == "sched.status"
+                                   for s in spans) >= 6
+        # A re-plan waits for its plan: most of ``retry.submit`` is a
+        # wait the runner chose.
+        submits = [s for s in spans if s["name"] == "retry.submit"]
+        assert sum(_tags(s)["blocked_s"] for s in submits) > \
+            0.5 * sum(s["dur"] for s in submits)
+        ctx = {"spans": spans, "notes": []}
+        stages, cycle = _reducer("runner_stages"), _reducer("runner_cycle")
+        on_cpu = cycle.reduce({"what": "on_cpu_share"}, ctx)
+        blocked = stages.reduce({"what": "blocked_share"}, ctx)
+        stalled = stages.reduce({"what": "stalled_share"}, ctx)
+        assert on_cpu + blocked + stalled == pytest.approx(100.0)
+        assert blocked > 0.0
+        assert 0.0 < stages.reduce({"what": "retry_cycle_share"}, ctx) \
+            < 100.0
+        assert stages.reduce({"what": "retry_stalled_share"}, ctx) \
+            is not None
+        table = [n for n in ctx["notes"] if n.startswith("  ")]
+        assert {n.split(":")[0].split(" [")[0].strip()
+                for n in table} >= set(staged)
+
     def test_fit_walk_rows_ride_the_prep_spans_and_two_counters(self):
         """Every prep says how many rows its fit walk examined
         (``fit_rows``) of those a whole walk examines (``fit_rows_full``
