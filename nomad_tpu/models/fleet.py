@@ -234,6 +234,16 @@ class FleetStatics:
         avail = self.capacity[:self.n_real] - self.reserved[:self.n_real]
         return float(avail[:, 0].min()), float(avail[:, 1].min())
 
+    @cached_property
+    def host_scorer(self):
+        """The numpy twin's node-static pieces over the real rows
+        (ops/binpack_host._HostScorer: reserved base, valid rows, safe
+        divisors, and the node shapes it derives on first use): built
+        once a fleet generation, not once a twin call."""
+        from nomad_tpu.ops.binpack_host import _HostScorer
+        return _HostScorer(self.capacity[:self.n_real],
+                           self.reserved[:self.n_real])
+
     def device_capacity_reserved(self):
         from nomad_tpu.parallel.devices import ensure_on_default, \
             on_default_platform

@@ -75,7 +75,8 @@ re-plan; name, extent and tags as before) is a parent: per attempt
 (``attempt`` tag) ``retry.begin`` (job lookup, reconcile, prep, up to
 the kernel call), ``retry.dispatch`` (the kernel call; ``engine``,
 ``slots``, ``mode``, ``rounds`` as ``dispatch_tags`` gives them; on the
-host engine its duration is the span's ``twin_s``), ``retry.finish``
+host engine its duration is the span's ``twin_s``, and it says
+``twin_rows`` of ``twin_rows_full`` as the span does), ``retry.finish``
 (results -> plan; ``node_inits``, ``walked``) and ``retry.submit``
 (``planner.submit_plan``: enqueue -> result, a forced refresh
 included), recorded from a :class:`StageClock` the retrying scheduler

@@ -82,7 +82,7 @@ def _stage_spans(name: str, scheds, clock, span_ids: Optional[dict] = None,
 
 
 def dispatch_tags(rounds_mode: bool, rounds: int, engine: str,
-                  cost: int, lanes: int, slots: int) -> dict:
+                  cost: int, lanes: int, slots: int, twin=None) -> dict:
     """What a lane's ``sched.dispatch`` span says of the kernel it rode
     (nothing while tracing is off).  ``mode`` is the kernel the
     dispatch ran — ``rounds`` (one scoring pass per slot and top-k
@@ -96,12 +96,21 @@ def dispatch_tags(rounds_mode: bool, rounds: int, engine: str,
     ``lanes`` how many lanes shared that choice (a fused window's, 1
     for a lone eval); ``slots`` is THIS lane's real slot count
     (``DeviceArgs.n_groups``: its task groups after those of one ask
-    have deduped), where ``cost`` counts the padded axis."""
+    have deduped), where ``cost`` counts the padded axis.  On the host
+    engine, with ``twin`` the lane's scheduler: ``twin_rows`` of
+    ``twin_rows_full``, the rows the twin's rounds passes scored for it
+    (``place_rounds_host``'s candidate sets, summed over slots and
+    rounds) of those whole passes score (both 0 on the sequence
+    kernel)."""
     if not trace_mod.ENABLED:
         return {}
-    return {"mode": "rounds" if rounds_mode else "sequence",
+    tags = {"mode": "rounds" if rounds_mode else "sequence",
             "rounds": rounds if rounds_mode else 0, "engine": engine,
             "cost": cost, "lanes": lanes, "slots": slots}
+    if twin is not None and engine == "host":
+        tags["twin_rows"] = twin.twin_rows
+        tags["twin_rows_full"] = twin.twin_rows_full
+    return tags
 
 
 class BatchEvalRunner:
@@ -168,6 +177,11 @@ class BatchEvalRunner:
         # they would have examined had none stopped early.
         self.fit_rows = 0
         self.fit_rows_full = 0
+        # Its schedulers' ``twin_rows`` / ``twin_rows_full``: rows the
+        # numpy twin's rounds passes scored, and the rows they would
+        # have scored had every candidate set been the fleet.
+        self.twin_rows = 0
+        self.twin_rows_full = 0
         # Finish: per-node network states built, and how many of those
         # walked the node's allocations because the usage mirror's
         # occupancy could not serve them (nomad.finish.*).
@@ -181,7 +195,7 @@ class BatchEvalRunner:
     def _note_dispatch(self, sched) -> None:
         """Fold one scheduler's own kernel-call counts (its single-eval
         dispatches and finish-loop host re-plans) and its whole-store
-        usage walks and fit-walk rows into the mix."""
+        usage walks, fit-walk rows and twin rows into the mix."""
         calls = sched.kernel_calls
         self.host_dispatches += calls["host"]
         self.device_dispatches += calls["device"]
@@ -196,6 +210,9 @@ class BatchEvalRunner:
         self.fit_rows += sched.fit_rows
         self.fit_rows_full += sched.fit_rows_full
         sched.fit_rows = sched.fit_rows_full = 0
+        self.twin_rows += sched.twin_rows
+        self.twin_rows_full += sched.twin_rows_full
+        sched.twin_rows = sched.twin_rows_full = 0
 
     def _note_finish(self, scheds: list) -> dict:
         """Fold the schedulers' node-init counts into nomad.finish.*;
@@ -224,6 +241,8 @@ class BatchEvalRunner:
             "usage_walks": self.usage_walks,
             "fit_rows": self.fit_rows,
             "fit_rows_full": self.fit_rows_full,
+            "twin_rows": self.twin_rows,
+            "twin_rows_full": self.twin_rows_full,
         }
 
     def finish_stats(self) -> dict:
@@ -364,7 +383,8 @@ class BatchEvalRunner:
         # seconds its ``dispatch_host`` calls spent in the numpy twin
         # with the real slots they carried (a re-plan has no
         # ``sched.dispatch`` span of its own), and the rows its preps'
-        # fit walks examined, before they are folded.
+        # fit walks examined and its twin's rounds passes scored,
+        # before they are folded.
         calls = {"host_calls": retry.kernel_calls["host"],
                  "device_calls": retry.kernel_calls["device"],
                  "attempts": retry.attempts,
@@ -372,7 +392,9 @@ class BatchEvalRunner:
                  "twin_s": retry.twin_s,
                  "twin_slots": retry.twin_slots,
                  "fit_rows": retry.fit_rows,
-                 "fit_rows_full": retry.fit_rows_full} \
+                 "fit_rows_full": retry.fit_rows_full,
+                 "twin_rows": retry.twin_rows,
+                 "twin_rows_full": retry.twin_rows_full} \
             if trace_mod.ENABLED else {}
         self._note_dispatch(retry)
         self._note_finish([retry])
@@ -660,7 +682,8 @@ class BatchEvalRunner:
                     statics.capacity, statics.reserved, base_usage,
                     args.view.job_counts, args.feasible_h, args.asks,
                     args.distinct, args.counts, float(args.penalty),
-                    k_cap=k_cap, rounds=rounds, n_real=n_real)
+                    k_cap=k_cap, rounds=rounds, n_real=n_real,
+                    scorer=statics.host_scorer, tally=sched)
                 chosen, scores = rounds_to_placements(
                     args, chosen_s, score_s)
             else:
@@ -672,7 +695,7 @@ class BatchEvalRunner:
             _stage_spans("sched.dispatch", [sched], clock, host=True,
                          **dispatch_tags(
                              rounds_ok, rounds, "host", fused_cost,
-                             len(pending), args.n_groups))
+                             len(pending), args.n_groups, sched))
             self.host_dispatches += 1
             self.slots += args.n_groups
             self.padded_slots += args.g_pad
@@ -701,7 +724,7 @@ class BatchEvalRunner:
             args.rounds_eligible, args.rounds,
             "host" if sched.dispatched_host else
             "sharded" if sched.dispatched_sharded else "device",
-            sched.dispatch_cost(args), 1, args.n_groups))
+            sched.dispatch_cost(args), 1, args.n_groups, sched))
         sched.finish_deferred(place, args, chosen, scores)
         self._note_dispatch(sched)
         _stage_spans("sched.finish", [sched], clock,

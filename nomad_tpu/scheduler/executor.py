@@ -36,13 +36,16 @@ kernel on one chip) or ``sharded`` (the same over a mesh), beside
 that shared the choice and the lane's own REAL slot count (``slots``,
 also on its ``sched.begin``; a one-by-one re-plan has no
 ``sched.dispatch`` and states ``twin_s`` / ``twin_slots`` on its
-``sched.retry``);
+``sched.retry``) and, on the host engine, ``twin_rows`` of
+``twin_rows_full``: the rows the twin's candidate sets held, of those
+whole passes score (on ``sched.retry`` and ``retry.dispatch`` too);
 ``nomad.batch_runner.{host,device,sharded}_dispatches`` count the same
 choice always, ``nomad.batch_runner.{host,device}_lanes`` the lanes
 each engine placed (a fused device window is one dispatch of many
 lanes), and ``nomad.batch_runner.slots`` / ``.padded_slots`` the real
 slots of every kernel call beside the padded slot axis it was shaped
-to.
+to, and ``nomad.batch_runner.twin_rows`` / ``.twin_rows_full`` those
+rows.
 
 Where the break-even falls.  A fused window scans its PADDED slot axis
 (``g_pad``, at least 8), so a window of single-group lanes costs lanes x
@@ -53,15 +56,20 @@ at 42 lanes, whether its lanes carry one real slot or that cell's
 three.  A lone eval counts its real slots: one group on 131,072 nodes
 is 2^17, inside ``HOST_ALWAYS_COST``; a three-slot stack on 100,000 is
 300,000, inside ``HOST_SINGLE_SHOT_COST``.  Measured on a v5e at 131,072 nodes
-(PERF.md section 6, PR 33): the twin takes 8.4-10.2 ms a lane (539 ms
-for 64 lanes); a fused window on the kernel takes 165 ms at 64 lanes
+(PERF.md section 6, PR 33): the twin's pass over every row takes
+8.4-10.2 ms a lane (539 ms for 64 lanes; since PR 38 a lane whose fleet
+is 2% occupied scores a candidate set in 1.0 ms, and these figures are
+a full fleet's); a fused window on the kernel takes 165 ms at 64 lanes
 (61 ms from enqueue to results, 50 ms of it device time, 101 MB
 uploaded), 52 ms at 32 lanes, 14 ms at 16 and 7.7 ms at one (the twin:
 10.2); a fenced round trip of a tiny kernel is 0.99 ms.  At that width
-the kernel wins at every lane count, and ``auto`` still keeps a window
-of 32 lanes or fewer on the twin (275 ms against 52): the thresholds
-predate these numbers, and moving them is ROADMAP D2's, judged on
-``fleet131k.storm`` and ``baseline4-10k.small``.
+the kernel won at every lane count against the pass over every row
+(not against a candidate set's 1.0 ms), and ``auto`` still keeps a window
+of 32 lanes or fewer on the twin (275 ms against 52, before PR 38):
+the thresholds predate these numbers, the both-engines sweep has to be
+run again on the candidate-set twin (PERF.md section 7), and moving
+them is ROADMAP D2's, judged on ``fleet131k.storm`` and
+``baseline4-10k.small``.
 
 The override only selects the executor; plan semantics are identical on
 both sides (tests/test_executor_parity.py gates this on every run).

@@ -648,6 +648,12 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         # the walk examines where no block settles its answer.
         self.fit_rows = 0
         self.fit_rows_full = 0
+        # Rows the numpy twin's rounds passes scored for this
+        # scheduler (``place_rounds_host``'s candidate sets), summed
+        # over slots and rounds, and ``n_real`` a slot-round: what
+        # whole passes score.
+        self.twin_rows = 0
+        self.twin_rows_full = 0
 
     def _process(self) -> bool:
         self.attempts += 1
@@ -715,13 +721,22 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
     # slots; a fused window counts the PADDED slot axis its kernel scans
     # (g_pad, at least 8), so a window of single-group lanes reaches
     # 2^25 at lanes x nodes = 2^22: 33 lanes of 131,072 nodes, 420 of
-    # 10,000.  Measured on a v5e's host (PR 33): numpy takes 65-80 ns a
-    # real slot and node (0.69 ms a lane of 10,000 nodes, 8.4-10.2 ms of
-    # 131,072).
-    # One lane x one slot x 262,144 nodes: ~20 ms of numpy.
+    # 10,000.  Measured on a v5e's host: the twin's pass over EVERY row
+    # takes 60-80 ns a real slot and node (0.68-0.69 ms a lane of 10,000
+    # nodes, 7.6-10.2 ms of 131,072; PRs 33, 38).  Since PR 38 it scores
+    # a candidate set (ops/binpack_host.place_rounds_host): a lane of
+    # 131,072 nodes of which 2% hold something takes 1.0 ms, of 10,000
+    # nodes 0.22, and only a fleet over a third occupied pays the whole
+    # pass.  The figures beside the two constants are that whole pass's,
+    # an upper bound since; the both-engines sweep on this twin is owed
+    # (PERF.md section 7, ROADMAP S4 / D2).
+    # One lane x one slot x 262,144 nodes: ~20 ms of numpy over every
+    # row, ~2 over a candidate set.
     HOST_ALWAYS_COST = 1 << 18
-    # 32 lanes x 8 padded slots x 131,072 nodes: 275 ms of numpy, where
-    # the kernel's fused window takes 52 ms (165 ms at 64 lanes).
+    # 32 lanes x 8 padded slots x 131,072 nodes: 275 ms of numpy over
+    # every row (~32 over candidate sets, by the lane above: not
+    # measured as a window), where the kernel's fused window takes
+    # 52 ms (165 ms at 64 lanes).
     HOST_SINGLE_SHOT_COST = 1 << 25
 
     @classmethod
@@ -811,13 +826,15 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         t0 = time.perf_counter() if traced else 0.0
         if traced:
             self._stage("retry.begin", t0)
+        rows0, full0 = self.twin_rows, self.twin_rows_full
         if args.rounds_eligible:
             chosen, scores, _ = place_rounds_host(
                 statics.capacity, statics.reserved, args.view.usage,
                 args.view.job_counts, args.feasible_h, args.asks,
                 args.distinct, args.counts, args.penalty,
                 k_cap=args.k_cap, rounds=args.rounds,
-                n_real=statics.n_real)
+                n_real=statics.n_real, scorer=statics.host_scorer,
+                tally=self)
         else:
             chosen, scores, _ = place_sequence_host(
                 statics.capacity, statics.reserved, args.view.usage,
@@ -828,7 +845,9 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
             t1 = time.perf_counter()
             self.twin_s += t1 - t0
             self.twin_slots += args.n_groups
-            self._stage("retry.dispatch", t1, args=args, engine="host")
+            self._stage("retry.dispatch", t1, args=args, engine="host",
+                        twin_rows=self.twin_rows - rows0,
+                        twin_rows_full=self.twin_rows_full - full0)
         return chosen, scores
 
     def dispatch_device(self, args: "DeviceArgs",

@@ -276,6 +276,10 @@ def test_a_slot_with_more_copies_than_nodes_takes_a_second_round():
             # computed and chose to wait over the stage.
             assert all(lane.pop("cpu_s") >= 0.0 and
                        lane.pop("blocked_s") >= 0.0 for lane in lanes)
+            # ... and the rows the twin scored: 15 slots of one round
+            # and one of two, on a fleet too small for a candidate set.
+            assert all(lane.pop("twin_rows") == lane.pop("twin_rows_full")
+                       == 17 * N_NODES for lane in lanes)
             assert lanes == [{
                 "eval_id": lanes[0]["eval_id"], "host": True,
                 "mode": "rounds", "rounds": 2, "engine": "host",

@@ -409,6 +409,66 @@ def test_fit_walk_rows_on_the_prep_spans_and_in_the_stats(monkeypatch):
     _assert_placed_exactly(h, nodes, jobs)
 
 
+# ---------------------------------------------------------------------------
+# The numpy twin's candidate sets: rows scored, as the spans and the
+# runner say.
+# ---------------------------------------------------------------------------
+
+def _twin_rows_of(n_nodes: int, hold_something: bool):
+    """The contended storm on ``n_nodes`` under the tracer: the tags of
+    its host-engine ``sched.dispatch`` spans, of its ``sched.retry``
+    spans and of their ``retry.dispatch`` children, and the runner's
+    stats.  ``hold_something``: every node starts with an allocation
+    of another job on it."""
+    h, nodes, jobs = _contended_storm(n_nodes=n_nodes)
+    if hold_something:
+        held = []
+        for n in nodes:
+            a = mock.alloc()
+            a.node_id = n.id
+            held.append(a)
+        h.state.upsert_allocs(h.next_index(), held)
+    with trace.tracing(seed=38) as tracer:
+        runner = BatchEvalRunner(h.state.snapshot(), h,
+                                 state_refresh=h.snapshot)
+        runner.process([make_eval(j) for j in jobs])
+        spans = tracer.snapshot()
+    lanes = [s["tags"] for s in spans if s["name"] == "sched.dispatch"]
+    assert lanes and all(t["engine"] == "host" for t in lanes)
+    retries = [s["tags"] for s in spans if s["name"] == "sched.retry"]
+    attempts = [s["tags"] for s in spans if s["name"] == "retry.dispatch"]
+    assert len(retries) >= 3 and len(attempts) >= len(retries)
+    assert [e.status for e in h.evals] == ["complete"] * len(jobs)
+    return lanes, retries, attempts, runner.stats()
+
+
+def test_twin_rows_on_an_empty_fleet_are_a_few_of_its_rows():
+    """A host ``sched.dispatch``, a ``sched.retry`` and its
+    ``retry.dispatch`` carry ``twin_rows`` of ``twin_rows_full``, and
+    the runner's stats hold their sum.  On an empty fleet of 2,048
+    nodes the twin scores the rows the batch has filled and sixteen
+    empty ones: under a tenth of what whole passes score."""
+    lanes, retries, attempts, stats = _twin_rows_of(2048, False)
+    for t in lanes + retries + attempts:
+        assert 0 < t["twin_rows"] <= t["twin_rows_full"]
+        assert t["twin_rows_full"] == 2048 * t.get("attempts", 1)
+    for name in ("twin_rows", "twin_rows_full"):
+        assert sum(t[name] for t in attempts) == \
+            sum(t[name] for t in retries)
+        assert stats[name] == sum(t[name] for t in lanes + retries)
+    assert stats["twin_rows"] < 0.10 * stats["twin_rows_full"]
+
+
+def test_twin_rows_on_a_full_fleet_are_all_of_its_rows():
+    """Where every node holds something the candidate set is every row:
+    the share reads 100%."""
+    lanes, retries, attempts, stats = _twin_rows_of(64, True)
+    for t in lanes + retries + attempts:
+        assert t["twin_rows"] == t["twin_rows_full"] \
+            == 64 * t.get("attempts", 1)
+    assert stats["twin_rows"] == stats["twin_rows_full"] > 0
+
+
 def test_fleet_minima_are_read_once_a_fleet_generation():
     """The least available cpu and memory the prep's gain bound divides
     by are a constant of the fleet generation: kept on its statics,

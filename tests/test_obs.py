@@ -1299,6 +1299,37 @@ class TestWholePathSpans:
             assert after[name] - before[name] == \
                 sum(t[name] for t in begins + retries)
 
+    def test_twin_rows_ride_the_host_dispatches_and_two_counters(self):
+        """Every rounds pass of the numpy twin says how many rows it
+        scored (``twin_rows``) of those whole passes score
+        (``twin_rows_full`` = the fleet's real rows a slot and round):
+        on a host-engine ``sched.dispatch`` for a fused round's lane,
+        on ``sched.retry`` and its ``retry.dispatch`` for a one-by-one
+        re-plan, and ``nomad.batch_runner.twin_rows`` /
+        ``.twin_rows_full`` under /v1/agent/metrics move by their sum.
+        On eight nodes sixteen empties a shape are over the fall-back
+        line: every pass scores them all."""
+        before, after, spans, _n = self._replan_storm(
+            ("twin_rows", "twin_rows_full", "replans"))
+        lanes = [_tags(s) for s in spans if s["name"] == "sched.dispatch"]
+        retries = [_tags(s) for s in spans if s["name"] == "sched.retry"]
+        attempts = [_tags(s) for s in spans
+                    if s["name"] == "retry.dispatch"]
+        assert len(lanes) > 6 and retries
+        assert after["replans"] - before["replans"] == len(retries)
+        for t in lanes + attempts:
+            assert t["engine"] == "host"
+            assert 0 < t["twin_rows"] <= t["twin_rows_full"] \
+                == 8 * t["slots"]
+        for t in retries:
+            assert 0 < t["twin_rows"] <= t["twin_rows_full"] \
+                == 8 * t["attempts"]
+        for name in ("twin_rows", "twin_rows_full"):
+            assert sum(t[name] for t in attempts) == \
+                sum(t[name] for t in retries)
+            assert after[name] - before[name] == \
+                sum(t[name] for t in lanes + retries)
+
     def test_slot_tags_and_counters_say_how_many_slots_a_lane_carried(self):
         """A job of three groups whose asks differ is a lane of three
         REAL kernel slots; one whose two groups share an ask dedupes to
