@@ -14,12 +14,19 @@ value tuple, so a 10k-node fleet pays the Python predicate walk once per
 
 ``distinct_hosts`` is NOT compiled here — it depends on the in-flight plan,
 so it is evaluated on device from the per-node same-job alloc count tensor.
+
+A group-mask miss is what costs: the counters ``nomad.constraints.
+mask_compiles`` / ``.rows_judged`` (always on) count the misses and the
+rows the Python predicate walks judged for them, and with tracing on the
+miss is one ``constraints.compile`` span (``rows``, ``stanzas``).
 """
 from __future__ import annotations
 
 import numpy as np
 
+from nomad_tpu.obs import trace as trace_mod
 from nomad_tpu.structs import CONSTRAINT_DISTINCT_HOSTS, Constraint
+from nomad_tpu.utils.metrics import metrics
 from nomad_tpu.utils.predicates import (
     check_constraint_values,
     resolve_constraint_target,
@@ -47,10 +54,15 @@ def _constraint_key(c: Constraint) -> tuple:
 
 def compile_constraint_mask(fleet: FleetStatics, c: Constraint) -> np.ndarray:
     """bool[n_pad] mask of nodes meeting one hard constraint."""
+    return _constraint_mask(fleet, c)[0]
+
+
+def _constraint_mask(fleet: FleetStatics, c: Constraint) -> tuple:
+    """(the mask, rows judged in Python to make it: 0 on a hit)."""
     key = _constraint_key(c)
     mask = fleet.mask_cache.get(key)
     if mask is not None:
-        return mask
+        return mask, 0
 
     mask = np.zeros(fleet.n_pad, dtype=bool)
     if fleet.uniform and fleet.n_real and _targets_uniform(c):
@@ -58,12 +70,14 @@ def compile_constraint_mask(fleet: FleetStatics, c: Constraint) -> np.ndarray:
         # dc): the predicate's verdict on ONE representative row holds
         # for every row — O(1) instead of a 100k-1M-node Python walk.
         mask[:fleet.n_real] = _constraint_verdict(fleet.nodes[0], c)
+        judged = 1
     else:
         for i in range(fleet.n_real):
             mask[i] = _constraint_verdict(fleet.nodes[i], c)
+        judged = fleet.n_real
 
     fleet.mask_cache[key] = mask
-    return mask
+    return mask, judged
 
 
 # Interpolation targets that resolve PER ROW even on a uniform fleet:
@@ -90,12 +104,13 @@ def _constraint_verdict(node, c: Constraint) -> bool:
     return check_constraint_values(_mask_ctx, c.operand, l_val, r_val)
 
 
-def compile_driver_mask(fleet: FleetStatics, driver: str) -> np.ndarray:
-    """bool[n_pad] mask of nodes whose 'driver.<name>' attr parses true."""
+def _driver_mask(fleet: FleetStatics, driver: str) -> tuple:
+    """(bool[n_pad] mask of nodes whose 'driver.<name>' attr parses
+    true, rows judged in Python to make it: 0 on a hit)."""
     key = ("d", driver)
     mask = fleet.mask_cache.get(key)
     if mask is not None:
-        return mask
+        return mask, 0
 
     attr = f"driver.{driver}"
     mask = np.zeros(fleet.n_pad, dtype=bool)
@@ -110,26 +125,29 @@ def compile_driver_mask(fleet: FleetStatics, driver: str) -> np.ndarray:
         mask[:fleet.n_real] = mask[0]
 
     fleet.mask_cache[key] = mask
-    return mask
+    return mask, len(rows)
 
 
-def compile_dc_mask(fleet: FleetStatics, datacenters: list) -> np.ndarray:
-    """bool[n_pad] mask of nodes in one of the job's datacenters."""
+def _dc_mask(fleet: FleetStatics, datacenters: list) -> tuple:
+    """(bool[n_pad] mask of nodes in one of the job's datacenters, rows
+    judged in Python to make it: 0 on a hit)."""
     key = ("dc", tuple(sorted(datacenters)))
     mask = fleet.mask_cache.get(key)
     if mask is not None:
-        return mask
+        return mask, 0
 
     dc_set = set(datacenters)
     mask = np.zeros(fleet.n_pad, dtype=bool)
     if fleet.uniform and fleet.n_real:
         mask[:fleet.n_real] = fleet.datacenters[0] in dc_set
+        judged = 1
     else:
         for i in range(fleet.n_real):
             mask[i] = fleet.datacenters[i] in dc_set
+        judged = fleet.n_real
 
     fleet.mask_cache[key] = mask
-    return mask
+    return mask, judged
 
 
 def group_mask_key(datacenters: list, job_constraints: list,
@@ -168,13 +186,26 @@ def compile_group_mask(
     if hit is not None:
         return hit, distinct
 
+    tracer = trace_mod.tracer() if trace_mod.ENABLED else None
+    t0 = tracer.now() if tracer is not None else 0.0
     mask = fleet.ready.copy()
-    mask &= compile_dc_mask(fleet, datacenters)
+    part, judged = _dc_mask(fleet, datacenters)
+    mask &= part
     for c in job_constraints + tg_constraints:
         if not c.hard or c.operand == CONSTRAINT_DISTINCT_HOSTS:
             continue
-        mask &= compile_constraint_mask(fleet, c)
+        part, rows = _constraint_mask(fleet, c)
+        mask &= part
+        judged += rows
     for driver in sorted(drivers):
-        mask &= compile_driver_mask(fleet, driver)
+        part, rows = _driver_mask(fleet, driver)
+        mask &= part
+        judged += rows
     fleet.mask_cache[key] = mask
+    metrics.incr_counter("nomad.constraints.mask_compiles", 1)
+    metrics.incr_counter("nomad.constraints.rows_judged", judged)
+    if tracer is not None:
+        tracer.record("constraints.compile", t0, tracer.now() - t0,
+                      parent_ctx=tracer.ctx(), rows=judged,
+                      stanzas=len(key[2]))
     return mask, distinct

@@ -182,6 +182,12 @@ class BatchEvalRunner:
         # have scored had every candidate set been the fleet.
         self.twin_rows = 0
         self.twin_rows_full = 0
+        # Its schedulers' ``feasible_rows`` / ``feasible_rows_full`` /
+        # ``distinct_slots``: the rows the preps' slot masks admitted,
+        # ``n_real`` a slot, and the slots under ``distinct_hosts``.
+        self.feasible_rows = 0
+        self.feasible_rows_full = 0
+        self.distinct_slots = 0
         # Finish: per-node network states built, and how many of those
         # walked the node's allocations because the usage mirror's
         # occupancy could not serve them (nomad.finish.*).
@@ -213,6 +219,11 @@ class BatchEvalRunner:
         self.twin_rows += sched.twin_rows
         self.twin_rows_full += sched.twin_rows_full
         sched.twin_rows = sched.twin_rows_full = 0
+        self.feasible_rows += sched.feasible_rows
+        self.feasible_rows_full += sched.feasible_rows_full
+        self.distinct_slots += sched.distinct_slots
+        sched.feasible_rows = sched.feasible_rows_full = 0
+        sched.distinct_slots = 0
 
     def _note_finish(self, scheds: list) -> dict:
         """Fold the schedulers' node-init counts into nomad.finish.*;
@@ -243,6 +254,9 @@ class BatchEvalRunner:
             "fit_rows_full": self.fit_rows_full,
             "twin_rows": self.twin_rows,
             "twin_rows_full": self.twin_rows_full,
+            "feasible_rows": self.feasible_rows,
+            "feasible_rows_full": self.feasible_rows_full,
+            "distinct_slots": self.distinct_slots,
         }
 
     def finish_stats(self) -> dict:
@@ -297,11 +311,17 @@ class BatchEvalRunner:
                 del self.stage_span[ev.id]
                 # ``slots``: the real slots this lane takes to a kernel;
                 # ``fit_rows`` of ``fit_rows_full``: the rows its prep's
-                # fit walk examined, of those a whole walk examines
-                # (none where the eval needs no placement).
+                # fit walk examined, of those a whole walk examines;
+                # ``feasible_rows`` of ``feasible_rows_full``: the rows
+                # its slots' masks admit, of ``n_real`` a slot, and
+                # ``distinct_slots`` (none where the eval needs no
+                # placement).
                 tags = {"slots": sched.deferred[1].n_groups,
                         "fit_rows": sched.fit_rows,
-                        "fit_rows_full": sched.fit_rows_full} \
+                        "fit_rows_full": sched.fit_rows_full,
+                        "feasible_rows": sched.feasible_rows,
+                        "feasible_rows_full": sched.feasible_rows_full,
+                        "distinct_slots": sched.distinct_slots} \
                     if sched is not None and sched.deferred is not None \
                     else {}
                 t0, dur, pair = clock.lap()
@@ -383,8 +403,8 @@ class BatchEvalRunner:
         # seconds its ``dispatch_host`` calls spent in the numpy twin
         # with the real slots they carried (a re-plan has no
         # ``sched.dispatch`` span of its own), and the rows its preps'
-        # fit walks examined and its twin's rounds passes scored,
-        # before they are folded.
+        # fit walks examined, its twin's rounds passes scored and its
+        # slots' masks admitted, before they are folded.
         calls = {"host_calls": retry.kernel_calls["host"],
                  "device_calls": retry.kernel_calls["device"],
                  "attempts": retry.attempts,
@@ -394,7 +414,10 @@ class BatchEvalRunner:
                  "fit_rows": retry.fit_rows,
                  "fit_rows_full": retry.fit_rows_full,
                  "twin_rows": retry.twin_rows,
-                 "twin_rows_full": retry.twin_rows_full} \
+                 "twin_rows_full": retry.twin_rows_full,
+                 "feasible_rows": retry.feasible_rows,
+                 "feasible_rows_full": retry.feasible_rows_full,
+                 "distinct_slots": retry.distinct_slots} \
             if trace_mod.ENABLED else {}
         self._note_dispatch(retry)
         self._note_finish([retry])

@@ -654,6 +654,13 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         # whole passes score.
         self.twin_rows = 0
         self.twin_rows_full = 0
+        # For this scheduler's preps, summed over their real slots: the
+        # rows each slot's feasibility mask admits, ``n_real`` a slot,
+        # and the slots under ``distinct_hosts`` (counted once a
+        # feasibility entry, ``_tally_feasible``).
+        self.feasible_rows = 0
+        self.feasible_rows_full = 0
+        self.distinct_slots = 0
 
     def _process(self) -> bool:
         self.attempts += 1
@@ -1088,6 +1095,7 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
             # generations' HBM buffers for the job's lifetime.
             feas = statics.device_cache.get(tmpl[4])
             if feas is not None:
+                self._tally_feasible(feas, statics)
                 return _refresh_rounds(DeviceArgs(
                     statics=statics, view=view, start=start,
                     feasible_d=feas, feasible_h=feas[0], **tmpl[5]), self)
@@ -1160,10 +1168,16 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
                     tg_constr.constraints, tg_constr.drivers)
                 feasible_h[g] = mask
             # Device copy is lazy (filled on first device dispatch) so
-            # host-executor evals never touch the device at all.
-            cached = [feasible_h, None]
+            # host-executor evals never touch the device at all.  What
+            # the tally reads rides with it, counted once: the rows the
+            # real slots admit, the real slots, and those of them under
+            # ``distinct_hosts`` (a slot key's last member).
+            cached = [feasible_h, None,
+                      (int(np.count_nonzero(feasible_h)), len(slot_keys),
+                       sum(1 for k in slot_keys if k[2]))]
             statics.device_cache[feas_key] = cached
         feasible_h = cached[0]
+        self._tally_feasible(cached, statics)
 
         group_idx = np.zeros(p_pad, dtype=np.int32)
         valid = np.zeros(p_pad, dtype=bool)
@@ -1224,6 +1238,14 @@ class JaxBinPackScheduler(GenericScheduler, FastPlacementMixin):
         return _refresh_rounds(DeviceArgs(
             statics=statics, view=view, start=start,
             feasible_d=cached, feasible_h=feasible_h, **kw), self)
+
+    def _tally_feasible(self, entry: list, statics) -> None:
+        """Fold one prep's feasibility entry (``_prepare_device``) into
+        ``feasible_rows`` / ``feasible_rows_full`` / ``distinct_slots``."""
+        rows, slots, distinct = entry[2]
+        self.feasible_rows += rows
+        self.feasible_rows_full += statics.n_real * slots
+        self.distinct_slots += distinct
 
     def finish_deferred(self, place: list, args: DeviceArgs,
                         chosen: np.ndarray, scores: np.ndarray,

@@ -1330,6 +1330,111 @@ class TestWholePathSpans:
             assert after[name] - before[name] == \
                 sum(t[name] for t in lanes + retries)
 
+    def test_feasible_rows_ride_the_prep_spans_and_three_counters(self):
+        """Every prep says how many rows its slots' masks admit
+        (``feasible_rows``) of ``n_real`` a slot (``feasible_rows_full``)
+        and how many of its slots are ``distinct_hosts``
+        (``distinct_slots``): on ``sched.begin`` for a fused round's
+        lane, on ``sched.retry`` summed over a re-plan's attempts, and
+        ``nomad.batch_runner.feasible_rows`` / ``.feasible_rows_full`` /
+        ``.distinct_slots`` move by their sum.  The storm's jobs state
+        no stanza: every ready row of the eight is admitted."""
+        names = ("feasible_rows", "feasible_rows_full", "distinct_slots")
+        before, after, spans, _n = self._replan_storm(names + ("replans",))
+        begins = [_tags(s) for s in spans if s["name"] == "sched.begin"]
+        retries = [_tags(s) for s in spans if s["name"] == "sched.retry"]
+        assert len(begins) > 6 and retries
+        for t in begins:
+            assert t["feasible_rows"] == t["feasible_rows_full"] \
+                == 8 * t["slots"]
+            assert t["distinct_slots"] == 0
+        for t in retries:
+            assert t["feasible_rows"] == t["feasible_rows_full"] \
+                == 8 * t["attempts"]
+        for name in names:
+            assert after[name] - before[name] == \
+                sum(t[name] for t in begins + retries)
+
+    @staticmethod
+    def _mixed_fleet():
+        """The program's statics of eight nodes, every second one of
+        another class: no row stands for the rest."""
+        from nomad_tpu.models.fleet import build_fleet
+
+        nodes = [mock.node(i) for i in range(8)]
+        for node in nodes[::2]:
+            node.attributes = dict(node.attributes, arch="arm64")
+        return build_fleet(nodes)
+
+    @staticmethod
+    def _mask_counters() -> tuple:
+        from nomad_tpu.utils.metrics import metrics
+
+        got = metrics.inmem.snapshot()["counters"]
+        return (got.get("nomad.constraints.mask_compiles", 0.0),
+                got.get("nomad.constraints.rows_judged", 0.0))
+
+    def test_a_group_mask_miss_is_one_compile_span_and_two_counters(self):
+        """``compile_group_mask`` on a miss records ONE
+        ``constraints.compile`` span, tagged with the rows its predicate
+        walks judged (datacenter, driver and each stanza, every row of a
+        fleet whose rows differ) and its hard stanzas;
+        ``nomad.constraints.mask_compiles`` / ``.rows_judged`` move by
+        one and by those rows.  A hit records and counts nothing."""
+        from nomad_tpu.models.constraints import compile_group_mask
+        from nomad_tpu.structs import Constraint
+
+        statics = self._mixed_fleet()
+        job_cons = [Constraint(hard=True, l_target="$attr.kernel.name",
+                               operand="=", r_target="linux")]
+        arm = [Constraint(hard=True, l_target="$attr.arch", operand="=",
+                          r_target="arm64"),
+               Constraint(hard=True, operand="distinct_hosts")]
+        x86 = [Constraint(hard=True, l_target="$attr.arch", operand="!=",
+                          r_target="arm64")]
+        before = self._mask_counters()
+        with trace.tracing(seed=41) as tracer:
+            mask, distinct = compile_group_mask(statics, ["dc1"], job_cons,
+                                                arm, {"exec"})
+            again, _d = compile_group_mask(statics, ["dc1"], job_cons, arm,
+                                           {"exec"})
+            other, _d = compile_group_mask(statics, ["dc1"], job_cons, x86,
+                                           {"exec"})
+            spans = [s for s in tracer.snapshot()
+                     if s["name"] == "constraints.compile"]
+        after = self._mask_counters()
+        assert distinct and again is mask
+        assert mask[:8].tolist() == [True, False] * 4
+        assert other[:8].tolist() == [False, True] * 4
+        # First miss: datacenter, job stanza, group stanza, driver; the
+        # second shares all but its own stanza.
+        assert [_tags(s) for s in spans] == [
+            {"rows": 4 * 8, "stanzas": 2}, {"rows": 8, "stanzas": 2}]
+        assert all(s["dur"] >= 0.0 for s in spans)
+        assert (after[0] - before[0], after[1] - before[1]) == (2, 5 * 8)
+
+    def test_mask_compile_with_tracing_off_reads_one_bool(self, monkeypatch):
+        """Tracing off, a group-mask miss touches no tracer (every
+        entry booby-trapped) and still counts."""
+        from nomad_tpu.models.constraints import compile_group_mask
+        from nomad_tpu.structs import Constraint
+
+        def boom(*_a, **_kw):
+            raise AssertionError("a tracing-only call ran with "
+                                 "tracing off")
+        assert trace.ENABLED is False
+        monkeypatch.setattr(trace, "tracer", boom)
+        monkeypatch.setattr(Tracer, "record", boom)
+        monkeypatch.setattr(Tracer, "now", boom)
+        before = self._mask_counters()
+        mask, distinct = compile_group_mask(
+            self._mixed_fleet(), ["dc1"], [],
+            [Constraint(hard=True, l_target="$attr.arch", operand="=",
+                        r_target="x86")], {"exec"})
+        after = self._mask_counters()
+        assert not distinct and mask[:8].tolist() == [False, True] * 4
+        assert (after[0] - before[0], after[1] - before[1]) == (1, 3 * 8)
+
     def test_slot_tags_and_counters_say_how_many_slots_a_lane_carried(self):
         """A job of three groups whose asks differ is a lane of three
         REAL kernel slots; one whose two groups share an ask dedupes to
