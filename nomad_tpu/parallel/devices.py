@@ -264,14 +264,20 @@ def classify_move(src_platform: str, dst_platform: str) -> str:
     return "d2d"
 
 
-def put_counted(x, device=None):
+def put_counted(x, device=None, copy: bool = False):
     """EXPLICIT placement of one per-dispatch host value onto the
     current platform (counted).  The dispatch seams route every
     per-eval varying argument (usage views, job counts, fused lane
     stacks) through here instead of letting jit commit them implicitly
     — an implicit transfer is invisible to the odometer AND trips the
     transfer-guard sanitizer; an explicit one is accounted.  Arrays
-    already resident on the default platform pass through untouched."""
+    already resident on the default platform pass through untouched.
+    ``copy``: the host array will be written again while the device
+    array lives, so the device array must not share its memory.  The
+    cpu backend may alias an aligned numpy buffer: where it did, a copy
+    is placed instead.  (An accelerator's array lives in its own
+    memory; its pointer is not read, which could wait for the
+    transfer.)"""
     if isinstance(x, jax.Array):
         if on_default_platform(x):
             return x
@@ -279,7 +285,11 @@ def put_counted(x, device=None):
         note_transfer(classify_move(src, current_platform()), 1, x)
         return jax.device_put(x, device or default_device())
     note_transfer("h2d", 1, x)
-    return jax.device_put(x, device or default_device())
+    out = jax.device_put(x, device or default_device())
+    if copy and x.size and next(iter(out.devices())).platform == "cpu" \
+            and out.unsafe_buffer_pointer() == x.ctypes.data:
+        out = jax.device_put(x.copy(), device or default_device())
+    return out
 
 
 def fetch_host(x):

@@ -113,6 +113,81 @@ def dispatch_tags(rounds_mode: bool, rounds: int, engine: str,
     return tags
 
 
+# Fused window shapes ``(b_pad, g_pad, n_pad)`` whose lane stacks a
+# runner keeps (``_WindowStaging``), the least recently used dropped
+# first: a storm meets one to three (lane buckets, slot axes).
+STAGED_SHAPES = 4
+# A lane position's job-count rows where it wrote none.
+_NO_ROWS = np.zeros(0, dtype=np.int64)
+
+
+class _WindowStaging:
+    """A fused window's two fleet-wide operands at one shape, kept from
+    window to window and rewritten only where its lanes changed: the
+    feasibility stack ``[b_pad, g_pad, n_pad]`` and the job counts
+    ``[b_pad, n_pad]``.  A lane position's mask rows are rewritten when
+    the lane's ``feas_key`` (its slot keys and ``g_pad``, in one fleet
+    generation: what the rows are a function of) is not the key they
+    hold; its job counts by clearing the rows it wrote last time and
+    writing the lane's own non-nought rows.  Pad lanes and a lane's pad
+    slots keep the mask rows they held: their counts are nought
+    (``valid`` False on the sequence kernel), so they place nothing,
+    and no caller reads what they score.  Pad lanes' job counts are
+    cleared, so with no real lane counted the stack is all nought.
+
+    Beside the host buffers, device arrays: the feasibility stack as
+    last uploaded, passed again while no position was rewritten, and a
+    stack of nought job counts, passed while no real lane holds a
+    count.  Host buffers are rewritten only by the next window, after
+    this one fetched its results, so no upload is in flight then."""
+
+    def __init__(self, b_pad: int, g_pad: int, n_pad: int) -> None:
+        self.feasible = np.zeros((b_pad, g_pad, n_pad), dtype=bool)
+        self.keys: list = [None] * b_pad
+        self.job_counts = np.zeros((b_pad, n_pad), dtype=np.int32)
+        self.rows: list = [_NO_ROWS] * b_pad
+        self.feasible_d = None
+        self.nought_d = None
+
+    def stage(self, pending: list) -> tuple[int, bool]:
+        """Bring the host buffers to ``pending``'s lanes: (real lane
+        positions whose mask rows were rewritten, whether a real lane
+        holds a job count)."""
+        restaged = 0
+        for b, (_s, _p, a) in enumerate(pending):
+            if a.feas_key is None or a.feas_key != self.keys[b]:
+                self.feasible[b, :a.g_pad] = a.feasible_h
+                self.keys[b] = a.feas_key
+                restaged += 1
+        if restaged:
+            # The device stack no longer holds what the host buffer does.
+            self.feasible_d = None
+        counted = False
+        jc = self.job_counts
+        for b, held in enumerate(self.rows):
+            if len(held):
+                jc[b, held] = 0
+            rows = _NO_ROWS
+            if b < len(pending):
+                view_jc = pending[b][2].view.job_counts
+                rows = np.flatnonzero(view_jc != 0)
+                if len(rows):
+                    jc[b, rows] = view_jc[rows]
+                    counted = True
+            self.rows[b] = rows
+        return restaged, counted
+
+    def resident(self, restaged: int, counted: bool) -> tuple[bool, bool]:
+        """May the window pass the device feasibility stack as it is,
+        and the device stack of nought job counts?"""
+        from nomad_tpu.parallel.devices import on_default_platform
+
+        def held(d) -> bool:
+            return d is not None and on_default_platform(d)
+        return (not restaged and held(self.feasible_d),
+                not counted and held(self.nought_d))
+
+
 class BatchEvalRunner:
     """Fuses a batch of evaluations into one device dispatch.
 
@@ -193,6 +268,16 @@ class BatchEvalRunner:
         # occupancy could not serve them (nomad.finish.*).
         self.finish_node_inits = 0
         self.finish_node_walks = 0
+        # Fused device windows' kept lane stacks by shape, for the fleet
+        # generation ``staging_gen`` (``_WindowStaging``), and how often
+        # they were reused: real lane positions whose mask rows were
+        # rewritten, and windows that passed the device feasibility
+        # stack or the nought job counts as they were.
+        self.staging: dict = {}
+        self.staging_gen = None
+        self.window_lanes_restaged = 0
+        self.window_feas_reuses = 0
+        self.window_jc_reuses = 0
         # Tracing only: eval id -> span id of the stage span (sched.begin
         # / sched.submit) that eval is under right now; the planner's
         # ``sched.status`` span (server/worker.py) takes it as parent.
@@ -257,7 +342,29 @@ class BatchEvalRunner:
             "feasible_rows": self.feasible_rows,
             "feasible_rows_full": self.feasible_rows_full,
             "distinct_slots": self.distinct_slots,
+            "window_lanes_restaged": self.window_lanes_restaged,
+            "window_feas_reuses": self.window_feas_reuses,
+            "window_jc_reuses": self.window_jc_reuses,
         }
+
+    def _staging_take(self, shape: tuple, gen: int) -> _WindowStaging:
+        """Check out the kept lane stacks of one window shape (new ones
+        where none are kept) for one window; all are dropped when the
+        fleet generation moves.  ``_staging_keep`` takes them back once
+        the window fetched its results: a window that raises before
+        leaves none behind, so no later window reads buffers it left
+        half written or a device stack still in transfer."""
+        if gen != self.staging_gen:
+            self.staging.clear()
+            self.staging_gen = gen
+        return self.staging.pop(shape, None) or _WindowStaging(*shape)
+
+    def _staging_keep(self, shape: tuple, staging: _WindowStaging) -> None:
+        """Keep a window's lane stacks: ``STAGED_SHAPES`` at most, the
+        least recently used dropped first."""
+        if len(self.staging) >= STAGED_SHAPES:
+            del self.staging[next(iter(self.staging))]
+        self.staging[shape] = staging    # the most recently used last
 
     def finish_stats(self) -> dict:
         """Registry provider: how often the finish seeds a node from
@@ -474,8 +581,8 @@ class BatchEvalRunner:
         # per distinct lane count, and a storm whose batch size drifts
         # 3, 5, 6, ... would recompile per size (~0.5s each) — the
         # recompile-churn class devlint's provenance pass flags.  Pad
-        # lanes are all-invalid (feasible/valid False, counts 0) and
-        # place nothing; results are consumed per real lane only.
+        # lanes have counts 0 (valid False) and place nothing, whatever
+        # mask rows they hold; results are consumed per real lane only.
         B_pad = pad_lanes(B)
         rounds_ok = all(a.rounds_eligible for _, _, a in pending)
         k_cap = max(a.k_cap for _, _, a in pending)
@@ -507,22 +614,36 @@ class BatchEvalRunner:
         if tracer is not None:
             whole = trace_mod.StageClock(tracer)
             part = trace_mod.StageClock(tracer)
-        # Harmonize pad shapes across lanes, stack, one dispatch.
-        feasible = np.zeros((B_pad, g_max, statics.n_pad), dtype=bool)
+        # Mesh resolution rides the ONE authority (parallel/mesh.py):
+        # multi-chip agents automatically get the 2-D (lanes, fleet)
+        # storm layout when the shape splits, NOMAD_TPU_MESH overrides.
+        from nomad_tpu.parallel.mesh import dispatch_mesh
+
+        mesh = dispatch_mesh(B_pad, statics.n_pad)
+        # Harmonize pad shapes across lanes, stack, one dispatch.  The
+        # fleet-wide stacks come from the runner's kept staging of this
+        # shape, rewritten where the lanes changed; a mesh dispatch
+        # stacks afresh (its wrappers place host operands themselves).
+        shape = (B_pad, g_max, statics.n_pad)
+        staging = self._staging_take(shape, statics.gen) if mesh is None \
+            else _WindowStaging(*shape)
+        restaged, counted = staging.stage(pending)
+        feas_resident, jc_resident = staging.resident(restaged, counted)
+        self.window_lanes_restaged += restaged
+        self.window_feas_reuses += feas_resident
+        self.window_jc_reuses += jc_resident
+        feasible, job_counts = staging.feasible, staging.job_counts
         asks = np.zeros((B_pad, g_max, pending[0][2].asks.shape[1]),
                         dtype=np.float32)
         distinct = np.zeros((B_pad, g_max), dtype=bool)
         group_idx = np.zeros((B_pad, p_max), dtype=np.int32)
         valid = np.zeros((B_pad, p_max), dtype=bool)
-        job_counts = np.zeros((B_pad, statics.n_pad), dtype=np.int32)
         counts = np.zeros((B_pad, g_max), dtype=np.int32)
         for b, (_s, _p, a) in enumerate(pending):
-            feasible[b, :a.g_pad] = a.feasible_h
             asks[b, :a.g_pad] = a.asks
             distinct[b, :a.g_pad] = a.distinct
             group_idx[b, :a.p_pad] = a.group_idx
             valid[b, :a.p_pad] = a.valid
-            job_counts[b] = a.view.job_counts
             counts[b, :a.g_pad] = a.counts
 
         penalty = np.zeros(B_pad, dtype=np.float32)
@@ -534,14 +655,10 @@ class BatchEvalRunner:
                 b_pad=B_pad, g_pad=g_max, n_pad=statics.n_pad,
                 bytes=sum(x.nbytes for x in (
                     feasible, asks, distinct, group_idx, valid,
-                    job_counts, counts, penalty)), **pair)
+                    job_counts, counts, penalty)),
+                lanes_restaged=restaged, feas_resident=int(feas_resident),
+                jc_resident=int(jc_resident), **pair)
 
-        # Mesh resolution rides the ONE authority (parallel/mesh.py):
-        # multi-chip agents automatically get the 2-D (lanes, fleet)
-        # storm layout when the shape splits, NOMAD_TPU_MESH overrides.
-        from nomad_tpu.parallel.mesh import dispatch_mesh
-
-        mesh = dispatch_mesh(B_pad, statics.n_pad)
         self.device_dispatches += 1
         self.device_lanes += B
         if mesh is not None:
@@ -565,12 +682,14 @@ class BatchEvalRunner:
 
         def fetch(late, *arrays) -> list:
             """``fetch_results``, its seconds on the window's
-            ``device.dispatch`` span (``late``: that span's tags)."""
-            if late is None:
-                return fetch_results(*arrays)
+            ``device.dispatch`` span (``late``: that span's tags); the
+            window's kept lane stacks are handed back after it."""
             t0 = _tnow()
             out = fetch_results(*arrays)
-            late["fetch_s"] = _tnow() - t0
+            if late is not None:
+                late["fetch_s"] = _tnow() - t0
+            if mesh is None:
+                self._staging_keep(shape, staging)
             return out
         # All fused lanes share the same snapshot base usage (fast-path
         # contract above); use the resident device copies when available
@@ -596,18 +715,28 @@ class BatchEvalRunner:
                 moved = moved_bytes("h2d")
             capacity_d, reserved_d = statics.device_capacity_reserved()
             base_usage = put_counted(view0.dispatch_usage())
-            # The per-dispatch lane stacks are fresh host arrays: place
-            # them EXPLICITLY (counted) instead of letting jit commit
-            # them implicitly — the fused dispatch's h2d bytes are part
-            # of its honest cost, and the transfer-guard sanitizer
-            # rejects the implicit form.  (The sharded wrappers below
-            # _put their operands themselves.)
-            feasible = put_counted(feasible)
+            # The per-dispatch lane stacks are host arrays: place them
+            # EXPLICITLY (counted) instead of letting jit commit them
+            # implicitly — the fused dispatch's h2d bytes are part of
+            # its honest cost, and the transfer-guard sanitizer rejects
+            # the implicit form.  The staging's device stacks are passed
+            # as they are where they may be; its host buffers are
+            # uploaded as copies, since later windows write them again.
+            # (The sharded wrappers below _put their operands
+            # themselves.)
+            if not feas_resident:
+                staging.feasible_d = put_counted(feasible, copy=True)
+            feasible = staging.feasible_d
+            if counted:
+                job_counts = put_counted(job_counts, copy=True)
+            else:
+                if not jc_resident:
+                    staging.nought_d = put_counted(job_counts, copy=True)
+                job_counts = staging.nought_d
             asks = put_counted(asks)
             distinct = put_counted(distinct)
             group_idx = put_counted(group_idx)
             valid = put_counted(valid)
-            job_counts = put_counted(job_counts)
             counts = put_counted(counts)
             penalty = put_counted(penalty)
             if part is not None:

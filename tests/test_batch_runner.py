@@ -730,6 +730,10 @@ def test_a_kernel_window_says_its_stack_upload_and_fetch_once():
     uploads = [s for s in spans if s["name"] == "window.upload"]
     assert len(fused) == len(stacks) == len(uploads) == \
         BatchEvalRunner.FUSED_RETRY_ROUNDS == runner.stats()["fused_batches"]
+    # A fresh runner's first window stacks every lane and reuses nothing.
+    first = stacks[0]["tags"]
+    assert (first["lanes_restaged"], first["feas_resident"],
+            first["jc_resident"]) == (first["lanes"], 0, 0)
     for stack, upload, disp in zip(stacks, uploads, fused):
         assert stack["parent_id"] == upload["parent_id"] == \
             disp["parent_id"]
@@ -737,9 +741,14 @@ def test_a_kernel_window_says_its_stack_upload_and_fetch_once():
         assert upload["t0"] + upload["dur"] <= disp["t0"] + 1e-9
         for key in ("lanes", "b_pad", "g_pad", "n_pad"):
             assert stack["tags"][key] == disp["tags"][key], key
-        # Every byte stacked is uploaded, and nothing else but the
+        # Every byte stacked is uploaded but the device stacks the
+        # window passed as they were, and nothing else but the
         # snapshot's usage where it was not resident.
-        assert 0 < stack["tags"]["bytes"] <= upload["tags"]["h2d_bytes"] \
+        tags = stack["tags"]
+        rows = tags["b_pad"] * tags["n_pad"]
+        kept = tags["feas_resident"] * rows * tags["g_pad"] + \
+            tags["jc_resident"] * rows * 4
+        assert 0 < tags["bytes"] - kept <= upload["tags"]["h2d_bytes"] \
             <= disp["tags"]["h2d_bytes"]
         assert 0.0 < disp["tags"]["fetch_s"] <= disp["dur"]
         lanes = [s for s in spans if s["name"] == "sched.dispatch"

@@ -1479,6 +1479,47 @@ class TestWholePathSpans:
         assert after["slots"] - before["slots"] == 3 + 1
         assert after["padded_slots"] - before["padded_slots"] == 2 * 8
 
+    def test_a_kernel_window_says_what_its_kept_stacks_reused(self):
+        """On the device path ``window.stack`` says how many lane
+        positions had their mask rows rewritten (``lanes_restaged``) and
+        whether the window passed the device feasibility stack
+        (``feas_resident``) and the nought job counts (``jc_resident``)
+        as they were; ``nomad.batch_runner.window_lanes_restaged`` /
+        ``.window_feas_reuses`` / ``.window_jc_reuses`` under
+        /v1/agent/metrics move by their sums.  Two one-lane windows of
+        one slot key: the first rewrites its lane, the second reuses
+        both stacks."""
+        from nomad_tpu.parallel.mesh import mesh_override
+        from nomad_tpu.scheduler.executor import executor_override
+
+        names = ("window_lanes_restaged", "window_feas_reuses",
+                 "window_jc_reuses")
+        agent, api = _http_agent()
+
+        def counters() -> dict:
+            code, body = _http_get(
+                api, "/v1/agent/metrics?filter=batch_runner")
+            assert code == 200
+            return {k: body["providers"][f"nomad.batch_runner.{k}"]
+                    for k in names}
+
+        try:
+            with executor_override("device"), mesh_override("off"):
+                before = counters()
+                with trace.tracing(seed=42) as tracer:
+                    for _ in range(2):
+                        eval_id = api.job_register(_job(1))["eval_id"]
+                        assert _await_eval(api, eval_id).status == \
+                            "complete"
+                    spans = tracer.snapshot()
+                after = counters()
+        finally:
+            agent.shutdown()
+        stacks = [_tags(s) for s in spans if s["name"] == "window.stack"]
+        assert [(t["lanes_restaged"], t["feas_resident"], t["jc_resident"])
+                for t in stacks] == [(1, 0, 0), (0, 1, 1)]
+        assert [after[k] - before[k] for k in names] == [1, 1, 1]
+
     def test_leaf_spans_cover_the_interval(self):
         """The chain is contiguous: at most a tenth of socket-readable
         -> answer-written lies under no leaf span (the benchmark's
