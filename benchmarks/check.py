@@ -27,6 +27,13 @@ allocation in the store:
                           the one at which their evaluation read complete
     readback_mismatch     sampled ``GET /v1/allocation/<id>`` that differ
                           from the store
+    resident_lost         resident allocations (the configuration's
+                          ``resident`` jobs, written before the window:
+                          ``reference.resident_layout``) not read back
+                          live, under their id, job and name, on their
+                          row, with their ask and ports; they count in
+                          ``oversubscribed_nodes`` and in the scores'
+                          usage like any other, and are no window job
     redeliveries          broker nacks + failed worker batches + node
                           expiries, from boot
 
@@ -60,6 +67,7 @@ LIMITS = {
     "distinct_violations": 0,
     "late_commits": 0,
     "readback_mismatch": 0,
+    "resident_lost": 0,
     "redeliveries": 0,
     "score_gap": 1.0e-3,
     "score_regret": 1.0e-3,
@@ -81,12 +89,36 @@ REDELIVERY_COUNTERS = ("nomad.broker.nacks",
                        "nomad.heartbeat.expiries")
 
 
+def resident_lost(resident: "dict | None", live: dict) -> int:
+    """Resident allocations of ``resident`` (``reference.
+    resident_layout``) that are not among the ``live`` columns as laid
+    out: same id, job, name, row, ask and ports."""
+    if resident is None or not len(resident["id"]):
+        return 0
+    if not len(live["id"]):
+        return len(resident["id"])
+    where = {aid: i for i, aid in enumerate(live["id"])}
+    at = np.asarray([where.get(aid, -1) for aid in resident["id"]],
+                    dtype=np.int64)
+    found = at >= 0
+    i = np.where(found, at, 0)
+    same = found & (live["node"][i] == resident["row"]) \
+        & (live["vec"][i] == resident["vec"]).all(axis=1)
+    for k in np.flatnonzero(same).tolist():
+        j = int(at[k])
+        same[k] = (live["job"][j] == resident["job"][k]
+                   and live["name"][j] == resident["name"][k]
+                   and sorted(live["ports"][j]) == resident["ports"][k])
+    return int((~same).sum())
+
+
 def compare(seed: int, traffic: dict, fleet: dict, records: list,
             answers: dict, counters_end: dict, control_name: "str | None",
-            say) -> tuple:
+            say, resident: "dict | None" = None) -> tuple:
     """(checks {name: {"value", "limit"}}, info {name: value}).
     ``records``: one per finished job, ``in_window`` set on those that
-    completed inside the window."""
+    completed inside the window; ``resident``: the layout written before
+    the window, or None."""
     allocs, job_info = answers["allocs"], answers["jobs"]
     running = allocs["running"]
     live = {k: (v[running] if isinstance(v, np.ndarray)
@@ -182,6 +214,7 @@ def compare(seed: int, traffic: dict, fleet: dict, records: list,
         "distinct_violations": undistinct,
         "late_commits": late,
         "readback_mismatch": answers["readback_mismatch"],
+        "resident_lost": resident_lost(resident, live),
         "redeliveries": sum(counters_end[k] for k in REDELIVERY_COUNTERS),
         "score_gap": gap,
         "score_regret": regret,

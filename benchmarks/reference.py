@@ -166,6 +166,114 @@ def group_ask(group: dict) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
+# resident work: what the fleet already runs when the window opens
+# ---------------------------------------------------------------------------
+
+RESIDENT_GROUP = "main"
+ASK_KEYS = ("cpu", "memory_mb", "disk_mb", "iops", "mbits", "dynamic_ports")
+
+
+def resident_jobs(config: dict, fleet: dict) -> list:
+    """The configuration's ``resident`` jobs as plain job specs of one
+    group (``main``) each, in file order: ``id``, ``name``, ``type``,
+    ``priority``, ``datacenters`` (those of its classes) and the group
+    with its ask and ``count`` unset; plus ``classes`` (class indexes it
+    may use), ``node_share`` and ``per_node``.  Empty without the key."""
+    names = [c["name"] for c in fleet["classes"]]
+    out = []
+    for r in config.get("resident", ()):
+        if r["type"] not in PENALTY:
+            raise ValueError(f"resident {r['id']}: type {r['type']!r}")
+        unknown = set(r.get("classes", ())) - set(names)
+        if unknown:
+            raise ValueError(f"resident {r['id']}: no class {unknown}")
+        classes = [names.index(c) for c in r.get("classes", names)]
+        share, per_node = float(r["node_share"]), int(r["per_node"])
+        if not 0.0 < share <= 1.0 or per_node < 1:
+            raise ValueError(f"resident {r['id']}: node_share in (0, 1] "
+                             "and per_node >= 1")
+        group = {k: r[k] for k in ASK_KEYS if k in r}
+        group["name"] = RESIDENT_GROUP
+        out.append({
+            "id": r["id"], "name": r["id"], "type": r["type"],
+            "priority": int(r["priority"]),
+            "datacenters": sorted({fleet["classes"][k]["datacenter"]
+                                   for k in classes}),
+            "groups": [group], "classes": classes,
+            "node_share": share, "per_node": per_node})
+    return out
+
+
+def resident_layout(config: dict, fleet: dict, seed: int) -> dict:
+    """Every resident allocation of the configuration on ``fleet``, as
+    columns: ``id``, ``job``, ``group``, ``name`` (``<job>.<group>[i]``),
+    ``row`` [A], ``vec`` [A, 6] (its ask), ``ports`` (handed out on each
+    node from 20000 upward, in file order, skipping the node's reserved
+    ports); and ``jobs``, each spec with its group's ``count`` set to its
+    allocations.
+
+    A job takes ``round(node_share * rows)`` of the rows of its classes
+    (all of them, or a sorted sample drawn from ``{seed}:resident``, a
+    stream no other draw uses) and ``per_node`` copies on each.  A
+    layout that would overfill a node in any dimension, or hand out a
+    port past the dynamic range, is refused: ``ValueError``.  Without
+    ``resident`` the layout is empty and nothing is drawn; a job whose
+    share of a (rehearsal's) fleet rounds to no row is left out."""
+    rng = random.Random(f"{seed}:resident")
+    out = {"id": [], "job": [], "group": [], "name": [], "row": [],
+           "vec": [], "ports": [], "jobs": []}
+    n = len(fleet["ids"])
+    class_of = fleet["class_of"]
+    used = fleet["reserved"].copy()
+    cursor = np.full(n, MIN_DYNAMIC_PORT, dtype=np.int64)
+    reserved_ports = [set(p) for p in fleet["class_reserved_ports"]]
+    for spec in resident_jobs(config, fleet):
+        group = spec["groups"][0]
+        ask = group_ask(group)
+        candidates = np.flatnonzero(np.isin(class_of, spec["classes"]))
+        take = int(round(spec["node_share"] * len(candidates)))
+        if not take:
+            continue
+        if take < len(candidates):
+            candidates = np.sort(np.asarray(
+                rng.sample(candidates.tolist(), take), dtype=np.int64))
+        rows = np.repeat(candidates, spec["per_node"])
+        n_ports = len(group.get("dynamic_ports", ()))
+        for i, row in enumerate(rows.tolist()):
+            skip = reserved_ports[class_of[row]]
+            ports = []
+            p = int(cursor[row])
+            while len(ports) < n_ports:
+                if p not in skip:
+                    ports.append(p)
+                p += 1
+            cursor[row] = p
+            out["ports"].append(ports)
+            out["id"].append(seeded_uuid(rng))
+            out["name"].append(f"{spec['id']}.{group['name']}[{i}]")
+        np.add.at(used, rows, ask)
+        out["row"].append(rows)
+        out["vec"].append(np.broadcast_to(ask, (len(rows), len(DIMS))))
+        out["job"].extend([spec["id"]] * len(rows))
+        out["group"].extend([group["name"]] * len(rows))
+        group["count"] = len(rows)
+        out["jobs"].append(spec)
+        over = np.flatnonzero((used > fleet["capacity"]).any(axis=1)
+                              | (cursor > MAX_DYNAMIC_PORT))
+        if len(over):
+            raise ValueError(
+                f"resident {spec['id']} overfills node rows "
+                f"{over[:5].tolist()} ({len(over)} in all): "
+                f"{used[over[0]].tolist()} of "
+                f"{fleet['capacity'][over[0]].tolist()}")
+    out["row"] = np.concatenate(out["row"]) if out["jobs"] else \
+        np.zeros(0, dtype=np.int64)
+    out["vec"] = np.concatenate(out["vec"]) if out["jobs"] else \
+        np.zeros((0, len(DIMS)), dtype=np.float64)
+    return out
+
+
+# ---------------------------------------------------------------------------
 # feasibility: datacenters, the driver, hard constraints, distinct_hosts
 # ---------------------------------------------------------------------------
 

@@ -37,8 +37,12 @@ ROOT = os.path.dirname(HERE)
 
 # --rehearse: the command at a size a CPU holds.  Never a measurement.
 # 1,024 nodes: at 512 a 2 s rehearsal of four stack clients filled the
-# fleet (16 placements a job, 7 copies a node) on this sandbox's CPU.
-REHEARSAL = {"nodes": 1024, "groups": 24, "count": 24, "clients": 4}
+# fleet (16 placements a job, 7 copies a node) on a CPU.  ``tiered``:
+# the copies a job of tiers asks in all, at most (a tier keeps at least
+# one): at ten copies of ten tiers, a 2 s rehearsal filled the 65 rows
+# of the one class that a tier of ``batch10x64`` admits at 1,024 nodes.
+REHEARSAL = {"nodes": 1024, "groups": 24, "count": 24, "clients": 4,
+             "tiered": 32}
 
 
 def say(msg: str) -> None:
@@ -73,6 +77,10 @@ def rehearsal_traffic(traffic: dict) -> dict:
     job = out["job"]
     job["groups"] = min(job["groups"], REHEARSAL["groups"])
     job["count"] = min(job["count"], REHEARSAL["count"])
+    if "tiers" in job:
+        tiers = min(len(job["tiers"]), job["groups"])
+        job["count"] = min(job["count"],
+                           max(1, REHEARSAL["tiered"] // tiers))
     out["clients"] = min(out["clients"], REHEARSAL["clients"])
     return out
 
@@ -229,6 +237,7 @@ def main(argv=None, rehearsal_is_never_correct: bool = True) -> int:
 
     generator = load_module("generators", traffic["generator"])
     fleet = reference.make_fleet(config, args.seed, n_nodes)
+    resident = reference.resident_layout(config, fleet, args.seed)
     work_dir = tempfile.mkdtemp(prefix="nomad-bench-")
     served = None
     trace = None
@@ -236,7 +245,8 @@ def main(argv=None, rehearsal_is_never_correct: bool = True) -> int:
     try:
         try:
             served = served_mod.Served(config, fleet, args.seed,
-                                       os.path.join(work_dir, "raft"), say)
+                                       os.path.join(work_dir, "raft"), say,
+                                       resident)
             for plug in traffic.get("prewarm", ()):
                 t0 = time.perf_counter()
                 load_module("prewarm", plug["module"]).prewarm(
@@ -314,7 +324,7 @@ def main(argv=None, rehearsal_is_never_correct: bool = True) -> int:
             r["in_window"] = run["t_open"] <= r["t_done"] <= run["t_close"]
         checks, info = check.compare(args.seed, traffic, fleet, records,
                                      answers, counters_end, args.control,
-                                     say)
+                                     say, resident)
         done = [r for r in records
                 if r["in_window"] and r["status"] == "complete"]
         lat = sorted(1e3 * (r["t_done"] - r["t_submit"]) for r in done)

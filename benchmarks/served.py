@@ -11,6 +11,7 @@ allocations come out.
 from __future__ import annotations
 
 import os
+import random
 import time
 
 import numpy as np
@@ -49,6 +50,11 @@ def ensure_native() -> dict:
             "seconds": time.perf_counter() - t0}
 
 
+def node_ip(i: int) -> str:
+    """The address of fleet row ``i``'s one NIC, ``eth0``."""
+    return f"192.168.0.{(i % 250) + 1}"
+
+
 def program_node(fleet: dict, i: int):
     """One fleet row as the program's ``Node``, from its row's class
     (``reference.node_classes``: without classes, the reference's
@@ -58,7 +64,7 @@ def program_node(fleet: dict, i: int):
     cls = fleet["classes"][fleet["class_of"][i]]
     shape = cls["node"]
     res = shape["reserved"]
-    octet = (i % 250) + 1
+    ip = node_ip(i)
     return Node(
         id=fleet["ids"][i], datacenter=cls["datacenter"], name=f"node-{i}",
         attributes=dict(cls["attributes"]), meta=dict(cls["meta"]),
@@ -66,13 +72,13 @@ def program_node(fleet: dict, i: int):
             cpu=shape["cpu"], memory_mb=shape["memory_mb"],
             disk_mb=shape["disk_mb"], iops=shape["iops"],
             networks=[NetworkResource(
-                device="eth0", cidr=f"192.168.0.{octet}/32",
+                device="eth0", cidr=f"{ip}/32",
                 mbits=shape["mbits"])]),
         reserved=Resources(
             cpu=res.get("cpu", 0), memory_mb=res.get("memory_mb", 0),
             disk_mb=res.get("disk_mb", 0), iops=res.get("iops", 0),
             networks=[NetworkResource(
-                device="eth0", ip=f"192.168.0.{octet}",
+                device="eth0", ip=ip,
                 reserved_ports=list(res.get("ports", [])),
                 mbits=res.get("mbits", 0))]),
         node_class=cls["node_class"], status="ready")
@@ -119,19 +125,27 @@ def program_job(spec: dict):
                 networks=nets))]))
     return Job(
         region="global", id=spec["id"], name=spec["name"],
-        type=spec.get("type", "service"), priority=50,
+        type=spec.get("type", "service"),
+        priority=spec.get("priority", 50),
         datacenters=list(reference.job_datacenters(spec)),
         constraints=program_constraints(spec.get(
             "constraints", reference.MOCK_JOB_CONSTRAINTS)),
         task_groups=groups)
 
 
+# Allocations in one ALLOC_UPDATE_REQUEST of the resident load: an
+# entry applies in well under a second, inside raft_apply's 30 s wait.
+RESIDENT_CHUNK = 8192
+
+
 class Served:
     """One server-only agent with a raft log on disk, the whole fleet
-    registered over ``Node.Register`` and heartbeating."""
+    registered over ``Node.Register`` and heartbeating, and the
+    configuration's resident allocations (``reference.resident_layout``)
+    written on it."""
 
     def __init__(self, config: dict, fleet: dict, seed: int,
-                 raft_dir: str, say) -> None:
+                 raft_dir: str, say, resident: "dict | None" = None) -> None:
         from nomad_tpu.agent import Agent, AgentConfig
         from nomad_tpu.agent.swarm import AgentSwarm
         from nomad_tpu.api import APIClient
@@ -166,9 +180,78 @@ class Served:
                                    f"{len(nodes)} nodes")
             say(f"agent up, {len(nodes)} nodes registered in "
                 f"{time.perf_counter() - t0:.1f}s")
+            if resident is not None and resident["jobs"]:
+                self.load_resident(resident, seed, say)
         except BaseException:
             self.shutdown()
             raise
+
+    def load_resident(self, layout: dict, seed: int, say) -> None:
+        """Write the resident allocations as a server replays them from
+        its log: one ``JOB_REGISTER_REQUEST`` a job (its group's count is
+        its allocations, so no evaluation is owed and none is made), then
+        ``ALLOC_UPDATE_REQUEST`` entries of ``encode_alloc_update``
+        payloads, ``RESIDENT_CHUNK`` allocations each, one columnar slab
+        an entry: what a plan commit writes, without the scheduler."""
+        from nomad_tpu.scheduler.jax_binpack import build_slots_c
+        from nomad_tpu.structs import Resources, codec
+        from nomad_tpu.structs.alloc_slab import (AllocSlab,
+                                                  encode_alloc_update)
+
+        server = self.agent.server
+        state = server.fsm.state
+        ids = self.fleet["ids"]
+        rng = random.Random(f"{seed}:resident-evals")
+        t0 = time.perf_counter()
+        entries = lo = 0
+        for spec in layout["jobs"]:
+            job = program_job(spec)
+            errors = job.validate()
+            if errors:
+                raise ValueError(f"resident {spec['id']}: {errors}")
+            server.raft_apply(codec.JOB_REGISTER_REQUEST,
+                              {"job": job.to_dict()})
+            entries += 1
+            job = state.job_by_id(spec["id"])
+            group = job.task_groups[0]
+            task = group.tasks[0]
+            res = task.resources
+            size = Resources(cpu=res.cpu, memory_mb=res.memory_mb,
+                             disk_mb=res.disk_mb, iops=res.iops,
+                             networks=list(res.networks))
+            slots = build_slots_c([(size, [
+                (task.name, res, res.networks[0] if res.networks
+                 else None)])])
+            eval_id = reference.seeded_uuid(rng)
+            end = lo + group.count
+            for a in range(lo, end, RESIDENT_CHUNK):
+                b = min(end, a + RESIDENT_CHUNK)
+                k = b - a
+                ports = layout["ports"][a:b]
+                port_off = np.zeros(k + 1, dtype=np.int64)
+                np.cumsum([len(p) for p in ports], out=port_off[1:])
+                slab = AllocSlab(
+                    eval_id=eval_id, job=job, slots=slots,
+                    metric_proto={"nodes_evaluated": 0,
+                                  "allocation_time": 0.0},
+                    groups=[0] * k, ids=layout["id"][a:b],
+                    names=layout["name"][a:b], tgs=[group.name] * k,
+                    scores=[0.0] * k, port_off=port_off, n_rows=k,
+                    ports=np.asarray([p for row in ports for p in row],
+                                     dtype=np.int32))
+                rows = layout["row"][a:b].tolist()
+                slab.node_ids = [ids[r] for r in rows]
+                slab.ips = [node_ip(r) for r in rows]
+                slab.devs = ["eth0"] * k
+                slab.seal(k)
+                server.raft_apply(codec.ALLOC_UPDATE_REQUEST,
+                                  encode_alloc_update(
+                                      [slab.alloc(r) for r in range(k)]))
+                entries += 1
+            lo = end
+        say(f"resident: {lo} allocations of {len(layout['jobs'])} jobs "
+            f"loaded in {time.perf_counter() - t0:.1f}s ({entries} "
+            "entries)")
 
     # -- the two calls of a client -----------------------------------------
     def client(self, wait_time_s: float):

@@ -12,23 +12,26 @@ for path in (BENCH, ROOT):
         sys.path.insert(0, path)
 
 FIXTURES = os.path.join(HERE, "fixtures")
-# A cell no BENCHMARK.json lists: ten constrained task groups on three
-# node classes (``fixtures/``).  ``fixture_cell`` is what ``run.find_cell``
-# returns for it; the tests that drive it patch that lookup.
+# Cells no BENCHMARK.json lists: ten constrained task groups on three
+# node classes, and the same on a fleet that already runs two resident
+# jobs (``fixtures/``).  ``fixture_cell`` is what ``run.find_cell``
+# returns for them; the tests that drive them patch that lookup.
 FIXTURE_CELL = "fixture.classes3.constrained10"
+RESIDENT_CELL = "fixture.resident3.constrained10"
 
 
 def fixture_cell(workload: str):
     import json
 
-    if workload != FIXTURE_CELL:
+    if workload not in (FIXTURE_CELL, RESIDENT_CELL):
         return None
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    cell = {"name": FIXTURE_CELL, "config": "classes3",
-            "traffic": "constrained10", "chips": 1}
+    _kind, config, traffic = workload.split(".")
+    cell = {"name": workload, "config": config, "traffic": traffic,
+            "chips": 1}
     files = []
-    for name in ("classes3", "constrained10"):
+    for name in (config, traffic):
         with open(os.path.join(FIXTURES, f"{name}.json")) as fh:
             files.append(json.load(fh))
     return (dict(bench, workloads=[cell]), cell, *files)

@@ -12,9 +12,17 @@ chips to leave out.
                             ready node, where the scheduler composes it
     distinct_ignored        every slot's ``distinct_hosts`` flag off,
                             where the scheduler derives it
+    resident_dropped        the store drops every allocation whose
+                            evaluation it does not hold: the resident
+                            allocations, written with none
+    resident_usage_ignored  the usage view (``models/fleet.alloc_vec``:
+                            the scheduler's and the plan verifier's)
+                            counts nothing for those allocations
 
-The last two only show where a job states a constraint or
-``distinct_hosts`` (the fixture under ``fixtures/``; no cell yet).
+``constraints_ignored`` and ``distinct_ignored`` only show where a job
+states a constraint or ``distinct_hosts``, the last two only where a
+configuration states ``resident`` jobs (the fixtures under
+``fixtures/``).
 
 ``test_control_and_faults.py`` drives a rehearsal under each.  On the
 chip, at a cell's own size (the readings in PERF.md section 2):
@@ -25,6 +33,8 @@ chip, at a cell's own size (the readings in PERF.md section 2):
 import contextlib
 import os
 import sys
+
+import numpy as np
 
 
 @contextlib.contextmanager
@@ -88,9 +98,59 @@ def distinct_ignored():
     return _patched(JaxBinPackScheduler, "_derive_sem", replacement)
 
 
+def _without_evaluation(store, items):
+    """``upsert_allocs_batched``'s items split by whether the store
+    holds the evaluation of an allocation: (kept, the others)."""
+    kept, others = [], []
+    for index, allocs in items:
+        known = []
+        for a in allocs:
+            (known if store.eval_by_id(a.eval_id) else others).append(a)
+        kept.append((index, known))
+    return kept, others
+
+
+def resident_dropped():
+    from nomad_tpu.state.store import StateStore
+
+    def replacement(sound):
+        def drop(self, items):
+            return sound(self, _without_evaluation(self, items)[0])
+        return drop
+
+    return _patched(StateStore, "upsert_allocs_batched", replacement)
+
+
+@contextlib.contextmanager
+def resident_usage_ignored():
+    from nomad_tpu.models import fleet
+    from nomad_tpu.state.store import StateStore
+
+    unplaced = set()
+
+    def record(sound):
+        def upsert(self, items):
+            unplaced.update(a.eval_id for a in
+                            _without_evaluation(self, items)[1])
+            return sound(self, items)
+        return upsert
+
+    def ignore(sound):
+        def vec(alloc):
+            if alloc.eval_id in unplaced:
+                return np.zeros(fleet.NDIMS, dtype=np.float32)
+            return sound(alloc)
+        return vec
+
+    with _patched(StateStore, "upsert_allocs_batched", record), \
+            _patched(fleet, "alloc_vec", ignore):
+        yield
+
+
 FAULTS = {f.__name__: f for f in (state_left_unchanged, half_of_every_plan,
                                   answer_altered, constraints_ignored,
-                                  distinct_ignored)}
+                                  distinct_ignored, resident_dropped,
+                                  resident_usage_ignored)}
 
 if __name__ == "__main__":
     bench = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

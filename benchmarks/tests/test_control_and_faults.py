@@ -12,7 +12,10 @@ each fault of
 chip: PERF.md section 2.  Beside the cells, the fixture of ten
 constrained task groups on three node classes (``conftest.FIXTURE_CELL``,
 which no ``BENCHMARK.json`` lists): the two faults that only a job with
-a constraint or ``distinct_hosts`` can show run there alone.
+a constraint or ``distinct_hosts`` can show run there alone.  And the
+same traffic on a fleet that already runs two resident jobs
+(``conftest.RESIDENT_CELL``): the two faults that only resident work
+can show run there alone.
 """
 import io
 import json
@@ -22,11 +25,12 @@ from contextlib import nullcontext, redirect_stdout
 import pytest
 
 import run as bench_run
-from conftest import FIXTURE_CELL, ROOT, fixture_cell
+from conftest import FIXTURE_CELL, RESIDENT_CELL, ROOT, fixture_cell
 from faults import FAULTS
 
 CELLS = [w["name"] for w in json.load(
-    open(os.path.join(ROOT, "BENCHMARK.json")))["workloads"]] + [FIXTURE_CELL]
+    open(os.path.join(ROOT, "BENCHMARK.json")))["workloads"]] + [
+        FIXTURE_CELL, RESIDENT_CELL]
 
 
 @pytest.fixture(autouse=True)
@@ -36,12 +40,12 @@ def fixture_lookup(monkeypatch):
                         lambda name: fixture_cell(name) or listed(name))
 
 
-def drive(cell, seed, *flags, fault=None):
+def drive(cell, seed, *flags, fault=None, seconds=2):
     out = io.StringIO()
     with redirect_stdout(out), (FAULTS[fault]() if fault else nullcontext()):
         rc = bench_run.main(
-            ["--workload", cell, "--seed", str(seed), "--seconds", "2",
-             "--trace", "0", "--rehearse", *flags],
+            ["--workload", cell, "--seed", str(seed), "--seconds",
+             str(seconds), "--trace", "0", "--rehearse", *flags],
             rehearsal_is_never_correct=False)
     assert rc == 0
     result = json.loads(out.getvalue().strip().splitlines()[-1])
@@ -85,11 +89,24 @@ def test_fault_is_not_correct(cell, fault, caught_by):
     assert caught_by & outside
 
 
-@pytest.mark.parametrize("fault, caught_by", [
-    ("constraints_ignored", "constraint_violations"),
-    ("distinct_ignored", "distinct_violations"),
+@pytest.mark.parametrize("fault, caught_by, seconds", [
+    ("constraints_ignored", "constraint_violations", 2),
+    # A copy lands beside its job's own only once the fleet has filled
+    # unevenly: after ~80 jobs, which a loaded CPU takes more than 2 s
+    # to complete (``tests/test_bench_node_classes.py``).
+    ("distinct_ignored", "distinct_violations", 10),
 ])
-def test_constraint_fault_is_not_correct(fault, caught_by):
-    result, outside = drive(FIXTURE_CELL, 33, fault=fault)
+def test_constraint_fault_is_not_correct(fault, caught_by, seconds):
+    result, outside = drive(FIXTURE_CELL, 33, fault=fault, seconds=seconds)
+    assert result["correct"] is False
+    assert caught_by in outside
+
+
+@pytest.mark.parametrize("fault, caught_by", [
+    ("resident_dropped", "resident_lost"),
+    ("resident_usage_ignored", "oversubscribed_nodes"),
+])
+def test_resident_fault_is_not_correct(fault, caught_by):
+    result, outside = drive(RESIDENT_CELL, 34, fault=fault)
     assert result["correct"] is False
     assert caught_by in outside

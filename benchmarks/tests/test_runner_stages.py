@@ -18,26 +18,29 @@ from test_xplane import reducer
 
 BENCHMARK = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
-THREE = ["baseline4-10k.small", "fleet131k.storm", "fleet100k.stacks"]
-KERNEL = ["fleet131k.storm", "fleet100k.stacks"]
+# The cells whose traced runs re-plan one by one, and those whose
+# windows ride the kernel.
+REPLAN = ["baseline4-10k.small", "fleet131k.storm", "fleet100k.stacks",
+          "gcd2011-65k.batch10"]
+KERNEL = ["fleet131k.storm", "fleet100k.stacks", "gcd2011-65k.batch10"]
 # name -> (cells, source, layer, moves, reducer, the span it means)
 NEW = {
     "retry_cycle_share": (CELLS, "program_span", "Worker / fused runner",
                           "placements_per_s", "runner_stages", None),
-    "retry_ms": (THREE, "device_trace", "Worker / fused runner",
+    "retry_ms": (REPLAN, "device_trace", "Worker / fused runner",
                  "placements_per_s", "span_mean_ms", "sched.retry"),
-    "retry_begin_ms": (THREE, "device_trace", "Worker / fused runner",
+    "retry_begin_ms": (REPLAN, "device_trace", "Worker / fused runner",
                        "placements_per_s", "span_mean_ms", "retry.begin"),
-    "retry_finish_ms": (THREE, "device_trace", "Worker / fused runner",
+    "retry_finish_ms": (REPLAN, "device_trace", "Worker / fused runner",
                         "placements_per_s", "span_mean_ms", "retry.finish"),
-    "retry_submit_ms": (THREE, "device_trace",
+    "retry_submit_ms": (REPLAN, "device_trace",
                         "Plan queue, verify, raft, FSM", "placements_per_s",
                         "span_mean_ms", "retry.submit"),
     "runner_blocked_share": (CELLS, "program_span", "Worker / fused runner",
                              "placements_per_s", "runner_stages", None),
     "runner_stalled_share": (CELLS, "program_span", "Worker / fused runner",
                              "placements_per_s", "runner_stages", None),
-    "retry_stalled_share": (THREE, "device_trace", "Worker / fused runner",
+    "retry_stalled_share": (REPLAN, "device_trace", "Worker / fused runner",
                             "placements_per_s", "runner_stages", None),
     "plan_encode_ms": (CELLS, "program_span",
                        "Plan queue, verify, raft, FSM", "job_commit_p50_ms",
